@@ -136,6 +136,8 @@ def main() -> None:
     if "--quick" in sys.argv[1:]:
         os.environ["RECXL_BENCH_QUICK"] = "1"
     quick = os.environ.get("RECXL_BENCH_QUICK", "") not in ("", "0")
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     traced = "--trace" in sys.argv[1:]
     trace_out = None
     if "--trace-out" in sys.argv[1:]:

@@ -56,17 +56,17 @@ This module is the streaming tier that removes all three:
    gather any row, and a replicated bank keeps the gather local and
    communication-free), and tiles carry only two ``int32`` row-index
    vectors. The tile program gathers its columns *inside* the jitted /
-   ``shard_map``'d kernel -- through the fused Pallas kernel
-   (``repro.kernels.bank_scan``) on TPU, through an XLA gather
-   everywhere else -- so H2D bytes and host stacking scale with
-   ``unique_rows`` instead of ``cells``. And because a timeline
-   consumes nothing but (arrivals row, max-plus row, SB depth), cells
-   sharing that triple are one **scan lane**: the engine scans each
-   unique lane once and scatters the outputs to member cells, so
-   device compute too scales with unique lanes (the 12 960-cell
-   mega-grid scans ~2 700). ``data_plane="stacked"``
-   keeps the PR-3 plane (full per-cell copies, ``_stack_tile``) as the
-   measured baseline; both planes are bit-identical.
+   ``shard_map``'d program with an XLA gather (``_bank_gather``, then
+   the shared ``_scan_wv`` core -- one program on every backend), so
+   H2D bytes and host stacking scale with ``unique_rows`` instead of
+   ``cells``. And because a timeline consumes nothing but (arrivals
+   row, max-plus row, SB depth), cells sharing that triple are one
+   **scan lane**: the engine scans each unique lane once and scatters
+   the outputs to member cells, so device compute too scales with
+   unique lanes (the 12 960-cell mega-grid scans ~2 700).
+   ``data_plane="stacked"`` keeps the per-cell plane (full per-cell
+   copies, ``_stack_tile``) as the measured baseline; both planes are
+   bit-identical.
    :func:`bank_stats` reports the last run's data-plane accounting
    (H2D bytes, bank rows, dedup ratio, device-memory high-water mark).
 
@@ -148,7 +148,6 @@ from repro.distributed.sharding import (
     tile_shardings,
     tile_specs,
 )
-from repro.kernels.bank_scan import bank_scan, bank_scan_backend
 
 #: Cells per tile (before canonical padding) at the default byte
 #: budget. Large enough that one scan amortizes dispatch overhead,
@@ -478,10 +477,10 @@ def _build_tile_fn(sig: TileSignature) -> Callable:
 
 
 def _build_bank_tile_fn(sig: TileSignature) -> Callable:
-    """Banked tile program: in-kernel gather from the device-resident
-    bank columns, then the blocked scan -- fused into one Pallas kernel
-    on TPU, an XLA gather + the shared ``_scan_wv`` core elsewhere.
-    Tiles ship only the two ``int32`` row-index vectors.
+    """Banked tile program: in-jit XLA gather from the device-resident
+    bank columns, then the shared ``_scan_wv`` blocked scan -- the same
+    program on every backend. Tiles ship only the two ``int32``
+    row-index vectors.
 
     ``sig.bank_sub`` selects the per-shard sub-bank layout: the three
     max-plus planes arrive stacked ``(n_shards, local_rows, n_stores)``
@@ -490,10 +489,9 @@ def _build_bank_tile_fn(sig: TileSignature) -> Callable:
     and ``[0]`` IS its local sub-bank -- the gather (wv indices are
     pre-remapped to local rows, and the scheduler put every lane in its
     owner's slot block) runs against shard-resident rows with zero
-    cross-shard communication, through the SAME kernel as the
+    cross-shard communication, through the SAME program as the
     replicated layout. Gathering a local row moves the identical bits
     the global gather would, so the planes stay ``==``."""
-    fused = bank_scan_backend() == "pallas"
 
     def run(a_bank, w_bank, v_bank, p_bank, trace_idx, wv_idx):
         global _TRACE_COUNT
@@ -503,13 +501,6 @@ def _build_bank_tile_fn(sig: TileSignature) -> Callable:
             # reshape on device: axis 0 is size 1 inside shard_map, and
             # the full local plane at n_shards=1)
             w_bank, v_bank, p_bank = w_bank[0], v_bank[0], p_bank[0]
-        if fused:
-            # gathered rows stream HBM->VMEM inside the kernel; no
-            # stacked (B, n_stores) intermediate ever exists in HBM
-            return bank_scan(a_bank, w_bank, v_bank, p_bank,
-                             trace_idx, wv_idx,
-                             chunk=sig.chunk, sb=sig.sb_uniform,
-                             force="pallas")
         # the shared gather (one row memcpy per cell + the same cheap
         # device transpose as the stacked plane) -- and NO per-tile
         # precompute: w/v were collapsed on the host, once per unique
@@ -701,17 +692,14 @@ def warm_signatures(sigs: List[TileSignature], t_l1, t_wt,
     pool calls it at startup against its own device-resident bank, so
     the first live query never pays a compile.
 
-    Warming MUST go through a real call: on the jax versions this repo
-    targets (0.4.x), AOT ``jit(f).lower(shapes).compile()`` does not
-    populate the jit call cache (measured -- the first real call pays
-    the compile again), so shape-only warming would double every
-    compile. Banked programs warm against the REAL device-resident
-    bank (placed on the main thread before this runs -- a zero bank of
-    the right shape would hit the same program but duplicating the
-    replicated placement measured slower than the compile it hides)
-    with zero index vectors: row 0 is a valid gather everywhere, and
-    the warm call sees exactly the shardings of the streaming loop's
-    calls."""
+    Warming goes through a real call, so the warmed entry is the one
+    the streaming loop's calls hit. Banked programs warm against the
+    REAL device-resident bank (placed on the main thread before this
+    runs -- a zero bank of the right shape would hit the same program
+    but duplicating the replicated placement measured slower than the
+    compile it hides) with zero index vectors: row 0 is a valid gather
+    everywhere, and the warm call sees exactly the shardings of the
+    streaming loop's calls."""
     for sig in sigs:
         if sig.data_plane == "bank":
             idx = (np.zeros((sig.b_pad,), np.int32),

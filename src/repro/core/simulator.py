@@ -125,8 +125,8 @@ and sharer invalidations are added to the exposed coherence latency
 (the ``w`` side of the max-plus recurrence absorbs them through the
 store's ready time), persist barriers to the REPL-ack / drain terms
 (the ``v`` side), all inside :func:`_make_cell_arrays` BEFORE the
-collapse -- so every engine tier, both data planes and the Pallas
-kernel work unchanged and stay bit-identical. Active axes append the
+collapse -- so every engine tier and both data planes work unchanged
+and stay bit-identical. Active axes append the
 resolved params to the bank's max-plus row key; all-``None`` axes
 change neither outputs nor dedup keys, bit-for-bit.
 
@@ -149,8 +149,8 @@ span on the arrival clock. Each epoch's waiting time (carried backlog
 + an M/D/1 in-epoch wait) is folded into every directory-transacting
 store's ``w`` side (:func:`_directory_delay_row`, host-side inside
 :func:`_make_cell_arrays` BEFORE the collapse) -- so the level-1
-collapse, every engine tier, both data planes and the Pallas kernel
-again work unchanged. ``directory_load=None`` keeps outputs AND dedup
+collapse, every engine tier and both data planes again work
+unchanged. ``directory_load=None`` keeps outputs AND dedup
 keys bit-identical; active coupling appends the resolved
 :class:`~repro.core.directory.DirectoryParams` to the wv key, so cells
 sharing a (shard, epoch-profile) still dedup to one bank row / scan

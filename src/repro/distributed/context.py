@@ -15,6 +15,7 @@ from dataclasses import dataclass
 from typing import Iterator, Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 @dataclass(frozen=True)
@@ -62,41 +63,19 @@ def mesh_context(ctx: MeshContext) -> Iterator[MeshContext]:
 
 def make_mesh(axis_shapes: Tuple[int, ...], axis_names: Tuple[str, ...],
               devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions: newer releases want explicit
-    ``axis_types`` (Auto) for the shard_map regions; older ones (<= 0.4.x)
-    have neither the kwarg nor ``jax.sharding.AxisType``."""
+    """``jax.make_mesh`` with every axis ``Auto``, the axis type the
+    ``shard_map`` regions and the sharded jits expect."""
     kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names), **kwargs)
-        except TypeError:
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    # jax < 0.4.35: no jax.make_mesh at all
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                         devices=devices)
-    return jax.sharding.Mesh(devs, tuple(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         **kwargs)
 
 
 def shard_map(f, mesh: jax.sharding.Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the top-level API (with
-    ``check_vma``) landed after 0.4.x, where the same transform lives in
-    ``jax.experimental.shard_map`` and the kwarg is ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:          # releases where the kwarg is check_rep
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off: the
+    callers' bodies are lane-wise or issue their collectives by hand."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @functools.lru_cache(maxsize=8)

@@ -7,8 +7,9 @@
 * ``ssd_scan``      -- Mamba-2 SSD chunked scan in matmul form.
 
 Each kernel ships ``kernel.py`` (pl.pallas_call + BlockSpec), ``ops.py``
-(jit'd wrapper with a pure-jnp fallback for non-TPU backends) and
-``ref.py`` (the oracle the tests sweep against). Kernels are validated
-with ``interpret=True`` on CPU; on real TPUs ``ops.py`` selects the
-compiled kernel.
+(jit'd wrapper with a pure-jnp fallback) and ``ref.py`` (the oracle the
+tests sweep against). The tests run them with ``interpret=True`` on the
+CPU; none has been compiled for or run on a TPU. They serve the
+training-side stack only: no simulator path calls them (the bank scan
+is the XLA tile program of ``repro.core.engine``).
 """

@@ -22,6 +22,28 @@ import os
 import time
 
 
+def query_stream(n_queries: int, seed: int = 0):
+    """The daemon's demo traffic: ``(warm_grid, stream)``.
+
+    ``warm_grid`` is a 360-cell mixed-SB sweep; ``stream`` holds
+    ``n_queries`` seeded picks, 70% cells of the warm grid (lane-cache
+    hits) and 30% novel cells from a grid delta (bank-diff misses)."""
+    import numpy as np
+
+    from repro.core.scenarios import grid_delta, sweep_grid
+
+    warm_grid = sweep_grid(seeds=(0, 1), sb_sizes=(None, 48),
+                           link_bw_gbps=(None, 40.0))
+    novel = grid_delta(warm_grid, workloads=("ycsb", "canneal", "barnes"),
+                       configs=("proactive", "baseline"),
+                       n_replicas=(2, 4), sb_sizes=(None, 48))
+    rng = np.random.default_rng(seed)
+    stream = [warm_grid[rng.integers(len(warm_grid))] if rng.random() < 0.7
+              else novel[rng.integers(len(novel))]
+              for _ in range(n_queries)]
+    return warm_grid, stream
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--stores", type=int, default=5_000,
@@ -63,25 +85,18 @@ def main() -> None:
 
     import numpy as np
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from repro.core import chaos
     from repro.core import telemetry
     from repro.core.engine import simulate_grid, trace_count
-    from repro.core.scenarios import grid_delta, sweep_grid
     from repro.core.serving import ScenarioServer
 
     if args.trace_out:
         telemetry.enable()
 
-    warm_grid = sweep_grid(seeds=(0, 1), sb_sizes=(None, 48),
-                           link_bw_gbps=(None, 40.0))
-    novel = grid_delta(warm_grid, workloads=("ycsb", "canneal", "barnes"),
-                       configs=("proactive", "baseline"),
-                       n_replicas=(2, 4), sb_sizes=(None, 48))
-
-    rng = np.random.default_rng(args.seed)
-    stream = [warm_grid[rng.integers(len(warm_grid))] if rng.random() < 0.7
-              else novel[rng.integers(len(novel))]
-              for _ in range(args.queries)]
+    warm_grid, stream = query_stream(args.queries, args.seed)
 
     # arm far out so the warm phase runs clean, then re-arm a couple of
     # dispatches into the query stream once warm's dispatch count is known

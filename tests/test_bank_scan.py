@@ -1,28 +1,29 @@
-"""Differential tests for the fused bank-gather + scan kernel.
+"""The banked blocked scan against the serial oracle, bit for bit.
 
-Three implementations must agree **bit-for-bit** on real bank columns:
-the Pallas kernel (CPU interpreter mode -- the same kernel the TPU
-path compiles), the self-contained pure-jax ``ref.py`` oracle, and the
-simulator's banked blocked scan (``_timeline_banked``). Chunk sizes
-sweep ragged tails, chunk == sb, and chunk 1.
+``_timeline_banked`` is the in-jit bank gather (``_bank_gather``) plus
+the shared ``_scan_wv`` core: the program every banked tile runs, on
+every backend. On real bank columns its per-cell results must equal the
+serial ``simulate()`` oracle ``==``. Chunk sizes sweep a one-store
+block, a ragged tail, chunk == SB, and a chunk past SB that the tile
+planner clamps to SB.
 """
 
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
+from repro.core.engine import plan_tiles
 from repro.core.simulator import (
     CONFIGS,
     PAPER_CLUSTER,
     ScenarioSpec,
     _banked_inputs,
+    _finish_result,
     _timeline_banked,
     get_trace_bank,
+    simulate_spec,
 )
-from repro.kernels.bank_scan import bank_scan, bank_scan_backend
-from repro.kernels.bank_scan.ref import bank_scan_ref
 
 N = 500                                  # ragged vs every chunk below
 SB = 24
@@ -39,45 +40,21 @@ def banked_grid():
     assert sb_uniform == SB
     assert n_lanes == len(specs)         # all-distinct lanes in this grid
     args = tuple(jnp.asarray(x) for x in
-                 (bank.arrivals, bank.w, bank.v, bank.pr_nc))
-    return args, jnp.asarray(tr), jnp.asarray(wv), jnp.asarray(sb_arr), sb_max
+                 (bank.arrivals, bank.w, bank.v, bank.pr_nc)) + tuple(
+        jnp.asarray(x) for x in (tr, wv, sb_arr))
+    oracle = [simulate_spec(s, n_stores=N) for s in specs]
+    return specs, cells, cell_lane, args, sb_max, oracle
 
 
-def _assert_tuple_identical(got, want, ctx):
-    for g, w, name in zip(got, want, ("exec", "at_head", "sb_full")):
-        assert np.array_equal(np.asarray(g), np.asarray(w)), (ctx, name)
-
-
-@pytest.mark.parametrize("chunk", [1, 7, SB])
-def test_pallas_interpret_matches_ref(banked_grid, chunk):
-    args, tr, wv, _, _ = banked_grid
-    ref = bank_scan_ref(*args, tr, wv, chunk=chunk, sb=SB)
-    pal = bank_scan(*args, tr, wv, chunk=chunk, sb=SB,
-                    force="pallas_interpret")
-    _assert_tuple_identical(pal, ref, f"chunk={chunk}")
-
-
-@pytest.mark.parametrize("chunk", [7, SB])
-def test_ref_matches_simulator_banked_scan(banked_grid, chunk):
-    args, tr, wv, sb_arr, sb_max = banked_grid
-    ref = bank_scan_ref(*args, tr, wv, chunk=chunk, sb=SB)
-    sim = _timeline_banked(*args, tr, wv, sb_arr, sb_max, chunk, SB)
-    _assert_tuple_identical(ref, sim, f"chunk={chunk}")
-
-
-def test_chunk_clamped_to_sb_and_trace(banked_grid):
-    args, tr, wv, _, _ = banked_grid
-    # chunk > sb clamps to sb; chunk > n clamps to the trace
-    a = bank_scan_ref(*args, tr, wv, chunk=4 * SB, sb=SB)
-    b = bank_scan_ref(*args, tr, wv, chunk=SB, sb=SB)
-    _assert_tuple_identical(a, b, "clamp")
-
-
-def test_backend_selection(monkeypatch):
-    monkeypatch.delenv("RECXL_BANK_SCAN", raising=False)
-    want = "pallas" if jax.default_backend() == "tpu" else "jax"
-    assert bank_scan_backend() == want
-    monkeypatch.setenv("RECXL_BANK_SCAN", "pallas")
-    assert bank_scan_backend() == "pallas"
-    monkeypatch.setenv("RECXL_BANK_SCAN", "jax")
-    assert bank_scan_backend() == "jax"
+@pytest.mark.parametrize("chunk", [1, 7, SB, 4 * SB])
+def test_banked_scan_matches_serial_oracle(banked_grid, chunk):
+    specs, cells, cell_lane, args, sb_max, oracle = banked_grid
+    # a block may not look past the SB ring: the planner clamps to SB
+    (eff,) = {t.sig.chunk for t in plan_tiles(specs, n_stores=N,
+                                              chunk_size=chunk)}
+    assert eff == min(chunk, SB)
+    exec_ns, at_head, sb_full = (np.asarray(x) for x in _timeline_banked(
+        *args, sb_max, eff, SB))
+    got = [_finish_result(c, exec_ns[j], int(at_head[j]), int(sb_full[j]))
+           for c, j in zip(cells, cell_lane)]
+    assert got == oracle, f"chunk={chunk} (effective {eff})"
