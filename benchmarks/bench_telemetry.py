@@ -96,8 +96,8 @@ def bench_telemetry() -> List[Dict]:
     clear_sim_caches()
     E.run_grid(specs, n_stores=MEGA_STORES)       # warm compiles + memos
 
-    # one traced run feeds the per-stage breakdown, protocol counters
-    # and the Chrome-trace round-trip
+    # one traced run feeds the per-stage breakdown and the Chrome-trace
+    # round-trip
     with telemetry.recording() as rec:
         res_on = E.run_grid(specs, n_stores=MEGA_STORES)
         summ = rec.summary()
@@ -141,7 +141,6 @@ def bench_telemetry() -> List[Dict]:
         res_on[i] == simulate_spec(specs[i], n_stores=MEGA_STORES)
         for i in sample)
 
-    counters = summ["counters"]
     rows += [
         _row("grid_cells", n),
         _row("stores_per_cell", MEGA_STORES),
@@ -155,10 +154,6 @@ def bench_telemetry() -> List[Dict]:
              us=stage_s * 1e6 / max(n, 1)),
         _row("telemetry_overhead_ratio", round(t_on / t_off, 3),
              us=t_on * 1e6 / max(n, 1)),
-        _row("proto_repl_msgs", int(counters.get("proto/repl_msgs", 0))),
-        _row("proto_log_unit_mb",
-             round(counters.get("proto/log_unit_bytes", 0.0)
-                   / (1 << 20), 1)),
         _row("trace_events", n_events),
         _row("trace_valid", trace_valid),
     ]

@@ -812,6 +812,19 @@ def run_grid(specs: Sequence[ScenarioSpec],
     unfinished cells on a mesh shrunk by one shard with the bank
     replicated (one recompile, kept serving).
     """
+    with _tm.span("engine/run", cells=len(specs)):
+        return _run_grid(specs, cluster, n_stores, chunk_size, tile_cells,
+                         n_shards, data_plane, bank_partition, k_replicas,
+                         worker_timeout_s)
+
+
+def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
+              n_stores: int, chunk_size: Optional[int],
+              tile_cells: Optional[int], n_shards: Optional[int],
+              data_plane: Optional[str], bank_partition: Optional[str],
+              k_replicas: Optional[int],
+              worker_timeout_s: Optional[float]) -> List[SimResult]:
+    """:func:`run_grid`'s body, inside its ``engine/run`` span."""
     if not specs:
         return []
     if chunk_size is not None and chunk_size < 1:
@@ -837,89 +850,95 @@ def run_grid(specs: Sequence[ScenarioSpec],
         n_shards = n_dev
     if not 1 <= n_shards <= n_dev:
         raise ValueError(f"n_shards must be in [1, {n_dev}], got {n_shards}")
-    for s in specs:
-        s.validate(cluster)
 
     from repro.core.simulator import _plane_keys, bank_row_maps
 
-    plan_kw = dict(cluster=cluster, n_stores=n_stores, chunk_size=chunk_size,
-                   tile_cells=tile_cells or _default_tile_cells(n_stores),
-                   n_shards=n_shards)
     bank = bank_dev = None
     bank_fresh = 0
     sub = False
     k_eff = 1
     local_rows = 0
     lane_members: List[List[int]] = []
-    if plane == "bank":
-        # --- scan-lane dedup -------------------------------------------
-        # A cell's timeline consumes exactly (arrivals row, max-plus
-        # row, SB depth) -- nothing else. Cells sharing that triple
-        # (e.g. the whole CN axis of a sweep, or WB/WT cells across
-        # replication knobs) therefore have bit-identical timelines:
-        # the engine scans each unique LANE once and scatters the lane
-        # outputs to every member cell (work_scale and the bandwidth /
-        # log metrics are per-cell host math in ``_finish_result``, as
-        # on every other tier). The mega-grid's 12 960 cells collapse
-        # to ~2 700 scanned lanes.
-        lane_of: Dict[tuple, int] = {}
-        lane_specs: List[ScenarioSpec] = []
-        lane_wv_keys: List[tuple] = []
-        for i, s in enumerate(specs):
-            sb = s.sb_size if s.sb_size is not None else cluster.store_buffer
-            key = (sb,) + _plane_keys(s, cluster)
-            j = lane_of.setdefault(key, len(lane_specs))
-            if j == len(lane_specs):
-                lane_specs.append(s)
-                lane_wv_keys.append(key[2])
-                lane_members.append([i])
-            else:
-                lane_members[j].append(i)
-        # the bank's SHAPE comes from a cheap key pass, so the tile
-        # signatures -- and therefore compile warming -- do not wait
-        # for the heavy row materialization below
-        trace_map, wv_map = bank_row_maps(specs, cluster)
-        sub = partition == "sub"
-        if sub:
-            # per-shard sub-banks: the signature carries the LOCAL
-            # (per-shard) wv row count, and the scheduler places each
-            # lane in the slot block of the shard owning its wv row.
-            # k_eff > 1 (chaos/recovery runs only) appends the Replica
-            # set blocks along the local axis -- the signature sees
-            # the widened stack (jit specializes on the bank shape),
-            # while indices keep targeting the primary block
-            k_eff = _chaos.resolve_k_replicas(k_replicas, n_shards)
-            local_rows = sub_bank_rows(len(wv_map), n_shards)
-            shape = (len(trace_map), k_eff * local_rows)
-            owners = [wv_map[wk] % n_shards for wk in lane_wv_keys]
-        else:
-            shape = (len(trace_map), len(wv_map))
-            owners = None
-        tiles = [dataclasses.replace(
-            t, sig=dataclasses.replace(t.sig, data_plane="bank",
-                                       bank_shape=shape, bank_sub=sub))
-            for t in plan_tiles(lane_specs, small_pad=False, owners=owners,
-                                **plan_kw)]
-    else:
-        tiles = plan_tiles(specs, **plan_kw)
     costs = _commit_cost_ns("proactive", cluster)
     t_l1 = np.float32(costs["t_l1"])
     t_wt = np.float32(costs["t_wt"])
-
     results: List[Optional[SimResult]] = [None] * len(specs)
 
     # --- data-plane accounting (bank_stats / SimResult.meta) -----------
     def tile_payload_bytes(sig: TileSignature) -> int:
         return 8 * sig.b_pad if plane == "bank" else _stacked_tile_bytes(sig)
 
-    # what the stacked plane would ship for the SAME grid (it tiles
-    # cells, not lanes) -- the dedup_ratio baseline, counted from the
-    # per-SB group sizes without materializing a throwaway tiling
-    if plane == "bank":
-        stacked_h2d = _stacked_plane_h2d(specs, cluster, n_stores,
-                                         plan_kw["tile_cells"], n_shards)
-    else:
-        stacked_h2d = sum(_stacked_tile_bytes(t.sig) for t in tiles)
+    with _tm.span("engine/plan"):
+        for s in specs:
+            s.validate(cluster)
+        plan_kw = dict(cluster=cluster, n_stores=n_stores,
+                       chunk_size=chunk_size,
+                       tile_cells=tile_cells or _default_tile_cells(n_stores),
+                       n_shards=n_shards)
+        if plane == "bank":
+            # --- scan-lane dedup ---------------------------------------
+            # A cell's timeline consumes exactly (arrivals row, max-plus
+            # row, SB depth) -- nothing else. Cells sharing that triple
+            # (e.g. the whole CN axis of a sweep, or WB/WT cells across
+            # replication knobs) therefore have bit-identical timelines:
+            # the engine scans each unique LANE once and scatters the
+            # lane outputs to every member cell (work_scale and the
+            # bandwidth / log metrics are per-cell host math in
+            # ``_finish_result``, as on every other tier). The
+            # mega-grid's 12 960 cells collapse to ~2 700 scanned lanes.
+            lane_of: Dict[tuple, int] = {}
+            lane_specs: List[ScenarioSpec] = []
+            lane_wv_keys: List[tuple] = []
+            for i, s in enumerate(specs):
+                sb = s.sb_size if s.sb_size is not None \
+                    else cluster.store_buffer
+                key = (sb,) + _plane_keys(s, cluster)
+                j = lane_of.setdefault(key, len(lane_specs))
+                if j == len(lane_specs):
+                    lane_specs.append(s)
+                    lane_wv_keys.append(key[2])
+                    lane_members.append([i])
+                else:
+                    lane_members[j].append(i)
+            # the bank's SHAPE comes from a cheap key pass, so the tile
+            # signatures -- and therefore compile warming -- do not wait
+            # for the heavy row materialization below
+            trace_map, wv_map = bank_row_maps(specs, cluster)
+            sub = partition == "sub"
+            if sub:
+                # per-shard sub-banks: the signature carries the LOCAL
+                # (per-shard) wv row count, and the scheduler places each
+                # lane in the slot block of the shard owning its wv row.
+                # k_eff > 1 (chaos/recovery runs only) appends the
+                # Replica set blocks along the local axis -- the
+                # signature sees the widened stack (jit specializes on
+                # the bank shape), while indices keep targeting the
+                # primary block
+                k_eff = _chaos.resolve_k_replicas(k_replicas, n_shards)
+                local_rows = sub_bank_rows(len(wv_map), n_shards)
+                shape = (len(trace_map), k_eff * local_rows)
+                owners = [wv_map[wk] % n_shards for wk in lane_wv_keys]
+            else:
+                shape = (len(trace_map), len(wv_map))
+                owners = None
+            tiles = [dataclasses.replace(
+                t, sig=dataclasses.replace(t.sig, data_plane="bank",
+                                           bank_shape=shape, bank_sub=sub))
+                for t in plan_tiles(lane_specs, small_pad=False,
+                                    owners=owners, **plan_kw)]
+            # what the stacked plane would ship for the SAME grid (it
+            # tiles cells, not lanes) -- the dedup_ratio baseline,
+            # counted from the per-SB group sizes without materializing
+            # a throwaway tiling
+            stacked_h2d = _stacked_plane_h2d(specs, cluster, n_stores,
+                                             plan_kw["tile_cells"], n_shards)
+        else:
+            tiles = plan_tiles(specs, **plan_kw)
+            stacked_h2d = sum(_stacked_tile_bytes(t.sig) for t in tiles)
+    _tm.count("engine/cells", len(specs))
+    _tm.count("engine/lanes", len(lane_members) if plane == "bank"
+              else len(specs))
+    _tm.count("engine/tiles", len(tiles))
     h2d_bytes = sum(tile_payload_bytes(t.sig) for t in tiles)
     live_bytes = 0
     hwm_bytes = 0
@@ -977,23 +996,25 @@ def run_grid(specs: Sequence[ScenarioSpec],
         live_bytes -= tile_payload_bytes(tile.sig)
         slots = tile.slots if tile.slots is not None \
             else range(len(tile.indices))
-        for group, pos in zip(groups, slots):
-            for i, cell in group:
-                meta = {"engine": ("sharded" if tile.sig.n_shards > 1
-                                   else "streamed"),
-                        "chunk": tile.sig.chunk,
-                        "auto_chunk": chunk_size is None,
-                        "tile_cells": tile.sig.b_pad,
-                        "n_shards": tile.sig.n_shards,
-                        "data_plane": plane,
-                        "bank_partition": (partition if plane == "bank"
-                                           else None),
-                        "bank_rows": bank.n_rows if bank is not None else 0,
-                        "h2d_bytes": h2d_bytes,
-                        "bank_fabric_bytes": fabric_bytes}
-                results[i] = _finish_result(cell, exec_ns[pos],
-                                            int(at_head[pos]),
-                                            int(sb_full[pos]), meta=meta)
+        with _tm.span("tile/finish", tile=kt):
+            for group, pos in zip(groups, slots):
+                for i, cell in group:
+                    meta = {"engine": ("sharded" if tile.sig.n_shards > 1
+                                       else "streamed"),
+                            "chunk": tile.sig.chunk,
+                            "auto_chunk": chunk_size is None,
+                            "tile_cells": tile.sig.b_pad,
+                            "n_shards": tile.sig.n_shards,
+                            "data_plane": plane,
+                            "bank_partition": (partition if plane == "bank"
+                                               else None),
+                            "bank_rows": (bank.n_rows if bank is not None
+                                          else 0),
+                            "h2d_bytes": h2d_bytes,
+                            "bank_fabric_bytes": fabric_bytes}
+                    results[i] = _finish_result(cell, exec_ns[pos],
+                                                int(at_head[pos]),
+                                                int(sb_full[pos]), meta=meta)
         done[kt] = True
 
     # --- resilience plumbing (inert without an active chaos scope) -----

@@ -345,7 +345,8 @@ class ScenarioServer:
             return
         if self._journal_wanted():
             self._bank.enable_journal()
-        nt, nw = self._bank.extend(specs)
+        with _tm.span("serve/bank_extend"):
+            nt, nw = self._bank.extend(specs)
         self._stats["appended_trace_rows"] += nt
         self._stats["appended_wv_rows"] += nw
 

@@ -491,10 +491,6 @@ class _CellInputs:
     max_log_bytes: float
     cxl_mem_bw_gbps: float
     log_dump_bw_gbps: float
-    # background utilization of this cell's shared directory shard
-    # (DirectoryParams.rho_bg; 0.0 with the directory axis off) --
-    # surfaced as the paper-facing queue-occupancy telemetry counter
-    dir_occupancy: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1103,17 +1099,25 @@ def bank_row_maps(specs: Sequence[ScenarioSpec],
 
 def _make_trace_bank(specs: Tuple[ScenarioSpec, ...], n_stores: int,
                      cluster: ClusterConfig) -> TraceBank:
-    trace_row, wv_row = bank_row_maps(specs, cluster)
-    a_rows = [_trace_cached(w, n_stores, seed, cluster)["arrivals"]
-              for (w, seed) in trace_row]
-    wv_rows = [_wv_row(k, n_stores, cluster) for k in wv_row]
-    return TraceBank(
-        n_stores=n_stores, cluster=cluster,
-        arrivals=np.stack(a_rows, axis=0),
-        w=np.stack([c[0] for c in wv_rows], axis=0),
-        v=np.stack([c[1] for c in wv_rows], axis=0),
-        pr_nc=np.stack([c[2] for c in wv_rows], axis=0),
-        trace_row=trace_row, wv_row=wv_row)
+    with _tm.span("bank/build", cells=len(specs)):
+        trace_row, wv_row = bank_row_maps(specs, cluster)
+        with _tm.span("bank/synth", rows=len(trace_row)):
+            a_rows = [_trace_cached(w, n_stores, seed, cluster)["arrivals"]
+                      for (w, seed) in trace_row]
+        misses = _WV_ROW_CACHE.misses
+        with _tm.span("bank/rows", rows=len(wv_row)):
+            wv_rows = [_wv_row(k, n_stores, cluster) for k in wv_row]
+        _tm.count("bank/trace_rows", len(trace_row))
+        _tm.count("bank/wv_rows", len(wv_row))
+        _tm.count("bank/wv_rows_built", _WV_ROW_CACHE.misses - misses)
+        with _tm.span("bank/stack"):
+            return TraceBank(
+                n_stores=n_stores, cluster=cluster,
+                arrivals=np.stack(a_rows, axis=0),
+                w=np.stack([c[0] for c in wv_rows], axis=0),
+                v=np.stack([c[1] for c in wv_rows], axis=0),
+                pr_nc=np.stack([c[2] for c in wv_rows], axis=0),
+                trace_row=trace_row, wv_row=wv_row)
 
 
 def get_trace_bank(specs: Sequence[ScenarioSpec], n_stores: int,
@@ -1124,9 +1128,10 @@ def get_trace_bank(specs: Sequence[ScenarioSpec], n_stores: int,
     the streaming engine running the same grid share ONE bank handle
     (and therefore one device upload per placement) across engine
     switches. :func:`clear_sim_caches` drops it."""
-    key = ("bank",) + _specs_key(tuple(specs), n_stores, cluster)
-    return _BANK_CACHE.get_or_put(
-        key, lambda: _make_trace_bank(tuple(specs), n_stores, cluster))
+    with _tm.span("bank/get"):
+        key = ("bank",) + _specs_key(tuple(specs), n_stores, cluster)
+        return _BANK_CACHE.get_or_put(
+            key, lambda: _make_trace_bank(tuple(specs), n_stores, cluster))
 
 
 def _prepare_cell(spec: ScenarioSpec, trace: Dict[str, np.ndarray],
@@ -1179,7 +1184,6 @@ def _prepare_cell(spec: ScenarioSpec, trace: Dict[str, np.ndarray],
         max_log_bytes=log_bytes,
         cxl_mem_bw_gbps=arr.mem_demand * ncn,
         log_dump_bw_gbps=(dump_bw * ncn if replicating else 0.0),
-        dir_occupancy=float(dirp.rho_bg) if dirp is not None else 0.0,
     )
 
 
@@ -1187,25 +1191,6 @@ def _finish_result(cell: _CellInputs, exec_ns: float, at_head: int,
                    sb_full: int,
                    meta: Optional[Dict[str, object]] = None) -> SimResult:
     n = cell.n_stores
-    rec = _tm.active()
-    if rec is not None:
-        # paper-facing simulated protocol counters: every tier funnels
-        # its cells through this epilogue, so a traced run reports the
-        # same per-cell quantities the paper's figures plot (SS VII/
-        # VIII), regardless of which engine produced the timeline.
-        # Units: messages / bytes per dump period / GB/s / utilization.
-        # ev=False: aggregate-only -- at mega-grid scale this path runs
-        # tens of thousands of times per traced run, and per-cell ring
-        # events would both wrap the tape and dominate the recorder's
-        # overhead budget (the <= 1.05 bench pin).
-        rec.count("proto/cells", 1, ev=False)
-        rec.count("proto/repl_msgs", cell.n_repl_msgs, ev=False)
-        rec.count("proto/log_unit_bytes", cell.max_log_bytes, ev=False)
-        rec.observe("proto/dump_bw_gbps", cell.log_dump_bw_gbps, ev=False)
-        rec.observe("proto/cxl_mem_bw_gbps", cell.cxl_mem_bw_gbps,
-                    ev=False)
-        rec.observe("proto/dir_queue_occupancy", cell.dir_occupancy,
-                    ev=False)
     return SimResult(
         workload=cell.spec.workload,
         config=cell.spec.config,

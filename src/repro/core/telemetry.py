@@ -30,6 +30,18 @@ when full, the oldest half is dropped in one slice — a flight recorder
 keeps the most recent window.  Aggregates (histograms, counters) are
 kept separately and survive ring wrap.
 
+**One clock with the device trace.**  While a recorder is live, every
+span also enters a ``jax.profiler.TraceAnnotation`` of the same name
+(its args become the event's stats), so a ``jax.profiler`` trace taken
+at the same time carries the program's spans on its host plane, beside
+the device operations.  The recorder also installs two hooks while it
+is live: a ``gc.callbacks`` entry that records every cyclic collection
+as a ``host/gc`` span (plus the ``host/gc_collections`` counter), and a
+``jax.monitoring`` listener that counts XLA backend compiles
+(``jax/compiles``, ``jax/compile_s``).  Both go when no recorder is
+live.  ``jax.profiler`` is imported on the first recorder, not at
+module import.
+
 **Export.**  ``export_chrome(path)`` writes Chrome trace-event JSONL —
 one event object per line — loadable at https://ui.perfetto.dev.
 ``summary()`` merges every thread into one plain dict (the thing that
@@ -45,6 +57,7 @@ Span taxonomy and counter units are documented in
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -96,7 +109,7 @@ class _ThreadLog:
     """One thread's ring buffer + aggregates.  Owner-thread-only writes."""
 
     __slots__ = ("tid", "os_tid", "name", "cap", "events", "n_dropped",
-                 "stack", "spans", "dists", "counters", "gauges")
+                 "stack", "spans", "dists", "counters", "gauges", "gc")
 
     def __init__(self, tid: int, os_tid: Optional[int], name: str,
                  cap: int) -> None:
@@ -119,6 +132,8 @@ class _ThreadLog:
         self.counters: Dict[str, float] = {}
         # name -> (t_ns, value): last-wins merged by timestamp
         self.gauges: Dict[str, Tuple[int, float]] = {}
+        # (t0_ns, annotation) of the collection in progress, or None
+        self.gc: Optional[Tuple[int, Any]] = None
 
     def push(self, ev: Tuple[str, int, str, Any]) -> None:
         if len(self.events) >= self.cap:
@@ -140,10 +155,23 @@ def _obs(table: Dict[str, List[Any]], name: str, value: float) -> None:
         st[3].append(value)
 
 
-class _Span:
-    """Live span: records B/E events and feeds the duration histogram."""
+#: ``jax.profiler.TraceAnnotation``, bound by the first :class:`Recorder`.
+_ANNOTATION: Any = None
 
-    __slots__ = ("_rec", "_name", "_args", "_log", "_t0")
+
+def _annotation(name: str, args: Optional[Dict[str, Any]]) -> Any:
+    """An entered profiler annotation (a no-op unless a profiler trace
+    is being taken)."""
+    ann = _ANNOTATION(name, **args) if args else _ANNOTATION(name)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    """Live span: records B/E events and feeds the duration histogram;
+    the same interval is a profiler annotation of the same name."""
+
+    __slots__ = ("_rec", "_name", "_args", "_log", "_t0", "_ann")
 
     def __init__(self, rec: "Recorder", name: str,
                  args: Optional[Dict[str, Any]]) -> None:
@@ -152,6 +180,7 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
+        self._ann = _annotation(self._name, self._args)
         log = self._rec._log()
         self._log = log
         t0 = time.perf_counter_ns()
@@ -168,6 +197,7 @@ class _Span:
             log.stack.pop()
         log.push(("E", t1, self._name, None))
         _obs(log.spans, self._name, t1 - self._t0)
+        self._ann.__exit__(None, None, None)
         return False
 
 
@@ -175,6 +205,10 @@ class Recorder:
     """A telemetry session: per-thread logs plus merge/export views."""
 
     def __init__(self, ring_events: int = DEFAULT_RING_EVENTS) -> None:
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
         self.ring_events = int(ring_events)
         self.pid = os.getpid()
         self.t0_ns = time.perf_counter_ns()
@@ -199,16 +233,11 @@ class Recorder:
              args: Optional[Dict[str, Any]] = None) -> _Span:
         return _Span(self, name, args)
 
-    def count(self, name: str, n: float = 1, ev: bool = True) -> None:
-        """``ev=False`` updates the aggregate only (no ring event):
-        the cheap mode for per-cell hot paths -- a counter sampled tens
-        of thousands of times per run would wrap the event tape anyway,
-        and its ``summary()`` total is what consumers read."""
+    def count(self, name: str, n: float = 1) -> None:
         log = self._log()
         total = log.counters.get(name, 0) + n
         log.counters[name] = total
-        if ev:
-            log.push(("C", time.perf_counter_ns(), name, total))
+        log.push(("C", time.perf_counter_ns(), name, total))
 
     def gauge(self, name: str, value: float) -> None:
         log = self._log()
@@ -216,11 +245,10 @@ class Recorder:
         log.gauges[name] = (t, value)
         log.push(("C", t, name, value))
 
-    def observe(self, name: str, value: float, ev: bool = True) -> None:
+    def observe(self, name: str, value: float) -> None:
         log = self._log()
         _obs(log.dists, name, value)
-        if ev:
-            log.push(("X", time.perf_counter_ns(), name, value))
+        log.push(("X", time.perf_counter_ns(), name, value))
 
     # -- merge / export --------------------------------------------------
 
@@ -349,6 +377,67 @@ def _pct(sorted_xs: List[float], q: float) -> float:
 
 _RECORDER: Optional[Recorder] = None
 
+#: The monitoring event of one XLA backend compile (its duration in s).
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _gc_hook(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` entry: one ``host/gc`` span per collection on
+    the collecting thread.  Never takes the recorder's registration
+    lock (a collection can start inside it), so a thread that has no
+    log yet drops the sample."""
+    rec = _RECORDER
+    if rec is None:
+        return
+    log = getattr(rec._tls, "log", None)
+    if log is None:
+        return
+    if phase == "start":
+        args = {"generation": info.get("generation", -1)}
+        ann = _annotation("host/gc", args)
+        t0 = time.perf_counter_ns()
+        log.gc = (t0, ann)
+        log.push(("B", t0, "host/gc", args))
+    elif log.gc is not None:
+        t0, ann = log.gc
+        log.gc = None
+        t1 = time.perf_counter_ns()
+        log.push(("E", t1, "host/gc", None))
+        _obs(log.spans, "host/gc", t1 - t0)
+        log.counters["host/gc_collections"] = \
+            log.counters.get("host/gc_collections", 0) + 1
+        ann.__exit__(None, None, None)
+
+
+def _compile_hook(event: str, secs: float, **kw: Any) -> None:
+    """``jax.monitoring`` listener: counts XLA backend compiles."""
+    rec = _RECORDER
+    if rec is not None and event == _COMPILE_EVENT:
+        rec.count("jax/compiles")
+        rec.observe("jax/compile_s", secs)
+
+
+_HOOKED = False
+
+
+def _set_recorder(rec: Optional[Recorder]) -> None:
+    """Make ``rec`` the live recorder, installing the gc and compile
+    hooks when one goes live and removing them when none is."""
+    global _RECORDER, _HOOKED
+    _RECORDER = rec
+    if (rec is not None) == _HOOKED:
+        return
+    import jax.monitoring
+
+    if rec is not None:
+        gc.callbacks.append(_gc_hook)
+        jax.monitoring.register_event_duration_secs_listener(_compile_hook)
+    else:
+        if _gc_hook in gc.callbacks:
+            gc.callbacks.remove(_gc_hook)
+        jax.monitoring.unregister_event_duration_listener(_compile_hook)
+    _HOOKED = rec is not None
+
 
 def active() -> Optional[Recorder]:
     """The live :class:`Recorder`, or ``None`` when telemetry is off."""
@@ -361,35 +450,32 @@ def enabled() -> bool:
 
 def enable(ring_events: int = DEFAULT_RING_EVENTS) -> Recorder:
     """Turn telemetry on (idempotent); returns the recorder."""
-    global _RECORDER
     if _RECORDER is None:
-        _RECORDER = Recorder(ring_events)
+        _set_recorder(Recorder(ring_events))
     return _RECORDER
 
 
 def disable() -> None:
-    global _RECORDER
-    _RECORDER = None
+    _set_recorder(None)
 
 
 def reset(ring_events: int = DEFAULT_RING_EVENTS) -> Recorder:
     """Drop all recorded data and start a fresh (enabled) recorder."""
-    global _RECORDER
-    _RECORDER = Recorder(ring_events)
-    return _RECORDER
+    rec = Recorder(ring_events)
+    _set_recorder(rec)
+    return rec
 
 
 @contextlib.contextmanager
 def recording(ring_events: int = DEFAULT_RING_EVENTS):
     """Scoped enable: fresh recorder inside, previous state restored."""
-    global _RECORDER
     prev = _RECORDER
     rec = Recorder(ring_events)
-    _RECORDER = rec
+    _set_recorder(rec)
     try:
         yield rec
     finally:
-        _RECORDER = prev
+        _set_recorder(prev)
 
 
 def span(name: str, **args: Any) -> Union[_Span, _NoopSpan]:

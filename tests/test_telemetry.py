@@ -17,13 +17,21 @@ Two families:
   chaos-injected shard loss emits the
   detection -> rollback -> rebuild -> re-place -> re-dispatch timeline
   in exactly that order.
+
+Plus the recorder's place on the profiler's clock: its spans reach a
+``jax.profiler`` trace, and the gc / compile hooks it installs while
+live are gone when it is not.
 """
 
+import gc
+import glob
 import json
+import threading
 
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import chaos
 from repro.core import engine as E
@@ -177,7 +185,8 @@ def test_traced_run_grid_bitident_keys_bank_and_compiles():
     assert get_trace_bank(GRID, N, PAPER_CLUSTER).nbytes == bank_off
     # and the traced run actually observed the pipeline
     assert summ["spans"]["tile/dispatch"]["count"] >= 1
-    assert summ["counters"]["proto/cells"] == len(GRID)
+    assert summ["spans"]["engine/plan"]["count"] == 1
+    assert summ["spans"]["engine/run"]["count"] == 1
     assert res_on[0].meta["telemetry"] is not None
     # tracing may annotate meta, but == ignores it by contract
     assert "telemetry" not in (res_off[0].meta or {})
@@ -265,16 +274,167 @@ def test_chaos_recovery_timeline_span_order():
                           {"count": 0})["count"] >= 1
 
 
-def test_protocol_counters_flow_from_finish_result():
+# ------------------------------------------- bank build, plan and finish
+
+def test_bank_build_spans_and_row_counters():
     clear_sim_caches()
     with tm.recording() as rec:
-        res = E.run_grid(GRID, n_stores=N)
+        bank = get_trace_bank(GRID, N, PAPER_CLUSTER)
+        get_trace_bank(GRID, N, PAPER_CLUSTER)          # memo hit
+        first = rec.summary()
+    spans, counters = first["spans"], first["counters"]
+    assert spans["bank/get"]["count"] == 2
+    for name in ("bank/build", "bank/synth", "bank/rows", "bank/stack"):
+        assert spans[name]["count"] == 1, name
+    parts = sum(spans[n]["total"] for n in
+                ("bank/synth", "bank/rows", "bank/stack"))
+    assert parts <= spans["bank/build"]["total"] + 1e-6
+    assert spans["bank/build"]["total"] <= spans["bank/get"]["total"] + 1e-6
+    assert counters["bank/trace_rows"] == bank.trace_rows
+    assert counters["bank/wv_rows"] == bank.wv_rows
+    assert counters["bank/wv_rows_built"] == bank.wv_rows
+    # a rebuilt bank over warm row memos collapses no row again
+    from repro.core.simulator import _BANK_CACHE
+    _BANK_CACHE.clear()
+    with tm.recording() as rec:
+        get_trace_bank(GRID, N, PAPER_CLUSTER)
+        again = rec.summary()["counters"]
+    assert again["bank/wv_rows"] == bank.wv_rows
+    assert again["bank/wv_rows_built"] == 0
+
+
+def test_run_grid_counts_cells_lanes_and_tiles_once():
+    clear_sim_caches()
+    with tm.recording() as rec:
+        E.run_grid(GRID, n_stores=N)
         summ = rec.summary()
-    assert summ["counters"]["proto/cells"] == len(GRID)
-    assert summ["counters"]["proto/repl_msgs"] == \
-        sum(r.n_repl_msgs for r in res)
-    assert summ["counters"]["proto/log_unit_bytes"] == \
-        sum(r.max_log_bytes for r in res)
-    for dist in ("proto/dump_bw_gbps", "proto/cxl_mem_bw_gbps",
-                 "proto/dir_queue_occupancy"):
-        assert summ["dists"][dist]["count"] == len(GRID), dist
+    st = E.bank_stats()
+    assert summ["counters"]["engine/cells"] == len(GRID)
+    assert summ["counters"]["engine/lanes"] == st["scan_lanes"]
+    assert summ["counters"]["engine/tiles"] == \
+        summ["spans"]["tile/dispatch"]["count"]
+    assert summ["spans"]["tile/finish"]["count"] == \
+        summ["counters"]["engine/tiles"]
+    run = summ["spans"]["engine/run"]["total"]
+    for child in ("engine/plan", "bank/get", "tile/finish"):
+        assert summ["spans"][child]["total"] <= run + 1e-6, child
+
+
+# ------------------------------------------------ profiler clock + hooks
+
+def _host_events(log_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    clear_sim_caches()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test/outer"):
+            with tm.recording() as rec:
+                E.run_grid(GRID, n_stores=N)
+                gc.collect()
+                summ = rec.summary()
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    (lo, hi), = events["test/outer"]
+    for name in ("engine/run", "engine/plan", "tile/finish", "bank/synth",
+                 "bank/rows", "host/gc"):
+        assert events.get(name), name
+        assert all(lo <= a <= b <= hi for a, b in events[name]), name
+    # the recorder keeps its own view of the same spans
+    assert summ["spans"]["host/gc"]["count"] >= 1
+    assert summ["counters"]["host/gc_collections"] >= 1
+
+
+def test_hooks_leave_with_the_recorder():
+    from jax._src.monitoring import get_event_duration_listeners
+
+    def hooked():
+        return (gc.callbacks.count(tm._gc_hook),
+                get_event_duration_listeners().count(tm._compile_hook))
+
+    before = list(gc.callbacks)
+    tm.enable()
+    assert hooked() == (1, 1)
+    tm.disable()
+    assert gc.callbacks == before and hooked() == (0, 0)
+    with tm.recording():
+        with tm.recording():
+            assert hooked() == (1, 1)
+        assert hooked() == (1, 1)
+    assert gc.callbacks == before and hooked() == (0, 0)
+
+
+def test_gc_during_thread_registration_does_not_deadlock(tmp_path):
+    class CollectingLock:
+        """The registration lock, collecting garbage once held."""
+
+        def __init__(self):
+            self._lock = threading.Lock()
+
+        def __enter__(self):
+            self._lock.acquire()
+            gc.collect()
+            return self
+
+        def __exit__(self, *exc):
+            self._lock.release()
+
+    def worker():
+        with tm.span("worker/job"):
+            gc.collect()
+
+    path = tmp_path / "gc.jsonl"
+    with tm.recording() as rec:
+        rec._lock = CollectingLock()
+        t = threading.Thread(target=worker, daemon=True)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive(), "gc inside thread registration hung"
+        rec.export_chrome(str(path))
+        summ = rec.summary()
+    assert summ["spans"]["worker/job"]["count"] == 1
+    assert summ["spans"]["host/gc"]["count"] >= 1
+    tm.validate_chrome_trace(str(path))     # host/gc B/E balanced
+
+
+def test_compile_counter_counts_a_fresh_jit():
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    with tm.recording() as rec:
+        f(jnp.arange(7.0)).block_until_ready()
+        summ = rec.summary()
+    assert summ["counters"]["jax/compiles"] >= 1
+    assert summ["dists"]["jax/compile_s"]["count"] == \
+        summ["counters"]["jax/compiles"]
+
+
+def test_daemon_bank_growth_compiles_and_cached_flush_does_not():
+    clear_sim_caches()
+    grown = sweep_grid(workloads=("barnes",), configs=("proactive",),
+                       seeds=(7,), sb_sizes=(None,), n_replicas=(None,))
+    with ScenarioServer(n_stores=N, batch_cells=8,
+                        batch_window_ms=1.0) as srv:
+        srv.warm(GRID)
+        with tm.recording():
+            srv.query_batch(grown)              # new trace + wv rows
+            grow = srv.stats()["telemetry"]
+        with tm.recording():
+            srv.query_batch(grown)              # every lane cached
+            cached = srv.stats()["telemetry"]
+    assert grow["spans"]["serve/bank_extend"]["count"] == 1
+    assert grow["counters"]["jax/compiles"] > 0
+    assert cached["counters"].get("jax/compiles", 0) == 0
