@@ -43,7 +43,9 @@ This module is the streaming tier that removes all three:
    before the oldest is drained, bounding live memory. Host prep cost
    is further collapsed by the reduced-key ``_cell_arrays`` memo
    (cells differing only in config class / SB / CN share one
-   derivation), and everything is dropped by
+   derivation) on the stacked plane; a bank-plane tile needs no
+   per-store array of a cell, only ``_cell_scalars``, whose per-trace
+   part is memoized. Everything is dropped by
    ``repro.core.simulator.clear_sim_caches()`` -- including this
    module's compiled-tile cache, registered via
    ``register_cache_clearer``.
@@ -122,8 +124,11 @@ from repro.core.simulator import (
     ScenarioSpec,
     SimResult,
     TraceBank,
+    _CELL_ARRAY_CACHE,
+    _TRACE_SCALAR_CACHE,
     _bank_gather,
     _CellInputs,
+    _cell_scalars,
     _commit_cost_ns,
     _finish_result,
     _pad_len,
@@ -947,9 +952,9 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
 
     def prep_banked(tile: Tile):
         """Banked tile prep (prefetch thread): the two padded int32
-        row-index vectors, plus per-MEMBER-cell result metadata grouped
-        by lane (the scatter targets -- ``_prepare_cell``'s array
-        fields are memo references, not copies, so this stays cheap).
+        row-index vectors, plus per-MEMBER-cell result scalars grouped
+        by lane (the scatter targets -- ``_cell_scalars`` builds no
+        per-store array: the bank's rows already hold them).
         Sub-banked tiles remap wv rows to their SHARD-LOCAL index
         (``row // n_shards``) and scatter each lane into its
         :attr:`Tile.slots` position; unfilled slots stay 0 -- trace
@@ -965,11 +970,9 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
             tr, wr = bank.rows_for(s)
             trace_idx[pos] = tr
             wv_idx[pos] = wr // wv_div
-        groups = [[(i, _prepare_cell(
-            specs[i], _trace_cached(specs[i].workload, n_stores,
-                                    specs[i].seed, cluster),
-            n_stores, cluster)) for i in lane_members[lane]]
-            for lane in tile.indices]
+        groups = [[(i, _cell_scalars(specs[i], n_stores, cluster))
+                   for i in lane_members[lane]]
+                  for lane in tile.indices]
         return groups, (trace_idx, wv_idx)
 
     def prep_stacked(tile: Tile):
@@ -1191,6 +1194,10 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                     local_cap=local_rows if sub else 0,
                     wv_rows=bank.wv_rows)
             live_bytes = hwm_bytes = bank_dev_total
+        # host derivations the tiles cause, past the bank build (which
+        # counts its own): per-store cell arrays, per-trace scalars
+        arrays0 = _CELL_ARRAY_CACHE.misses
+        scalars0 = _TRACE_SCALAR_CACHE.misses
         sigs = list(dict.fromkeys(t.sig for t in tiles))
         warm = compile_pool.submit(warm_guarded)
         while not all(done):
@@ -1282,6 +1289,9 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
     finally:
         prep_pool.shutdown(wait=True)
         compile_pool.shutdown(wait=True)
+    _tm.count("engine/cell_arrays_built", _CELL_ARRAY_CACHE.misses - arrays0)
+    _tm.count("engine/result_scalars_built",
+              _TRACE_SCALAR_CACHE.misses - scalars0)
 
     if degraded_from is not None:
         # degraded-mesh fallback: finish the unfinished cells on a mesh

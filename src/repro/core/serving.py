@@ -71,11 +71,11 @@ to the closed-form SS VII-E model via
 
 Thread safety: all serve state is guarded by one re-entrant lock, and
 the shared host memos the flush path touches (`_trace_cached`,
-`_cell_arrays`, `_wv_row`) are the PR-6 thread-safe caches. A racing
-``clear_sim_caches()`` may drop compiled tile programs (the next flush
-recompiles) and host memos (rebuilt on demand), but never the server's
-bank handle or lane cache -- answers stay bit-identical throughout
-(tests/test_serving.py races exactly this).
+`_cell_arrays`, `_wv_row`, `_trace_scalars`) are thread-safe caches. A
+racing ``clear_sim_caches()`` may drop compiled tile programs (the next
+flush recompiles) and host memos (rebuilt on demand), but never the
+server's bank handle or lane cache -- answers stay bit-identical
+throughout (tests/test_serving.py races exactly this).
 """
 
 from __future__ import annotations
@@ -103,10 +103,9 @@ from repro.core.simulator import (
     ScenarioSpec,
     SimResult,
     _commit_cost_ns,
+    _cell_scalars,
     _finish_result,
     _plane_keys,
-    _prepare_cell,
-    _trace_cached,
     get_trace_bank,
 )
 from repro.distributed.context import cells_mesh
@@ -709,10 +708,7 @@ class ScenarioServer:
             results = []
             for s, k in zip(specs, keys):
                 exec_ns, at_head, sb_full, _ = self._lanes[k]
-                cell = _prepare_cell(
-                    s, _trace_cached(s.workload, self.n_stores, s.seed,
-                                     self.cluster),
-                    self.n_stores, self.cluster)
+                cell = _cell_scalars(s, self.n_stores, self.cluster)
                 meta = {"engine": "serving", "data_plane": "bank",
                         "bank_partition": "sub",
                         "cache": "miss" if k in miss else "hit",

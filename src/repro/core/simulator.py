@@ -99,7 +99,10 @@ streaming tier) collapses that to ``unique_rows x n_stores``:
   never re-derives ``w``/``v`` per cell;
 * cells carry only two ``int32`` row indices; the jitted timeline
   gathers its columns on device (:func:`_timeline_banked`), and the
-  streaming engine keeps one device-resident bank per mega-grid.
+  streaming engine keeps one device-resident bank per mega-grid;
+* on the host a banked cell is only its result scalars
+  (:func:`_cell_scalars`, memoized per trace): its per-store arrays are
+  built once per unique row, by the bank build, and never per cell.
 
 :func:`get_trace_bank` builds (and memoizes) the bank;
 ``tests/test_trace_bank.py`` property-tests that bank-gathered inputs
@@ -410,6 +413,9 @@ _BoundedCache = BoundedCache
 
 #: Reduced-key per-store array derivations (see :func:`_cell_arrays`).
 _CELL_ARRAY_CACHE = _BoundedCache(maxsize=512)
+#: Per-trace result scalars (see :func:`_trace_scalars`): three numbers
+#: per ``(workload, seed, coalescing)``, 54 entries a mega-grid sweep.
+_TRACE_SCALAR_CACHE = _BoundedCache(maxsize=256)
 #: Whole-batch stacked device inputs (see :func:`_batch_inputs`). One
 #: entry holds five ``(n_stores, B)`` f32 arrays plus the host cells
 #: (~50 MB for the Fig. 10 grid at the default store count), so the
@@ -422,7 +428,7 @@ _WV_ROW_CACHE = _BoundedCache(maxsize=1024)
 #: bank is a few hundred MB of host columns plus its device placements,
 #: so at most two stay alive.
 _BANK_CACHE = _BoundedCache(maxsize=2)
-#: Banked per-batch index vectors + prepared cells (the banked
+#: Banked per-batch index vectors + cell scalars (the banked
 #: counterpart of :data:`_BATCH_INPUT_CACHE`; entries are tiny).
 _BANKED_INPUT_CACHE = _BoundedCache(maxsize=8)
 
@@ -446,6 +452,7 @@ def clear_sim_caches() -> None:
     memory after a mega-grid sweep."""
     _trace_cached.cache_clear()
     _CELL_ARRAY_CACHE.clear()
+    _TRACE_SCALAR_CACHE.clear()
     _BATCH_INPUT_CACHE.clear()
     _WV_ROW_CACHE.clear()
     _BANK_CACHE.clear()       # drops host columns AND device placements
@@ -473,24 +480,38 @@ def _commit_cost_ns(config: str, cluster: ClusterConfig) -> Dict[str, float]:
 
 
 @dataclasses.dataclass
-class _CellInputs:
-    """Everything _timeline{,_batch} and result assembly need for one cell."""
+class _CellScalars:
+    """What result assembly (:func:`_finish_result`) and lane dedup need
+    of one cell: no per-store array (see :func:`_cell_scalars`)."""
     spec: ScenarioSpec
     n_stores: int
     sb_size: int
-    config_idx: int
     work_scale: float
+    # derived bandwidth / log metrics (timeline-independent)
+    n_repl_msgs: int
+    max_log_bytes: float
+    cxl_mem_bw_gbps: float
+    log_dump_bw_gbps: float
+
+
+@dataclasses.dataclass
+class _CellInputs(_CellScalars):
+    """Everything _timeline{,_batch} and result assembly need for one cell."""
+    config_idx: int
     # per-store timeline inputs, each (n_stores,)
     arrivals: np.ndarray
     coalesce: np.ndarray
     exposed: np.ndarray
     t_repl_i: np.ndarray
     svc_i: np.ndarray
-    # derived bandwidth / log metrics (timeline-independent)
-    n_repl_msgs: int
-    max_log_bytes: float
-    cxl_mem_bw_gbps: float
-    log_dump_bw_gbps: float
+
+
+@dataclasses.dataclass(frozen=True)
+class _TraceScalars:
+    """The scalars of one trace that results read (read-only)."""
+    n_coalesced: int
+    store_rate_per_core: float       # stores/s/core
+    mem_demand: float                # GB/s per CN
 
 
 @dataclasses.dataclass(frozen=True)
@@ -500,9 +521,6 @@ class _CellArrays:
     exposed: np.ndarray              # (n_stores,) f32 ns
     t_repl_i: np.ndarray             # (n_stores,) f32 ns
     svc_i: np.ndarray                # (n_stores,) f32 ns
-    n_coalesced: int
-    store_rate_per_core: float       # stores/s/core
-    mem_demand: float                # GB/s per CN
 
 
 def _directory_delay_row(arrivals: np.ndarray, tx_mask: np.ndarray,
@@ -565,20 +583,17 @@ def _make_cell_arrays(workload: str, n_stores: int, seed: int,
     wl = WORKLOADS[workload]
     trace = _trace_cached(workload, n_stores, seed, cluster)
     costs = _commit_cost_ns("proactive", cluster)   # config-independent
+    ts = _make_trace_scalars(workload, n_stores, seed, cluster, coalesce_on)
 
     # --- replication fan-out cost scaling -------------------------------
     # N_r REPLs leave in parallel but share the CN's CXL port: serialization
     # grows mildly with N_r; congestion scales latencies when offered load
     # nears the link bandwidth (Fig. 16/17 behaviour).
     repl_bytes = 8 + 64  # header + payload (coalesced line worst case)
-    mean_gap = float(np.mean(trace["gaps"]))
-    store_rate_per_core = 1e9 / max(mean_gap, 1e-3)          # stores/s/core
     cores = cluster.cores_per_cn
-    repl_demand = store_rate_per_core * cores * nr * repl_bytes / 1e9  # GB/s
-    mem_bytes = 64 + 16
-    read_rate = (wl.remote_read_rate / wl.remote_store_rate) * store_rate_per_core
-    mem_demand = (store_rate_per_core + read_rate) * cores * mem_bytes / 1e9
-    total_demand = mem_demand + (repl_demand if replicating else 0.0)
+    repl_demand = (ts.store_rate_per_core * cores * nr * repl_bytes
+                   / 1e9)                                     # GB/s
+    total_demand = ts.mem_demand + (repl_demand if replicating else 0.0)
     congestion = max(1.0, total_demand / bw)
     port_serial = 1.0 + 0.08 * (nr - 1)
 
@@ -637,10 +652,38 @@ def _make_cell_arrays(workload: str, n_stores: int, seed: int,
         exposed=np.asarray(exposed, np.float32),
         t_repl_i=np.asarray(t_repl_i, np.float32),
         svc_i=svc_i,
-        n_coalesced=int(coalesce.sum()),
-        store_rate_per_core=store_rate_per_core,
-        mem_demand=mem_demand,
     )
+
+
+def _make_trace_scalars(workload: str, n_stores: int, seed: int,
+                        cluster: ClusterConfig, coalesce_on: bool
+                        ) -> _TraceScalars:
+    """The one derivation of a trace's store rate, coalesced-store count
+    and memory demand, for both the per-store arrays
+    (:func:`_make_cell_arrays`) and the result scalars
+    (:func:`_cell_scalars`)."""
+    wl = WORKLOADS[workload]
+    trace = _trace_cached(workload, n_stores, seed, cluster)
+    mean_gap = float(np.mean(trace["gaps"]))
+    store_rate_per_core = 1e9 / max(mean_gap, 1e-3)          # stores/s/core
+    cores = cluster.cores_per_cn
+    mem_bytes = 64 + 16
+    read_rate = (wl.remote_read_rate / wl.remote_store_rate) * store_rate_per_core
+    mem_demand = (store_rate_per_core + read_rate) * cores * mem_bytes / 1e9
+    return _TraceScalars(
+        n_coalesced=int(trace["coalesce"].sum()) if coalesce_on else 0,
+        store_rate_per_core=store_rate_per_core,
+        mem_demand=mem_demand)
+
+
+def _trace_scalars(workload: str, n_stores: int, seed: int,
+                   cluster: ClusterConfig, coalesce_on: bool
+                   ) -> _TraceScalars:
+    """Memoized :func:`_make_trace_scalars` (:data:`_TRACE_SCALAR_CACHE`):
+    every cell of one trace and coalescing class shares the entry."""
+    key = (workload, n_stores, seed, cluster, coalesce_on)
+    return _TRACE_SCALAR_CACHE.get_or_put(
+        key, lambda: _make_trace_scalars(*key))
 
 
 def _cell_arrays(workload: str, n_stores: int, seed: int,
@@ -1134,20 +1177,59 @@ def get_trace_bank(specs: Sequence[ScenarioSpec], n_stores: int,
             key, lambda: _make_trace_bank(tuple(specs), n_stores, cluster))
 
 
-def _prepare_cell(spec: ScenarioSpec, trace: Dict[str, np.ndarray],
-                  n_stores: int, cluster: ClusterConfig) -> _CellInputs:
-    """Resolve a ScenarioSpec against a synthesized trace into the exact
-    per-store arrays the timeline consumes. Pure host-side numpy; used
-    verbatim by ``simulate``, ``simulate_batch`` and the streaming
-    engine (which validate the specs up front) so the paths cannot
-    drift. The heavy array work lives in :func:`_cell_arrays` and is
-    shared across every cell with the same reduced key."""
+def _cell_scalars(spec: ScenarioSpec, n_stores: int,
+                  cluster: ClusterConfig) -> _CellScalars:
+    """Resolve a ScenarioSpec into the scalars of its result, without
+    the per-store arrays: the bank plane's consumers (the streaming
+    engine's banked tiles, ``simulate_batch``'s banked tier, the
+    serving daemon) scan precollapsed bank rows and read only these.
+    The per-trace part is memoized (:func:`_trace_scalars`); the rest
+    is a few float operations per cell. :func:`_prepare_cell` builds
+    its scalars here too, so both planes' results agree bit for bit.
+    Contention and directory coupling change only the per-store
+    arrays, never these scalars."""
     config = spec.config
     nr = cluster.n_replicas if spec.n_replicas is None else spec.n_replicas
-    bw = cluster.cxl_link_bw_gbps if spec.link_bw_gbps is None else spec.link_bw_gbps
     ncn = cluster.n_cns if spec.n_cns is None else spec.n_cns
     sb = cluster.store_buffer if spec.sb_size is None else spec.sb_size
     replicating = config in _REPLICATING
+    ts = _trace_scalars(spec.workload, n_stores, spec.seed, cluster,
+                        spec.coalescing and config != "wt")
+
+    # --- scaling with CN count: fewer CNs -> each runs more of the fixed
+    # total work (weak scaling of the cluster as in Fig. 18).
+    work_scale = cluster.n_cns / ncn
+
+    n_repl = int(n_stores - ts.n_coalesced) if replicating else 0
+
+    # --- log sizing (Fig. 13): entries accumulated per dump period ------
+    entry_bytes = 12                       # Fig. 5: ~97 bits
+    stores_per_s = ts.store_rate_per_core * cluster.cores_per_cn * nr
+    log_bytes = stores_per_s * (cluster.dump_period_ms * 1e-3) * entry_bytes
+    dump_bw = (log_bytes / cluster.gzip_factor) / (cluster.dump_period_ms * 1e-3) / 1e9
+
+    return _CellScalars(
+        spec=spec, n_stores=n_stores, sb_size=sb, work_scale=work_scale,
+        n_repl_msgs=n_repl,
+        max_log_bytes=log_bytes,
+        cxl_mem_bw_gbps=ts.mem_demand * ncn,
+        log_dump_bw_gbps=(dump_bw * ncn if replicating else 0.0),
+    )
+
+
+def _prepare_cell(spec: ScenarioSpec, trace: Dict[str, np.ndarray],
+                  n_stores: int, cluster: ClusterConfig) -> _CellInputs:
+    """Resolve a ScenarioSpec against a synthesized trace into the exact
+    per-store arrays the timeline consumes, plus its result scalars
+    (:func:`_cell_scalars`). Pure host-side numpy; used verbatim by
+    ``simulate``, the stacked plane of ``simulate_batch`` and of the
+    streaming engine, and the contention oracle (which validate the
+    specs up front) so the paths cannot drift. The heavy array work
+    lives in :func:`_cell_arrays` and is shared across every cell with
+    the same reduced key."""
+    config = spec.config
+    nr = cluster.n_replicas if spec.n_replicas is None else spec.n_replicas
+    bw = cluster.cxl_link_bw_gbps if spec.link_bw_gbps is None else spec.link_bw_gbps
 
     # contention and directory coupling only touch the directory/
     # replication transactions of the replicating configs (WB/WT commit
@@ -1157,37 +1239,21 @@ def _prepare_cell(spec: ScenarioSpec, trace: Dict[str, np.ndarray],
     # data and the dedup keys cannot drift.
     con, dirp = _resolve_coupling(spec, cluster)
     arr = _cell_arrays(spec.workload, n_stores, spec.seed, cluster, nr, bw,
-                       replicating, spec.coalescing and config != "wt",
+                       config in _REPLICATING,
+                       spec.coalescing and config != "wt",
                        contention=con, directory=dirp)
-
-    # --- scaling with CN count: fewer CNs -> each runs more of the fixed
-    # total work (weak scaling of the cluster as in Fig. 18).
-    work_scale = cluster.n_cns / ncn
-
-    n_repl = int(n_stores - arr.n_coalesced) if replicating else 0
-
-    # --- log sizing (Fig. 13): entries accumulated per dump period ------
-    entry_bytes = 12                       # Fig. 5: ~97 bits
-    stores_per_s = arr.store_rate_per_core * cluster.cores_per_cn * nr
-    log_bytes = stores_per_s * (cluster.dump_period_ms * 1e-3) * entry_bytes
-    dump_bw = (log_bytes / cluster.gzip_factor) / (cluster.dump_period_ms * 1e-3) / 1e9
-
     return _CellInputs(
-        spec=spec, n_stores=n_stores, sb_size=sb,
-        config_idx=_CONFIG_IDX[config], work_scale=work_scale,
+        **vars(_cell_scalars(spec, n_stores, cluster)),
+        config_idx=_CONFIG_IDX[config],
         arrivals=trace["arrivals"],
         coalesce=arr.coalesce,
         exposed=arr.exposed,
         t_repl_i=arr.t_repl_i,
         svc_i=arr.svc_i,
-        n_repl_msgs=n_repl,
-        max_log_bytes=log_bytes,
-        cxl_mem_bw_gbps=arr.mem_demand * ncn,
-        log_dump_bw_gbps=(dump_bw * ncn if replicating else 0.0),
     )
 
 
-def _finish_result(cell: _CellInputs, exec_ns: float, at_head: int,
+def _finish_result(cell: _CellScalars, exec_ns: float, at_head: int,
                    sb_full: int,
                    meta: Optional[Dict[str, object]] = None) -> SimResult:
     n = cell.n_stores
@@ -1759,9 +1825,7 @@ def _make_banked_inputs(specs: Tuple[ScenarioSpec, ...], n_stores: int,
     # get_trace_bank and _BANK_CACHE's small bound stays the ONLY thing
     # keeping multi-hundred-MB banks alive
     bank = get_trace_bank(specs, n_stores, cluster)
-    cells = [_prepare_cell(s, _trace_cached(s.workload, n_stores, s.seed,
-                                            cluster), n_stores, cluster)
-             for s in specs]
+    cells = [_cell_scalars(s, n_stores, cluster) for s in specs]
     # scan-lane dedup (same reduction as the streaming engine's): a
     # timeline consumes only (arrivals row, max-plus row, SB depth), so
     # cells sharing that triple are ONE lane -- gathered and scanned
@@ -1800,8 +1864,8 @@ def _make_banked_inputs(specs: Tuple[ScenarioSpec, ...], n_stores: int,
 def _banked_inputs(specs: Tuple[ScenarioSpec, ...], n_stores: int,
                    cluster: ClusterConfig):
     """Memoized banked host prep for one batch: the padded ``int32``
-    lane-index vectors, the cell->lane scatter map, plus prepared cells
-    (the banked counterpart of :func:`_batch_inputs` -- entries are a
+    lane-index vectors, the cell->lane scatter map, plus each cell's
+    :class:`_CellScalars` (the banked counterpart of :func:`_batch_inputs` -- entries are a
     few KB instead of stacked array copies, and hold NO reference to
     the bank itself)."""
     key = _specs_key(specs, n_stores, cluster)

@@ -170,14 +170,14 @@ def test_poisoned_tile_surfaces_with_context(monkeypatch):
     grid = [ScenarioSpec(w, c) for w in ("ycsb", "barnes")
             for c in ("wb", "proactive")]
     clear_sim_caches()
-    real = E._prepare_cell
+    real = E._cell_scalars
 
     def poisoned(spec, *a, **kw):
         if spec.workload == "barnes":
             raise ValueError("poisoned tile input")
         return real(spec, *a, **kw)
 
-    monkeypatch.setattr(E, "_prepare_cell", poisoned)
+    monkeypatch.setattr(E, "_cell_scalars", poisoned)
     with pytest.raises(E.EngineWorkerError) as ei:
         E.run_grid(grid, n_stores=N, tile_cells=16, n_shards=1)
     assert ei.value.stage == "prefetch"
@@ -185,7 +185,7 @@ def test_poisoned_tile_surfaces_with_context(monkeypatch):
     assert "poisoned tile input" in str(ei.value)
     # the run fails promptly AND cleanly: the engine serves the same
     # grid fine immediately afterwards
-    monkeypatch.setattr(E, "_prepare_cell", real)
+    monkeypatch.setattr(E, "_cell_scalars", real)
     clear_sim_caches()
     _assert_bit_identical(E.run_grid(grid, n_stores=N, tile_cells=16,
                                      n_shards=1),

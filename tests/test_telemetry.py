@@ -39,6 +39,7 @@ from repro.core import telemetry as tm
 from repro.core.scenarios import chaos_grid, sweep_grid
 from repro.core.serving import ScenarioServer
 from repro.core.simulator import (
+    _CELL_ARRAY_CACHE,
     PAPER_CLUSTER,
     _plane_keys,
     _specs_key,
@@ -318,6 +319,39 @@ def test_run_grid_counts_cells_lanes_and_tiles_once():
     run = summ["spans"]["engine/run"]["total"]
     for child in ("engine/plan", "bank/get", "tile/finish"):
         assert summ["spans"][child]["total"] <= run + 1e-6, child
+
+
+#: WB, WT (coalescing off, as WT runs) and replicating cells over two
+#: seeds, replica and link knobs, coalescing on and off.
+SCALAR_GRID = sweep_grid(workloads=("ycsb", "barnes"),
+                         configs=("wb", "wt", "parallel", "proactive"),
+                         seeds=(0, 1), n_replicas=(None, 2),
+                         link_bw_gbps=(None, 40.0), sb_sizes=(None, 48),
+                         coalescing=(True, False))
+
+
+def test_bank_plane_run_builds_no_cell_arrays():
+    """Banked tile prep derives each cell's result scalars from its
+    trace: no per-store cell array is built past the bank build, and the
+    per-trace memo misses once per (workload, seed, coalescing class)."""
+    clear_sim_caches()
+    get_trace_bank(SCALAR_GRID, N, PAPER_CLUSTER)
+    misses0 = _CELL_ARRAY_CACHE.misses
+    with tm.recording() as rec:
+        E.run_grid(SCALAR_GRID, n_stores=N, tile_cells=16)
+        counters = rec.summary()["counters"]
+    assert _CELL_ARRAY_CACHE.misses == misses0
+    assert counters["engine/cell_arrays_built"] == 0
+    assert counters["engine/result_scalars_built"] == len(
+        {(s.workload, s.seed, s.coalescing and s.config != "wt")
+         for s in SCALAR_GRID})
+    # the stacked plane scans per-store arrays and builds them per key
+    with tm.recording() as rec:
+        E.run_grid(SCALAR_GRID, n_stores=N, tile_cells=16,
+                   data_plane="stacked")
+        counters = rec.summary()["counters"]
+    assert counters["engine/cell_arrays_built"] > 0
+    assert counters["engine/result_scalars_built"] == 0
 
 
 # ------------------------------------------------ profiler clock + hooks

@@ -10,6 +10,7 @@ including its device placements (no leaked device buffers across
 engine switches).
 """
 
+import dataclasses
 import gc
 import weakref
 
@@ -53,6 +54,69 @@ def ragged_grids(draw):
             sb_size=draw(st.sampled_from((None, 16, 24))),
             coalescing=draw(st.booleans())))
     return specs
+
+
+#: Ragged grids for the result-scalar record: WB and WT (coalescing on
+#: and off), the three replicating rules over replica / link / CN / SB
+#: knobs, the contention axes, and directory loads.
+SCALAR_GRIDS = {
+    "wb_wt": [ScenarioSpec(w, c, seed=s, n_replicas=nr, n_cns=ncn,
+                           coalescing=co)
+              for w in ("ycsb", "barnes") for c in ("wb", "wt")
+              for s in (0, 2) for nr in (None, 4) for ncn in (None, 4)
+              for co in (True, False)],
+    "replicating": [ScenarioSpec("canneal", c, seed=s, n_replicas=nr,
+                                 link_bw_gbps=bw, n_cns=ncn, sb_size=sb,
+                                 coalescing=co)
+                    for c in ("baseline", "parallel", "proactive")
+                    for s in (0, 1) for nr in (None, 1, 4)
+                    for bw in (None, 20.0) for ncn in (None, 2)
+                    for sb in (None, 24) for co in (True, False)],
+    "contention": [ScenarioSpec(w, c, seed=1, n_replicas=nr, n_cns=ncn,
+                                read_share=rs, conflict_rate=cr,
+                                consistency_schedule=cs)
+                   for w in ("ycsb", "raytrace")
+                   for c in ("wb", "wt", "baseline", "proactive")
+                   for nr in (None, 2) for ncn in (None, 8)
+                   for rs, cr, cs in ((0.3, None, None),
+                                      (None, 0.2, "eager"),
+                                      (0.5, 0.1, "epoch"))],
+    "directory": [ScenarioSpec(w, c, n_replicas=nr, link_bw_gbps=bw,
+                               n_cns=ncn, directory_load=dl,
+                               coalescing=co)
+                  for w in ("ocean_ncp", "barnes") for c in CONFIGS
+                  for nr in (None, 3) for bw in (None, 40.0)
+                  for ncn in (None, 4) for dl in (0.0, 0.4)
+                  for co in (True, False)],
+}
+
+
+@pytest.mark.parametrize("grid", sorted(SCALAR_GRIDS))
+def test_cell_scalars_equal_prepared_cell_fields(grid):
+    """The bank plane's scalar-only record (``_cell_scalars``) is ``==``
+    to the same fields of ``_prepare_cell``'s full inputs, and its
+    coalesced-store count agrees with the per-store coalesce array the
+    stacked plane scans; the per-trace memo holds one entry per (trace,
+    coalescing class)."""
+    specs = SCALAR_GRIDS[grid]
+    clear_sim_caches()
+    scalars = [S._cell_scalars(s, N, PAPER_CLUSTER) for s in specs]
+    assert len(S._TRACE_SCALAR_CACHE) == len(
+        {(s.workload, s.seed, s.coalescing and s.config != "wt")
+         for s in specs})
+    assert len(S._CELL_ARRAY_CACHE) == 0
+    clear_sim_caches()
+    names = [f.name for f in dataclasses.fields(S._CellScalars)]
+    for s, sc in zip(specs, scalars):
+        cell = S._prepare_cell(
+            s, S._trace_cached(s.workload, N, s.seed, PAPER_CLUSTER), N,
+            PAPER_CLUSTER)
+        for f in names:
+            assert getattr(sc, f) == getattr(cell, f), (s, f)
+        want_repl = (N - int(cell.coalesce.sum())
+                     if s.config in ("baseline", "parallel", "proactive")
+                     else 0)
+        assert sc.n_repl_msgs == want_repl, s
 
 
 @settings(max_examples=10, deadline=None)
