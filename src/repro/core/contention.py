@@ -76,6 +76,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from repro.configs.recxl_paper import ClusterConfig, PAPER_CLUSTER
+from repro.core import telemetry as _tm
 from repro.core.hostcache import BoundedCache
 
 #: Recognised crash-consistency schedules, weakest ordering first.
@@ -177,6 +178,12 @@ def clear_contention_caches() -> None:
 def contention_cache_sizes() -> Tuple[int, int]:
     """(draw entries, delay entries) currently memoized -- test hook."""
     return len(_DRAW_CACHE), len(_DELAY_CACHE)
+
+
+def contention_memo_misses() -> Tuple[int, int]:
+    """(draw misses, delay-row misses) since the memos were last
+    cleared: the bank build counts the rows it built from these."""
+    return _DRAW_CACHE.misses, _DELAY_CACHE.misses
 
 
 def _make_conflict_draws(n_stores: int, seed: int, conflict_rate: float,
@@ -294,9 +301,13 @@ def contention_arrays(params: ContentionParams, n_stores: int, seed: int,
     Memoized on the full row key (rows recur across every cell sharing
     the reduced derivation knobs)."""
     key = (params, n_stores, seed, cluster, congestion)
-    return _DELAY_CACHE.get_or_put(
-        key, lambda: _make_contention_arrays(params, n_stores, seed,
-                                             cluster, congestion))
+
+    def build():
+        with _tm.span("contention/rows"):
+            return _make_contention_arrays(params, n_stores, seed,
+                                           cluster, congestion)
+
+    return _DELAY_CACHE.get_or_put(key, build)
 
 
 # ---------------------------------------------------------------------------

@@ -186,6 +186,7 @@ from repro.core.contention import (
     ContentionParams,
     clear_contention_caches,
     contention_arrays,
+    contention_memo_misses,
     resolve_contention,
 )
 from repro.core.directory import (
@@ -1148,11 +1149,17 @@ def _make_trace_bank(specs: Tuple[ScenarioSpec, ...], n_stores: int,
             a_rows = [_trace_cached(w, n_stores, seed, cluster)["arrivals"]
                       for (w, seed) in trace_row]
         misses = _WV_ROW_CACHE.misses
+        draws0, delays0 = contention_memo_misses()
         with _tm.span("bank/rows", rows=len(wv_row)):
             wv_rows = [_wv_row(k, n_stores, cluster) for k in wv_row]
         _tm.count("bank/trace_rows", len(trace_row))
         _tm.count("bank/wv_rows", len(wv_row))
         _tm.count("bank/wv_rows_built", _WV_ROW_CACHE.misses - misses)
+        if any(isinstance(x, ContentionParams) for k in wv_row
+               for x in k[6:]):
+            draws1, delays1 = contention_memo_misses()
+            _tm.count("contention/draws_built", draws1 - draws0)
+            _tm.count("contention/delay_rows_built", delays1 - delays0)
         with _tm.span("bank/stack"):
             return TraceBank(
                 n_stores=n_stores, cluster=cluster,

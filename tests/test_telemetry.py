@@ -304,6 +304,38 @@ def test_bank_build_spans_and_row_counters():
     assert again["bank/wv_rows_built"] == 0
 
 
+def test_contended_bank_build_times_and_counts_its_delay_rows():
+    """A contended grid's bank build spans each delay row it builds
+    (``contention/rows``, inside ``bank/rows``) and counts the conflict
+    draws and delay rows it built; the legacy mega-grid records none of
+    them and keeps its 27 + 1 298 rows."""
+    from repro.core.contention import contention_cache_sizes
+    from repro.core.scenarios import contention_mega_grid, mega_grid
+
+    clear_sim_caches()
+    specs = contention_mega_grid(workloads=("ycsb", "canneal"), seeds=(0,))
+    with tm.recording() as rec:
+        get_trace_bank(specs, 64, PAPER_CLUSTER)
+        summ = rec.summary()
+    spans, counters = summ["spans"], summ["counters"]
+    draws, delays = contention_cache_sizes()
+    assert draws > 0 and delays > 0
+    assert counters["contention/draws_built"] == draws
+    assert counters["contention/delay_rows_built"] == delays
+    assert spans["contention/rows"]["count"] == delays
+    assert spans["contention/rows"]["total"] <= \
+        spans["bank/rows"]["total"] + 1e-6
+    clear_sim_caches()
+    with tm.recording() as rec:
+        bank = get_trace_bank(mega_grid(), 32, PAPER_CLUSTER)
+        summ = rec.summary()
+    assert "contention/rows" not in summ["spans"]
+    assert not {"contention/draws_built", "contention/delay_rows_built"} \
+        & set(summ["counters"])
+    assert (bank.trace_rows, bank.wv_rows) == (27, 1298)
+    clear_sim_caches()
+
+
 def test_run_grid_counts_cells_lanes_and_tiles_once():
     clear_sim_caches()
     with tm.recording() as rec:
