@@ -637,7 +637,9 @@ def _place_sub_bank(bank: TraceBank, n_shards: int,
     by a different shard than its wv row), the three max-plus planes
     shard-partitioned via ``TraceBank.sub_bank_host`` -- ONE copy of
     each wv row fleet-wide, so resident device bytes drop to
-    ~``1/n_shards`` of the replicated layout. The sub stacks
+    ~``1/n_shards`` of the replicated layout. At one shard that layout
+    is the identity, so the bank's own host columns are uploaded as
+    they are, with no host re-layout copy. The sub stacks
     ``device_put`` straight to their sharded layout (each device
     receives only its slice: host->device bytes stay at bank scale,
     no fabric replication); only the arrivals staging replicates.

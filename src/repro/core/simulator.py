@@ -972,10 +972,22 @@ class TraceBank:
         block back).  Gathers always target the primary block, so the
         scan arithmetic -- and at ``k_replicas=1`` the bytes -- are
         unchanged from the PR-8 layout; the replica blocks cost
-        ``(k - 1)/n_shards`` extra resident bytes per max-plus plane."""
+        ``(k - 1)/n_shards`` extra resident bytes per max-plus plane.
+
+        At one shard (which forces ``k_replicas=1``) with at least one
+        wv row the layout is the identity: the planes are ``(1, P,
+        n_stores)`` VIEWS of ``w``, ``v`` and ``pr_nc``, not a copy.
+        That is safe because placement copies them to the device,
+        :meth:`extend` replaces the columns rather than writing into
+        them, and chaos tampering touches only the device copy. Every
+        other layout (several shards, replica blocks, the one padding
+        row of an empty plane) is a fresh zero-padded copy."""
         if not 1 <= k_replicas <= n_shards:
             raise ValueError(f"k_replicas must be in [1, {n_shards}], "
                              f"got {k_replicas}")
+        if n_shards == 1 and self.wv_rows:
+            return (self.arrivals, self.w[None], self.v[None],
+                    self.pr_nc[None])
         p_loc = sub_bank_rows(self.wv_rows, n_shards)
 
         def sub(col: np.ndarray) -> np.ndarray:
@@ -1000,7 +1012,9 @@ class TraceBank:
         Growth re-places the whole sub-bank (no diff path: the
         streaming engine never extends a bank mid-run, and the serving
         daemon keeps its own capacity-padded device state with
-        per-shard splices)."""
+        per-shard splices). Counter ``bank/layout_bytes``: the host
+        bytes copied to lay the planes out, 0 for the one-shard
+        identity layout, whose views are uploaded as they are."""
         key = ("sub", n_shards) if k_replicas == 1 \
             else ("sub", n_shards, k_replicas)
         entry = self._device.get(key)
@@ -1010,6 +1024,10 @@ class TraceBank:
             if rows_placed == rows_now:
                 return 0, dev
         host = self.sub_bank_host(n_shards, k_replicas)
+        _tm.count("bank/layout_bytes", sum(
+            int(x.nbytes) for x, col in zip(host[1:],
+                                            (self.w, self.v, self.pr_nc))
+            if not np.may_share_memory(x, col)))
         dev = place(host) if place is not None else \
             tuple(jnp.asarray(x) for x in host)
         self._device[key] = (rows_now, dev)

@@ -92,6 +92,35 @@ def test_stream_single_shard_matches_sharded(blocked_results):
     assert single[0].meta["n_shards"] == 1
 
 
+@pytest.mark.parametrize("n_shards", [1, 8])
+def test_bank_placement_layout_bytes_and_oracle(n_shards):
+    """Sub-bank placement copies host bytes only where the layout moves
+    rows: ``bank/layout_bytes`` reads 0 at one shard (the bank's own
+    columns are uploaded) and the padded stacks' bytes on the 8-device
+    mesh, while every answer stays ``==`` the serial oracle."""
+    from repro.core import telemetry as tm
+    if jax.device_count() < n_shards:
+        pytest.skip(f"needs {n_shards} devices")
+    grid = RAGGED_GRID[:6] + RAGGED_GRID[-3:]
+    clear_sim_caches()                       # force a fresh placement
+    with tm.recording() as rec:
+        out = E.run_grid(grid, n_stores=N, tile_cells=16,
+                         n_shards=n_shards)
+        counters = rec.summary()["counters"]
+    bank = E.get_trace_bank(grid, N)
+    stacks = 0 if n_shards == 1 else sum(
+        x.nbytes for x in bank.sub_bank_host(n_shards)[1:])
+    assert counters["bank/layout_bytes"] == stacks
+    for s, got in zip(grid, out):
+        rs = simulate(s.workload, s.config, n_stores=N, seed=s.seed,
+                      n_replicas=s.n_replicas, link_bw_gbps=s.link_bw_gbps,
+                      n_cns=s.n_cns, sb_size=s.sb_size,
+                      coalescing=s.coalescing)
+        assert got.n_repl_msgs == rs.n_repl_msgs, s
+        for f in FLOAT_FIELDS:
+            assert getattr(got, f) == getattr(rs, f), (s, f)
+
+
 def test_compile_cache_hits_across_tiles():
     """One signature's program must be traced at most once however many
     tiles reuse it, and a second grid with the same shapes must not

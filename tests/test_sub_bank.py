@@ -144,6 +144,37 @@ def test_sub_bank_rows_and_host_layout():
         assert not w[s, local:].any()
 
 
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_sub_bank_host_copies_only_when_the_layout_moves_rows(n_shards):
+    """At one shard the sub-bank layout is the identity, so
+    ``sub_bank_host`` hands back views of the bank's own columns; at
+    several shards it is the zero-padded round-robin copy. Both equal
+    the layout built row by row, and an empty wv plane still gets its
+    one zero padding row."""
+    import dataclasses
+    from repro.core.simulator import get_trace_bank
+    specs = [ScenarioSpec(w, c) for w in WORKLOAD_POOL for c in CONFIGS]
+    bank = get_trace_bank(specs, N, PAPER_CLUSTER)
+    p_loc = sub_bank_rows(bank.wv_rows, n_shards)
+    cols = (bank.w, bank.v, bank.pr_nc)
+    host = bank.sub_bank_host(n_shards)
+    assert host[0] is bank.arrivals
+    for got, col in zip(host[1:], cols):
+        want = np.zeros((n_shards, p_loc, N), col.dtype)
+        for r in range(bank.wv_rows):
+            want[r % n_shards, r // n_shards] = col[r]
+        assert got.dtype == col.dtype
+        assert np.array_equal(got, want)
+        assert np.shares_memory(got, col) == (n_shards == 1)
+
+    empty = dataclasses.replace(
+        bank, w=bank.w[:0], v=bank.v[:0], pr_nc=bank.pr_nc[:0],
+        wv_row={}, _device={})
+    for got, col in zip(empty.sub_bank_host(n_shards)[1:], cols):
+        assert got.shape == (n_shards, 1, N) and got.dtype == col.dtype
+        assert not got.any()
+
+
 def test_measured_sub_bytes_cut_vs_replicated():
     """The point of the PR: measured per-shard resident bytes under the
     sub partition stay within ~1.1x of bank/n_shards + the replicated
