@@ -48,9 +48,9 @@ def bench_chaos() -> List[Dict]:
     from repro.core import chaos
     from repro.core import engine as E
     from repro.core.chaos import ChaosConfig
-    from repro.core.scenarios import chaos_grid, sweep_grid
+    from repro.core.scenarios import chaos_grid, run_sweep, sweep_grid
     from repro.core.serving import ScenarioServer
-    from repro.core.simulator import clear_sim_caches, simulate_batch
+    from repro.core.simulator import clear_sim_caches
 
     n_shards = min(8, len(jax.devices()))
     grid = (chaos_grid(replicas=(None, 2), bandwidths=(None,)) if QUICK
@@ -61,7 +61,7 @@ def bench_chaos() -> List[Dict]:
                                              for a, b in zip(got, want))
 
     clear_sim_caches()
-    oracle = simulate_batch(grid, n_stores=STORES)
+    oracle = run_sweep(grid, n_stores=STORES, engine="serial")
 
     # healthy baseline (k=1): timing + resident bytes for the ratios
     clear_sim_caches()
@@ -139,7 +139,7 @@ def bench_chaos() -> List[Dict]:
                        configs=("baseline", "proactive"),
                        n_replicas=(2, 3))
     clear_sim_caches()
-    novel_oracle = simulate_batch(novel, n_stores=STORES)
+    novel_oracle = run_sweep(novel, n_stores=STORES, engine="serial")
 
     with chaos.inject(ChaosConfig(lose_shard=max(n_shards - 1, 0),
                                   lose_at_dispatch=2)) as cs:

@@ -80,8 +80,6 @@ def bench_contention() -> List[Dict]:
     clear_sim_caches()
     traces0 = E.trace_count()
     t0 = time.perf_counter()
-    # engine forced to "stream" so the quick smoke exercises the same
-    # banked streaming tier the full grid auto-selects (>= 2048 cells)
     res = run_sweep(specs, n_stores=STORES, engine="stream")
     engine_s = time.perf_counter() - t0
     compiles = E.trace_count() - traces0

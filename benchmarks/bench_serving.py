@@ -25,7 +25,7 @@ record:
   serving must still trace 0 programs there, and the measured
   per-shard resident bytes are recorded;
 * ``oracle_bitident`` -- every streamed answer re-checked ``==``
-  against the cold blocked-batch oracle (sharded answers included).
+  against the serial oracle (sharded answers included).
 
 Registered by benchmarks/run.py; the ``serving`` CI job asserts the
 ``oracle_bitident`` and ``cache_hit_ratio`` rows in ``--quick`` mode.
@@ -50,9 +50,9 @@ N_QUERIES = 60 if QUICK else 400
 
 def bench_serving() -> List[Dict]:
     from repro.core import engine as E
-    from repro.core.scenarios import grid_delta, sweep_grid
+    from repro.core.scenarios import grid_delta, run_sweep, sweep_grid
     from repro.core.serving import ScenarioServer
-    from repro.core.simulator import clear_sim_caches, simulate_batch
+    from repro.core.simulator import clear_sim_caches
 
     # a rich warm bank (hundreds of wv rows) so the single-miss probe's
     # one-row diff is measured against a realistically sized platform
@@ -117,10 +117,10 @@ def bench_serving() -> List[Dict]:
         sharded_compiles = E.trace_count() - tc0
         sh_stats = ssrv.stats()
 
-    # cold oracle for every answer the daemon produced (fresh caches:
-    # the oracle must not ride the daemon's bank or memos)
+    # serial oracle for every answer the daemon produced (fresh caches:
+    # the oracle must not ride the daemon's memos)
     clear_sim_caches()
-    oracle = simulate_batch(stream + probe, n_stores=STORES)
+    oracle = run_sweep(stream + probe, n_stores=STORES, engine="serial")
     ident = all(a == b for a, b in zip(served + served_probe, oracle))
     ident = ident and all(a == b for a, b in zip(sh_served, oracle))
 

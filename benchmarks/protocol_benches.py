@@ -3,15 +3,13 @@
 Every function returns a list of row dicts with at least
 (name, us_per_call, derived); run.py renders them as CSV.
 
-All grids run through the engine tier selector (``scenarios.run_sweep``
--> ``repro.core.engine``): one call per figure instead of a serial
-Python loop per cell. ``bench_batch_speedup`` keeps the serial oracle
-and both batched engines (blocked default vs PR-1 per-step) honest by
-timing all paths on the full Fig. 10 grid; ``bench_megagrid`` times the
-streaming sharded tier against the one-shot blocked paths on the
->=10^4-cell sensitivity cross-product. ``clear_sim_caches()`` runs
-between engines so no path's timing rides on caches another warmed; all
-speedups land in the ``BENCH_protocol.json`` trajectory.
+All grids run through ``scenarios.run_sweep`` (the streaming engine,
+``repro.core.engine.run_grid``): one call per figure instead of a
+serial Python loop per cell. ``bench_batch_speedup`` times the engine
+against the serial oracle on the full Fig. 10 grid; ``bench_megagrid``
+times the engine on the >=10^4-cell sensitivity cross-product and
+checks its cells against the serial oracle. All rows land in the ``BENCH_protocol.json``
+trajectory.
 ``bench_recovery`` adds the SS VII-E downtime model rows
 (``fig9/recovery/*``) from one batched failure-time x node sweep.
 
@@ -37,7 +35,6 @@ from repro.core.simulator import (
     clear_sim_caches,
     geomean_slowdowns,
     simulate,
-    simulate_batch,
     slowdowns_from_results,
 )
 
@@ -50,22 +47,9 @@ MEGA_STORES = int(os.environ.get("RECXL_BENCH_MEGA_STORES",
                                  "2000" if QUICK else "30000"))
 
 
-def _available_memory_bytes():
-    """MemAvailable from /proc/meminfo, or None where unavailable."""
-    try:
-        with open("/proc/meminfo") as f:
-            for line in f:
-                if line.startswith("MemAvailable:"):
-                    return int(line.split()[1]) * 1024
-    except OSError:
-        pass
-    return None
-
-
 def _run(specs: Sequence[ScenarioSpec]) -> Dict[tuple, SimResult]:
-    """One sweep through the engine tier selector; results keyed by the
-    spec itself (figure grids are small, so this resolves to the
-    one-shot blocked batch)."""
+    """One sweep through ``run_sweep``; results keyed by the spec
+    itself."""
     res = run_sweep(specs, n_stores=N_STORES)
     return {s: r for s, r in zip(specs, res)}
 
@@ -111,37 +95,20 @@ def bench_protocols() -> List[Dict]:
 
 
 def bench_batch_speedup() -> List[Dict]:
-    """Engine wall-clock comparison on the full Fig. 10 grid (45 cells).
-
-    Four paths: the serial per-cell oracle loop; the PR-1 batched path
-    (per-step scan, host prep re-done every call -- exactly what PR 1
-    shipped, reproduced by clearing every simulator cache); the
-    per-step engine with cached inputs; and the blocked engine (the
-    ``simulate_batch`` default). Steady-state rows are warmed so they
-    track sweep throughput, not XLA compile time; the cold blocked time
-    is its own row since a CI smoke run pays it. ``clear_sim_caches``
-    runs between engines so no path's timing rides on caches another
-    path warmed.
-    """
+    """Wall clock on the full Fig. 10 grid (45 cells): the serial
+    per-cell oracle loop against ``run_sweep`` (the engine). The cold
+    sweep runs after ``clear_sim_caches()`` and pays trace synthesis and
+    compiles; the steady-state rows are warmed so they track sweep
+    throughput, not XLA compile time."""
     specs = [ScenarioSpec(w, c) for w in WORKLOADS for c in CONFIGS]
 
     clear_sim_caches()
     t0 = time.perf_counter()
-    simulate_batch(specs, n_stores=N_STORES)
+    run_sweep(specs, n_stores=N_STORES)
     cold_s = time.perf_counter() - t0
     t0 = time.perf_counter()
-    simulate_batch(specs, n_stores=N_STORES)
-    blocked_s = time.perf_counter() - t0
-
-    simulate_batch(specs, n_stores=N_STORES, chunk_size=0)   # warm per-step
-    t0 = time.perf_counter()
-    simulate_batch(specs, n_stores=N_STORES, chunk_size=0)
-    perstep_s = time.perf_counter() - t0
-
-    t0 = time.perf_counter()                                 # PR-1 path
-    clear_sim_caches()
-    simulate_batch(specs, n_stores=N_STORES, chunk_size=0)
-    pr1_s = time.perf_counter() - t0
+    run_sweep(specs, n_stores=N_STORES)
+    batched_s = time.perf_counter() - t0
 
     for s in specs[:5]:                     # warm the per-config serial jits
         simulate(s.workload, s.config, n_stores=N_STORES)
@@ -154,64 +121,39 @@ def bench_batch_speedup() -> List[Dict]:
     return [
         {"name": "fig10/sweep/serial_ms", "us_per_call": serial_s * 1e6 / n,
          "derived": round(serial_s * 1e3, 2)},
-        {"name": "fig10/sweep/pr1_perstep_uncached_ms",
-         "us_per_call": pr1_s * 1e6 / n, "derived": round(pr1_s * 1e3, 2)},
-        {"name": "fig10/sweep/perstep_ms", "us_per_call": perstep_s * 1e6 / n,
-         "derived": round(perstep_s * 1e3, 2)},
-        {"name": "fig10/sweep/batched_ms", "us_per_call": blocked_s * 1e6 / n,
-         "derived": round(blocked_s * 1e3, 2)},
+        {"name": "fig10/sweep/batched_ms", "us_per_call": batched_s * 1e6 / n,
+         "derived": round(batched_s * 1e3, 2)},
         {"name": "fig10/sweep/batched_cold_ms", "us_per_call": cold_s * 1e6 / n,
          "derived": round(cold_s * 1e3, 2)},
         {"name": "fig10/sweep/speedup_serial_over_batched",
          "us_per_call": 0.0,
-         "derived": round(serial_s / max(blocked_s, 1e-9), 2)},
-        {"name": "fig10/sweep/speedup_pr1_over_blocked",
-         "us_per_call": 0.0,
-         "derived": round(pr1_s / max(blocked_s, 1e-9), 2)},
-        {"name": "fig10/sweep/speedup_perstep_over_blocked",
-         "us_per_call": 0.0,
-         "derived": round(perstep_s / max(blocked_s, 1e-9), 2)},
+         "derived": round(serial_s / max(batched_s, 1e-9), 2)},
     ]
 
 
 def bench_megagrid() -> List[Dict]:
-    """``fig10/megagrid/*``: the streaming sharded engine tier vs the
-    one-shot blocked path on the full sensitivity cross-product
-    (``scenarios.mega_grid``: 12 960 cells full mode, a shrunken smoke
-    under ``--quick``).
+    """``fig10/megagrid/*``: the streaming sharded engine on the full
+    sensitivity cross-product (``scenarios.mega_grid``: 12 960 cells
+    full mode, a shrunken smoke under ``--quick``).
 
-    Four cold end-to-end runs, with ``clear_sim_caches()`` before each
-    so every path pays its own prep/compile:
+    One cold end-to-end run after ``clear_sim_caches()``: ``engine_s``
+    times :func:`repro.core.engine.run_grid` (tiled, cell-sharded over
+    the local devices, double-buffered host prep, one device-resident
+    dedup'd bank, tiles ship int32 row indices, the tile program
+    gathers). Bank rows (from ``engine.bank_stats()``) record the run's
+    H2D bytes, bank rows, the engine-accounted device-memory high-water
+    mark, and the MEASURED resident bank device bytes of the per-shard
+    sub-bank partition -- per-shard, fleet total, the replicated
+    layout's cost, and their cut ratio.
 
-    * ``engine_s``    -- :func:`repro.core.engine.run_grid` (tiled,
-      cell-sharded over the local devices, double-buffered host prep,
-      columnar **bank** data plane: one device-resident dedup'd bank,
-      tiles ship int32 row indices, the kernel gathers);
-    * ``pr3_stacked_s`` -- the same engine on the PR-3 **stacked**
-      plane (full per-cell array copies per tile);
-    * ``blocked_s``   -- the current one-shot blocked batch (auto
-      chunk, banked plane);
-    * ``pr2_blocked_s`` -- the PR-2 path faithfully: one-shot batch at
-      the old default ``chunk_size=128``, stacked plane, with the
-      reduced-key cell-array sharing disabled (PR 2 derived every
-      cell's arrays from scratch).
-
-    Data-plane rows (from ``engine.bank_stats()``) record each engine
-    run's H2D bytes, bank rows, dedup ratio, the engine-accounted
-    device-memory high-water mark, and (PR 8) the MEASURED resident
-    bank device bytes of the per-shard sub-bank partition --
-    per-shard, fleet total, the replicated baseline, and their cut
-    ratio -- so the ``BENCH_protocol.json`` trajectory captures the
-    bank win across PRs.
-
-    ``oracle_bitident`` re-runs a handful of sampled cells through the
-    serial oracle and checks ``==``, so the speedup rows can never
-    quietly come from drifting arithmetic.
+    ``oracle_bitident`` re-runs cells through the serial oracle and
+    checks ``==`` -- every cell under ``--quick``, six sampled cells of
+    the full grid -- so the timing rows can never quietly come from
+    drifting arithmetic.
     """
     import jax
 
     from repro.core import engine as E
-    from repro.core.simulator import _CELL_ARRAY_CACHE, DEFAULT_CHUNK_SIZE
     from repro.core.scenarios import mega_grid
 
     if QUICK:
@@ -231,61 +173,15 @@ def bench_megagrid() -> List[Dict]:
     shards = res_e[0].meta["n_shards"]
     bank = E.bank_stats()
 
-    clear_sim_caches()
-    t0 = time.perf_counter()
-    res_p3 = E.run_grid(specs, n_stores=MEGA_STORES, data_plane="stacked")
-    pr3_s = time.perf_counter() - t0
-    stacked = E.bank_stats()
-    plane_ident = all(a.exec_time_ns == b.exec_time_ns
-                      and a.sb_full_frac == b.sb_full_frac
-                      for a, b in zip(res_e, res_p3))
-    del res_p3
-    clear_sim_caches()
+    # every cell of the quick smoke, a sample of the full grid
+    pick = range(n) if QUICK else list(range(0, n, max(1, n // 5)))[:6]
+    oracle = run_sweep([specs[i] for i in pick], n_stores=MEGA_STORES,
+                       engine="serial")
+    ident = all(res_e[i].exec_time_ns == rs.exec_time_ns
+                and res_e[i].sb_full_frac == rs.sb_full_frac
+                and res_e[i].repl_at_head_frac == rs.repl_at_head_frac
+                for i, rs in zip(pick, oracle))
 
-    # the one-shot comparison rows materialize the WHOLE grid as one
-    # batch (the wall the streaming tier exists to avoid): ~17 bytes
-    # per cell-store on device plus a host staging copy. Skip them --
-    # engine rows still stand -- rather than swap/OOM a small machine.
-    oneshot_bytes = 2 * 17 * MEGA_STORES * (n + 8)
-    budget = _available_memory_bytes()
-    oneshot_ok = budget is None or oneshot_bytes < 0.6 * budget
-
-    blocked_s = pr2_s = None
-    res_b = None
-    if oneshot_ok:
-        clear_sim_caches()
-        t0 = time.perf_counter()
-        res_b = simulate_batch(specs, n_stores=MEGA_STORES)
-        blocked_s = time.perf_counter() - t0
-
-        clear_sim_caches()
-        old_bound = _CELL_ARRAY_CACHE.maxsize
-        _CELL_ARRAY_CACHE.maxsize = 0    # PR 2: no cross-cell sharing
-        try:
-            t0 = time.perf_counter()
-            simulate_batch(specs, n_stores=MEGA_STORES,
-                           chunk_size=DEFAULT_CHUNK_SIZE,
-                           data_plane="stacked")   # PR 2 predates the bank
-            pr2_s = time.perf_counter() - t0
-        finally:
-            _CELL_ARRAY_CACHE.maxsize = old_bound
-            clear_sim_caches()
-
-    ident = plane_ident and (res_b is None or all(
-        a.exec_time_ns == b.exec_time_ns
-        and a.sb_full_frac == b.sb_full_frac
-        for a, b in zip(res_e, res_b)))
-    for i in list(range(0, n, max(1, n // 5)))[:6]:     # sampled cells
-        s = specs[i]
-        rs = simulate(s.workload, s.config, n_stores=MEGA_STORES,
-                      seed=s.seed, n_replicas=s.n_replicas,
-                      link_bw_gbps=s.link_bw_gbps, n_cns=s.n_cns,
-                      sb_size=s.sb_size, coalescing=s.coalescing)
-        ident = ident and (res_e[i].exec_time_ns == rs.exec_time_ns
-                           and res_e[i].repl_at_head_frac ==
-                           rs.repl_at_head_frac)
-
-    skipped = f"skipped(needs~{oneshot_bytes >> 30}GiB)"
     mb = 1.0 / (1 << 20)
     rows = [
         {"name": "fig10/megagrid/cells", "us_per_call": 0.0, "derived": n},
@@ -299,16 +195,10 @@ def bench_megagrid() -> List[Dict]:
          "derived": compiles},
         {"name": "fig10/megagrid/engine_shards", "us_per_call": 0.0,
          "derived": f"{shards}/{len(jax.devices())}dev"},
-        # data-plane rows: the columnar bank vs the PR-3 stacked copies
         {"name": "fig10/megagrid/bank_rows", "us_per_call": 0.0,
          "derived": f"{bank['trace_rows']}trace+{bank['wv_rows']}wv"},
         {"name": "fig10/megagrid/h2d_bank_mb", "us_per_call": 0.0,
          "derived": round(bank["h2d_bytes"] * mb, 1)},
-        {"name": "fig10/megagrid/h2d_stacked_mb", "us_per_call": 0.0,
-         "derived": round(stacked["h2d_bytes"] * mb, 1)},
-        {"name": "fig10/megagrid/h2d_ratio", "us_per_call": 0.0,
-         "derived": round(stacked["h2d_bytes"]
-                          / max(bank["h2d_bytes"], 1), 2)},
         # replication of staged arrays to the other shards is
         # device-to-device traffic, not host bandwidth: the whole bank
         # under "replicated", only the arrivals column under "sub"
@@ -318,8 +208,8 @@ def bench_megagrid() -> List[Dict]:
         # (engine._measured_device_bytes). The run uses the per-shard
         # sub-bank partition (PR 8 default): one copy of each max-plus
         # row fleet-wide, arrivals replicated, so the per-shard bytes
-        # drop to ~1/n_shards of the replicated PR-4 layout -- whose
-        # cost is exactly bank_mb x n_shards (pinned == measured by
+        # drop to ~1/n_shards of the replicated layout -- whose cost is
+        # exactly bank_mb x n_shards (pinned == measured by
         # tests/test_engine.py), the cut_ratio baseline below.
         {"name": "fig10/megagrid/bank_partition", "us_per_call": 0.0,
          "derived": str(bank["bank_partition"])},
@@ -338,32 +228,11 @@ def bench_megagrid() -> List[Dict]:
          "derived": round(bank["bank_dev_bytes_per_shard"]
                           / max(bank["bank_bytes"] / max(shards, 1), 1),
                           3)},
-        {"name": "fig10/megagrid/dedup_ratio", "us_per_call": 0.0,
-         "derived": round(bank["dedup_ratio"], 2)},
         {"name": "fig10/megagrid/dev_mem_hwm_mb", "us_per_call": 0.0,
          "derived": round(bank["dev_mem_hwm_bytes"] * mb, 1)},
-        {"name": "fig10/megagrid/pr3_stacked_s",
-         "us_per_call": pr3_s * 1e6 / n, "derived": round(pr3_s, 2)},
-        {"name": "fig10/megagrid/speedup_bank_over_stacked",
-         "us_per_call": 0.0,
-         "derived": round(pr3_s / max(engine_s, 1e-9), 2)},
-        {"name": "fig10/megagrid/blocked_s",
-         "us_per_call": (blocked_s or 0.0) * 1e6 / n,
-         "derived": round(blocked_s, 2) if blocked_s else skipped},
-        {"name": "fig10/megagrid/pr2_blocked_s",
-         "us_per_call": (pr2_s or 0.0) * 1e6 / n,
-         "derived": round(pr2_s, 2) if pr2_s else skipped},
         {"name": "fig10/megagrid/oracle_bitident", "us_per_call": 0.0,
          "derived": int(ident)},
     ]
-    if blocked_s:
-        rows.insert(-1, {"name": "fig10/megagrid/speedup_engine_over_blocked",
-                         "us_per_call": 0.0,
-                         "derived": round(blocked_s / max(engine_s, 1e-9), 2)})
-    if pr2_s:
-        rows.insert(-1, {"name": "fig10/megagrid/speedup_engine_over_pr2",
-                         "us_per_call": 0.0,
-                         "derived": round(pr2_s / max(engine_s, 1e-9), 2)})
     return rows
 
 

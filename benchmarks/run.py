@@ -5,10 +5,9 @@ appends the run to the ``BENCH_protocol.json`` trajectory.
 Sections:
   fig2/*        WB vs WT (paper Fig. 2)
   fig10/*       five configurations + geomeans vs paper claims (Fig. 10),
-                plus fig10/sweep/* engine wall-clock tracking (serial
-                oracle vs PR-1 per-step scan vs blocked scan) and
-                fig10/megagrid/* (streaming sharded tier vs one-shot
-                blocked on the full sensitivity cross-product)
+                plus fig10/sweep/* (engine vs serial oracle wall clock)
+                and fig10/megagrid/* (the streaming sharded engine on
+                the full sensitivity cross-product)
   fig9/recovery/*  SS VII-E downtime estimates from the batched
                 failure-time x node recovery sweep
   fig11..18/*   characterization + sensitivity (Figs. 11-18)
@@ -52,7 +51,7 @@ JSONL for Perfetto.
 Perf history: every run appends ``{ts, quick, argv, rows}`` to
 ``benchmarks/BENCH_protocol.json`` (override the path with
 ``RECXL_BENCH_HISTORY=<path>``, disable with ``RECXL_BENCH_HISTORY=off``),
-so engine speedups are comparable across PRs. Row schema in
+so engine timings are comparable across PRs. Row schema in
 benchmarks/README.md.
 """
 

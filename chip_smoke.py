@@ -12,8 +12,8 @@ serial ``simulate()`` oracle with ``==``.
 
 Phase B starts a ``ScenarioServer`` at its default 50 000 stores, warms
 it on the daemon launcher's sweep grid, serves the launcher's mixed
-hit/miss stream (60 queries) and checks every answer ``==`` the blocked
-one-shot engine.
+hit/miss stream (60 queries) and checks every answer ``==`` the serial
+``simulate()`` oracle.
 
 ``--chips 4`` runs the mega-grid on a 4-shard ``cells`` mesh and
 compares it ``==`` with one shard in the same process, then loses a
@@ -149,12 +149,13 @@ def phase_sweep(specs: Sequence, n_stores: int, n_oracle: int = 32,
 def phase_serve(n_stores: int, n_queries: int = 60,
                 seed: int = 0) -> Dict[str, object]:
     """Phase B: warm a ``ScenarioServer``, serve the launcher's mixed
-    stream, check 0 steady-state compiles and ``==`` the blocked
-    engine."""
+    stream, check 0 steady-state compiles and ``==`` the serial
+    oracle."""
     import numpy as np
 
-    from repro.core.engine import simulate_grid, trace_count
+    from repro.core.engine import trace_count
     from repro.core.serving import ScenarioServer
+    from repro.core.simulator import simulate_spec
     from repro.launch.serve_scenarios import query_stream
 
     warm_grid, stream = query_stream(n_queries, seed)
@@ -187,13 +188,15 @@ def phase_serve(n_stores: int, n_queries: int = 60,
             raise SmokeError(f"serving compiled {compiles} programs in "
                              f"steady state (expected 0)")
         served = srv.query_batch(stream)
-    oracle = simulate_grid(stream, n_stores=n_stores, engine="blocked")
-    bad = _mismatches(served, oracle)
-    print(f"B check: {len(stream)} answers vs simulate_grid(blocked), "
-          f"{bad} mismatches")
+    t0 = time.perf_counter()
+    by_spec = {s: simulate_spec(s, n_stores=n_stores)
+               for s in dict.fromkeys(stream)}
+    bad = _mismatches(served, [by_spec[s] for s in stream])
+    print(f"B check: {len(stream)} answers vs serial simulate(), "
+          f"{bad} mismatches ({time.perf_counter() - t0:.1f} s)")
     if bad:
         raise SmokeError(f"{bad} of {len(stream)} served answers differ "
-                         f"from the blocked engine")
+                         f"from the serial oracle")
     return {"p50_ms": p50, "p99_ms": p99, "qps": len(stream) / wall,
             "hit_ratio": st["hit_ratio"], "compiles": compiles}
 

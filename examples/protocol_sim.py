@@ -2,11 +2,10 @@
 trace-driven protocol simulator, compare against the published claims,
 and estimate post-failure downtime (SS VII-E).
 
-The whole 9-workload x 5-configuration grid runs as ONE batched
-``simulate_batch`` call through the blocked-scan engine (see the
-ScenarioSpec API in repro/core/simulator.py); the PR-1 per-step engine
-is timed alongside for reference, and a batched ``recovery_sweep``
-reports estimated downtime per workload across the dump interval.
+The whole 9-workload x 5-configuration grid runs as ONE ``run_sweep``
+call through the streaming engine (see the ScenarioSpec API in
+repro/core/simulator.py), and a batched ``recovery_sweep`` reports
+estimated downtime per workload across the dump interval.
 
     PYTHONPATH=src python examples/protocol_sim.py
 """
@@ -14,12 +13,11 @@ reports estimated downtime per workload across the dump interval.
 import time
 
 from repro.configs.recxl_paper import PAPER_CLAIMS, WORKLOADS
-from repro.core.scenarios import recovery_sweep
+from repro.core.scenarios import recovery_sweep, run_sweep
 from repro.core.simulator import (
     CONFIGS,
     ScenarioSpec,
     geomean_slowdowns,
-    simulate_batch,
     slowdowns_from_results,
 )
 
@@ -31,18 +29,14 @@ def main() -> None:
           "(16 CN / 16 MN cluster, Table II parameters)...")
     specs = [ScenarioSpec(w, c) for w in WORKLOADS for c in CONFIGS]
     t0 = time.perf_counter()
-    results = simulate_batch(specs, n_stores=N_STORES)
+    results = run_sweep(specs, n_stores=N_STORES)
     cold = time.perf_counter() - t0
     t0 = time.perf_counter()
-    results = simulate_batch(specs, n_stores=N_STORES)
+    results = run_sweep(specs, n_stores=N_STORES)
     warm = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    simulate_batch(specs, n_stores=N_STORES, chunk_size=0)
-    perstep = time.perf_counter() - t0
     table = slowdowns_from_results(results)
     gm = geomean_slowdowns(table)
-    print(f"...{len(specs)} cells: {cold:.2f}s cold, {warm*1e3:.0f} ms warm "
-          f"(blocked scan; per-step engine: {perstep*1e3:.0f} ms)")
+    print(f"...{len(specs)} cells: {cold:.2f}s cold, {warm*1e3:.0f} ms warm")
 
     print(f"\n{'workload':14s}" + "".join(
         f"{c:>11s}" for c in CONFIGS))

@@ -1,84 +1,54 @@
-"""Sharded streaming mega-grid engine (the tier above ``simulate_batch``).
+"""Sharded streaming grid engine: the simulator's one fast path.
 
-``simulate_batch`` runs a whole grid as ONE blocked-scan call: perfect
-up to a few thousand cells, but a mega-grid (>10^4 cells -- the full
-(workload x config x N_r x bw x CN x SB) sensitivity space of Figs.
-10/16-18, times seeds) hits three walls:
+:func:`run_grid` runs any scenario grid -- one cell, a figure grid, or
+the 12 960-cell (workload x config x seed x N_r x bw x CN x SB)
+mega-grid of Figs. 10/16-18 -- and returns results ``==`` the serial
+``simulate()`` oracle (tests/test_engine.py asserts it). Four stages:
 
-* **one device** -- the time-major ``(n_stores, B)`` layout makes the
-  cell axis embarrassingly parallel, yet the whole batch scans on a
-  single device;
-* **one giant allocation + one compile per batch shape** -- every grid
-  size stacks fresh ``(n_stores, B)`` arrays and jits a program for
-  that exact ``B``;
-* **serialized host prep** -- trace synthesis / per-cell cost
-  derivation for the *whole* grid completes before the first scan step
-  runs.
+1. **Scan lanes and the columnar trace bank.** A timeline consumes
+   nothing but its (arrivals row, max-plus row, SB depth), so cells
+   sharing that triple are ONE scan lane: each lane is scanned once and
+   its outputs are scattered to the member cells (the 12 960-cell
+   mega-grid scans ~2 700 lanes). The grid's
+   :class:`~repro.core.simulator.TraceBank` holds each unique arrivals
+   row and each host-collapsed ``(w, v, pr_nc)`` max-plus row once; it
+   is uploaded once per grid and stays device-resident, and tiles ship
+   only two ``int32`` row-index vectors. The tile program gathers its
+   rows in-jit (``_bank_gather``) and runs the blocked scan
+   (``_scan_wv``).
 
-This module is the streaming tier that removes all three:
+2. **Tile scheduler** (:func:`plan_tiles`). Lanes are grouped by
+   store-buffer depth, so every tile is SB-uniform and runs the
+   tuple-history blocked scan, and each group is cut into tiles padded
+   to the full tile size, so a grid runs one compiled program per SB
+   group (the :class:`TileSignature` -> :func:`_tile_fn` cache),
+   however ragged it is.
 
-1. **Tile scheduler** (:func:`plan_tiles`). The grid is split into
-   tiles of at most :data:`DEFAULT_TILE_CELLS` cells, grouped by
-   store-buffer depth first, so every tile is SB-uniform and runs the
-   tuple-history fast path of the blocked scan -- a mixed-SB mega-grid
-   never falls back to the gather path the way a one-shot batch must.
-   Every tile is padded to a small set of canonical cell counts
-   (:func:`_canonical_sizes`), so an entire mega-grid executes with a
-   handful of compiled programs (:class:`TileSignature` ->
-   :func:`_tile_fn` cache), not one compile per ragged tail.
+3. **Per-shard sub-banks under ``shard_map``.** With more than one
+   local device the cell axis is sharded over a ``cells`` mesh
+   (``repro.distributed.context.cells_mesh``). The three max-plus
+   planes are partitioned into per-shard sub-banks (row ``r`` on shard
+   ``r % n_shards``) and every lane is scheduled onto the shard that
+   owns its row, so the gather and the scan run with ZERO cross-device
+   communication; cells are independent timelines, so sharding cannot
+   change a lane's arithmetic. ``bank_partition="replicated"`` (one
+   full bank per shard) is kept for the degraded-mesh recovery.
 
-2. **``shard_map`` over a ``cells`` mesh axis.** Each tile's arrays are
-   ``device_put`` with the cell axis sharded over all local devices
-   (``repro.distributed.context.cells_mesh`` /
-   ``repro.distributed.sharding.tile_shardings``) and the blocked scan
-   runs per shard with ZERO cross-device communication -- cells are
-   independent timelines, sharding is a pure partition. Elementwise
-   lane arithmetic is unchanged, so results stay bit-identical to the
-   single-device path and the serial oracle (tests/test_engine.py
-   asserts ``==``).
+4. **Double-buffered streaming.** A worker thread prepares tile k+1's
+   index vectors and result scalars while the devices compute tile k;
+   dispatch runs ahead of the devices by at most
+   :data:`MAX_IN_FLIGHT_TILES` tiles before the oldest is drained,
+   bounding live memory at the bank plus a few tile payloads. A second
+   thread compiles every signature against the resident bank while the
+   first tiles run.
 
-3. **Double-buffered streaming.** A single worker thread prepares tile
-   k+1 while the devices compute tile k; dispatch is async and runs
-   ahead of the devices by at most :data:`MAX_IN_FLIGHT_TILES` tiles
-   before the oldest is drained, bounding live memory. Host prep cost
-   is further collapsed by the reduced-key ``_cell_arrays`` memo
-   (cells differing only in config class / SB / CN share one
-   derivation) on the stacked plane; a bank-plane tile needs no
-   per-store array of a cell, only ``_cell_scalars``, whose per-trace
-   part is memoized. Everything is dropped by
-   ``repro.core.simulator.clear_sim_caches()`` -- including this
-   module's compiled-tile cache, registered via
-   ``register_cache_clearer``.
+``SimResult.meta`` records the chunk, tile and shard geometry of each
+cell; :func:`bank_stats` reports the last run's bank accounting (H2D
+bytes, bank rows, scan lanes, measured device bytes). Everything this
+module caches is dropped by ``simulator.clear_sim_caches()`` (its
+compiled-tile cache is registered via ``register_cache_clearer``).
 
-4. **The columnar bank data plane** (``data_plane="bank"``, the
-   default). Host prep materializes each unique trace / max-plus
-   column exactly once in a :class:`~repro.core.simulator.TraceBank`,
-   uploads it ONCE per mega-grid as a device-resident bank (columns
-   replicated across the ``cells`` mesh -- any shard's cells may
-   gather any row, and a replicated bank keeps the gather local and
-   communication-free), and tiles carry only two ``int32`` row-index
-   vectors. The tile program gathers its columns *inside* the jitted /
-   ``shard_map``'d program with an XLA gather (``_bank_gather``, then
-   the shared ``_scan_wv`` core -- one program on every backend), so
-   H2D bytes and host stacking scale with ``unique_rows`` instead of
-   ``cells``. And because a timeline consumes nothing but (arrivals
-   row, max-plus row, SB depth), cells sharing that triple are one
-   **scan lane**: the engine scans each unique lane once and scatters
-   the outputs to member cells, so device compute too scales with
-   unique lanes (the 12 960-cell mega-grid scans ~2 700).
-   ``data_plane="stacked"`` keeps the per-cell plane (full per-cell
-   copies, ``_stack_tile``) as the measured baseline; both planes are
-   bit-identical.
-   :func:`bank_stats` reports the last run's data-plane accounting
-   (H2D bytes, bank rows, dedup ratio, device-memory high-water mark).
-
-:func:`simulate_grid` is the tier selector: grids below
-:data:`STREAM_THRESHOLD` cells go to the blocked one-shot batch, larger
-grids stream; ``engine=`` forces a tier. ``SimResult.meta`` records
-which tier ran, the chunk used, the tile/shard geometry and the data
-plane.
-
-Two notes on axes and threads that this module gets for free:
+Two notes on axes and threads:
 
 * **Coupled axes ride the plane keys.** Lane and bank-row dedup both
   key on ``simulator._plane_keys``, which already appends the resolved
@@ -87,10 +57,10 @@ Two notes on axes and threads that this module gets for free:
   host, before the bank ever sees it). The engine therefore needs no
   knowledge of either axis: coupled cells that share a (shard,
   epoch-profile) still collapse to one scan lane, and axis-off grids
-  produce byte-identical keys -- and rows -- to the legacy plane.
+  produce byte-identical keys -- and rows -- to grids without the axes.
 * **Memo caches are shared with worker threads.** The prefetch and
   compile-warm executors mutate the same :class:`BoundedCache` memos
-  (`_cell_arrays`, trace synthesis, compiled tiles) as the caller;
+  (per-trace scalars, trace synthesis, compiled tiles) as the caller;
   ``hostcache.BoundedCache`` serializes per-cache, so each key is
   built exactly once even when a warm thread and the dispatch loop
   race on it.
@@ -124,23 +94,16 @@ from repro.core.simulator import (
     ScenarioSpec,
     SimResult,
     TraceBank,
-    _CELL_ARRAY_CACHE,
     _TRACE_SCALAR_CACHE,
     _bank_gather,
-    _CellInputs,
     _cell_scalars,
-    _commit_cost_ns,
     _finish_result,
-    _pad_len,
-    _prepare_cell,
+    _plane_keys,
     _scan_wv,
-    _timeline_batch_blocked,
-    _trace_cached,
     auto_chunk,
+    bank_row_maps,
     get_trace_bank,
     register_cache_clearer,
-    simulate_batch,
-    simulate_spec,
     sub_bank_rows,
 )
 from repro.distributed.context import cells_mesh, shard_map
@@ -150,18 +113,16 @@ from repro.distributed.sharding import (
     index_shardings,
     sub_bank_shardings,
     sub_bank_tile_specs,
-    tile_shardings,
-    tile_specs,
 )
 
-#: Cells per tile (before canonical padding) at the default byte
-#: budget. Large enough that one scan amortizes dispatch overhead,
-#: small enough that a tile's five (B_tile, n_stores) arrays stream
-#: through cache instead of RAM.
+#: Lanes per tile at the default byte budget. Large enough that one
+#: scan amortizes dispatch overhead, small enough that a tile's
+#: gathered (B_tile, n_stores) inputs stream through cache instead of
+#: RAM.
 DEFAULT_TILE_CELLS = 1024
 
-#: Byte budget for one tile's five per-store input arrays (~4+1+4+4+4
-#: bytes per cell-store). Long traces shrink the tile cell count so the
+#: Byte budget for one tile's per-store inputs, counted at 17 bytes
+#: per lane-store. Long traces shrink the tile lane count so the
 #: double-buffered ring (tile k on device, tile k+1 on the prep thread)
 #: stays at ~2x this footprint regardless of ``n_stores``. 128 MB
 #: measured fastest end-to-end at paper-scale store counts (the sweet
@@ -174,10 +135,6 @@ def _default_tile_cells(n_stores: int) -> int:
     return int(min(DEFAULT_TILE_CELLS,
                    max(64, DEFAULT_TILE_BYTES // per_cell)))
 
-
-#: Grid size at which ``simulate_grid(engine="auto")`` switches from the
-#: one-shot blocked batch to the streaming sharded tier.
-STREAM_THRESHOLD = 2048
 
 #: Dispatched-but-undrained tile bound. Dispatch runs ahead of device
 #: compute, so this -- together with the prep thread's one-tile
@@ -211,7 +168,7 @@ class EngineWorkerError(RuntimeError):
             msg += f" on tile {tile_no}"
         if sig is not None:
             msg += (f" (sig: b_pad={sig.b_pad} sb={sig.sb_uniform}"
-                    f" chunk={sig.chunk} plane={sig.data_plane})")
+                    f" chunk={sig.chunk})")
         if note:
             msg += f": {note}"
         super().__init__(msg)
@@ -254,26 +211,24 @@ class TileSignature:
     """Everything that selects a compiled tile program.
 
     Two tiles with equal signatures reuse one XLA executable: ``b_pad``
-    is the canonical padded cell count, ``chunk`` the blocked-scan block
-    length, ``sb_uniform`` the tile's (uniform, by scheduling) SB depth,
-    ``sb_max`` its padded ring width, ``n_shards`` the ``cells`` mesh
-    size, ``data_plane`` which input plane the program consumes, and
-    ``bank_shape`` the ``(trace_rows, wv_rows)`` of the grid's bank
-    (``(0, 0)`` on the stacked plane) -- jit specializes on the bank's
-    shape, so it is part of the program key. ``bank_sub=True`` selects
-    the per-shard sub-bank layout (the default banked plane): the three
-    max-plus columns arrive as a ``(n_shards, local_rows, n_stores)``
-    shard-partitioned stack, wv indices are shard-LOCAL, and
-    ``bank_shape[1]`` is the local (per-shard) row count. A whole
-    mega-grid runs with a handful of distinct signatures.
+    is the padded lane count, ``chunk`` the blocked-scan block length,
+    ``sb_uniform`` the tile's (uniform, by scheduling) SB depth,
+    ``n_shards`` the ``cells`` mesh size, and ``bank_shape`` the
+    ``(trace_rows, wv_rows)`` of the grid's bank -- jit specializes on
+    the bank's shape, so it is part of the program key.
+    ``bank_sub=True`` selects the per-shard sub-bank layout (the
+    default): the three max-plus columns arrive as a ``(n_shards,
+    local_rows, n_stores)`` shard-partitioned stack, wv indices are
+    shard-LOCAL, and ``bank_shape[1]`` is the local (per-shard) row
+    count; ``False`` is the replicated bank of the degraded-mesh
+    recovery. A whole mega-grid runs with a handful of distinct
+    signatures.
     """
     b_pad: int
     n_stores: int
     chunk: int
-    sb_max: int
     sb_uniform: int
     n_shards: int
-    data_plane: str = "stacked"
     bank_shape: Tuple[int, int] = (0, 0)
     bank_sub: bool = False
 
@@ -288,8 +243,8 @@ class Tile:
     contiguous blocks of ``b_pad // n_shards`` slots, and lane ``j``
     sits inside the block of the shard that OWNS its wv row, so the
     in-jit gather under ``shard_map`` stays shard-local. ``None`` means
-    the identity layout (entry ``j`` at position ``j``), as on the
-    stacked and replicated-bank planes."""
+    the identity layout (entry ``j`` at position ``j``), as at one shard
+    and on the replicated bank."""
     indices: Tuple[int, ...]
     specs: Tuple[ScenarioSpec, ...]
     sig: TileSignature
@@ -297,21 +252,9 @@ class Tile:
 
 
 def _align(n_shards: int) -> int:
-    """Cell-count alignment: a multiple of 8 (batch padding contract of
-    ``_stack_cells``) and of the shard count (shard_map divisibility)."""
+    """Tile-size alignment: a multiple of 8 and of the shard count
+    (shard_map divisibility)."""
     return 8 * n_shards // math.gcd(8, n_shards)
-
-
-def _canonical_sizes(tile_cells: int, align: int) -> List[int]:
-    """The canonical padded cell counts: the full tile and a 1/8 tile
-    (rounded up to ``align``). Ragged last tiles pad UP to the smallest
-    canonical size that fits, so at most two batch shapes -- and
-    therefore compiled programs -- exist per SB signature of a
-    mega-grid. The set is deliberately tiny: a compile costs ~50x more
-    than scanning the padding cells it would avoid, so only genuinely
-    small groups (<= tile/8 cells) get their own shape."""
-    small = -(-max(1, tile_cells // 8) // align) * align
-    return sorted({small, tile_cells})
 
 
 def plan_tiles(specs: Sequence[ScenarioSpec],
@@ -320,21 +263,18 @@ def plan_tiles(specs: Sequence[ScenarioSpec],
                chunk_size: Optional[int] = None,
                tile_cells: int = DEFAULT_TILE_CELLS,
                n_shards: int = 1,
-               small_pad: bool = True,
                owners: Optional[Sequence[int]] = None) -> List[Tile]:
-    """Schedule a grid into canonically-shaped, SB-uniform tiles.
+    """Schedule a grid into full-size, SB-uniform tiles.
 
     Cells are grouped by resolved store-buffer depth (preserving order
     within a group -- results are scattered back to original positions
     by :func:`run_grid`), so every tile runs the tuple-history fast
     path with its chunk clamped only by its OWN depth, not the
     narrowest cell of the whole grid. Each group is cut into
-    ``tile_cells``-sized tiles padded to canonical sizes.
-    ``small_pad=False`` drops the 1/8-tile canonical size, so every
-    tile pads to the FULL tile: one compiled program per SB group --
-    the banked plane uses this, because its deduplicated scan lanes
-    leave few tiles per group and a ragged tail's own program costs
-    ~50x the padding lanes it would avoid.
+    ``tile_cells``-sized tiles, and every tile -- a ragged last one too
+    -- pads to the full tile: one compiled program per SB group, since
+    deduplicated scan lanes leave few tiles per group and a ragged
+    tail's own program costs ~50x the padding lanes it would avoid.
 
     ``owners`` (sub-bank scheduling) gives each cell's owning shard
     (``wv_row % n_shards``, aligned with ``specs``): each tile's index
@@ -349,8 +289,6 @@ def plan_tiles(specs: Sequence[ScenarioSpec],
     """
     align = _align(n_shards)
     tile_cells = max(align, -(-tile_cells // align) * align)
-    sizes = _canonical_sizes(tile_cells, align) if small_pad \
-        else [tile_cells]
 
     groups: Dict[int, List[Tuple[int, ScenarioSpec]]] = {}
     for i, s in enumerate(specs):
@@ -362,10 +300,8 @@ def plan_tiles(specs: Sequence[ScenarioSpec],
         chunk = auto_chunk(n_stores, sb, tile_cells) if chunk_size is None \
             else max(1, min(chunk_size, n_stores, sb))
 
-        def sig_for(b_pad: int) -> TileSignature:
-            return TileSignature(b_pad=b_pad, n_stores=n_stores, chunk=chunk,
-                                 sb_max=_pad_len(sb), sb_uniform=sb,
-                                 n_shards=n_shards)
+        sig = TileSignature(b_pad=tile_cells, n_stores=n_stores,
+                            chunk=chunk, sb_uniform=sb, n_shards=n_shards)
 
         if owners is not None and n_shards > 1:
             by_shard: List[List[Tuple[int, ScenarioSpec]]] = \
@@ -376,25 +312,19 @@ def plan_tiles(specs: Sequence[ScenarioSpec],
             n_tiles = max(1, -(-max(len(b) for b in by_shard) // block))
             for t in range(n_tiles):
                 part: List[Tuple[int, ScenarioSpec]] = []
-                blocks = [b[t * block:(t + 1) * block] for b in by_shard]
-                widest = max(len(b) for b in blocks)
-                b_pad = next(c for c in sizes if c // n_shards >= widest)
-                per = b_pad // n_shards
                 slots: List[int] = []
-                for sh, blk in enumerate(blocks):
-                    for q, (i, s) in enumerate(blk):
+                for sh, b in enumerate(by_shard):
+                    for q, (i, s) in enumerate(b[t * block:(t + 1) * block]):
                         part.append((i, s))
-                        slots.append(sh * per + q)
+                        slots.append(sh * block + q)
                 tiles.append(Tile(indices=tuple(i for i, _ in part),
                                   specs=tuple(s for _, s in part),
-                                  sig=sig_for(b_pad), slots=tuple(slots)))
+                                  sig=sig, slots=tuple(slots)))
             continue
         for off in range(0, len(members), tile_cells):
             part = members[off:off + tile_cells]
-            b_pad = next(c for c in sizes if c >= len(part))
             tiles.append(Tile(indices=tuple(i for i, _ in part),
-                              specs=tuple(s for _, s in part),
-                              sig=sig_for(b_pad)))
+                              specs=tuple(s for _, s in part), sig=sig))
     return tiles
 
 
@@ -417,20 +347,18 @@ _BANK_STATS: Dict[str, object] = {}
 
 
 def bank_stats() -> Dict[str, object]:
-    """Data-plane accounting of the most recent :func:`run_grid` call
+    """Bank accounting of the most recent :func:`run_grid` call
     (``trace_count()``-style observability; benchmarks turn it into the
-    ``fig10/megagrid/*`` data-plane rows). Keys:
+    ``fig10/megagrid/*`` bank rows). Keys:
 
-    * ``data_plane`` -- ``"bank"`` or ``"stacked"``; ``cells`` /
-      ``n_shards`` -- run geometry; ``scan_lanes`` -- unique timelines
-      actually scanned (== ``cells`` on the stacked plane);
+    * ``cells`` / ``n_shards`` -- run geometry; ``scan_lanes`` -- unique
+      timelines actually scanned;
     * ``bank_partition`` -- ``"sub"`` (per-shard sub-banks, the
-      default) or ``"replicated"`` on the bank plane, ``None`` on the
-      stacked plane;
+      default) or ``"replicated"``;
     * ``trace_rows`` / ``wv_rows`` / ``bank_rows`` -- deduplicated bank
-      columns (0 on the stacked plane); ``bank_bytes`` -- host bytes of
-      one bank copy; ``bank_dev_bytes_per_shard`` / ``bank_dev_bytes``
-      -- **measured** resident device bytes of the placed bank (summed
+      columns; ``bank_bytes`` -- host bytes of one bank copy;
+      ``bank_dev_bytes_per_shard`` / ``bank_dev_bytes`` -- **measured**
+      resident device bytes of the placed bank (summed
       from the live buffers' addressable shards: max per device, and
       fleet total). Replicated placement measures ~``bank x n_shards``
       total; the sub-bank placement holds one copy of each max-plus
@@ -444,9 +372,6 @@ def bank_stats() -> Dict[str, object]:
       device-to-device bytes of replicating staged arrays to the other
       shards (NOT host bandwidth; the whole bank under the replicated
       placement, only the arrivals column under sub-banks);
-      ``stacked_h2d_bytes`` -- what the stacked plane would have
-      shipped host->device for the same grid; ``dedup_ratio`` -- their
-      ratio (>= 1; 1.0 on the stacked plane);
     * ``dev_mem_hwm_bytes`` -- engine-accounted device-memory
       high-water mark: the measured resident bank bytes plus the
       in-flight tiles' input payloads at their peak.
@@ -455,35 +380,9 @@ def bank_stats() -> Dict[str, object]:
     return dict(_BANK_STATS)
 
 
-def _build_tile_fn(sig: TileSignature) -> Callable:
-    if sig.data_plane == "bank":
-        return _build_bank_tile_fn(sig)
-
-    def run(arrivals, coalesce, exposed, t_repl_i, svc_i,
-            config_idx, sb_size, t_l1, t_wt):
-        global _TRACE_COUNT
-        _TRACE_COUNT += 1          # runs once per trace, not per call
-        # tiles arrive cell-major (host stacking is then a row memcpy
-        # per cell); the transpose to the scan's time-major layout is a
-        # cheap local device op, fused ahead of the block reshapes
-        return _timeline_batch_blocked(
-            arrivals.T, coalesce.T, exposed.T, t_repl_i.T, svc_i.T,
-            config_idx, sb_size, sig.sb_max, sig.chunk, sig.sb_uniform,
-            t_l1, t_wt)
-
-    if sig.n_shards > 1:
-        # every op in the blocked scan is lane-wise over the cell axis,
-        # so partitioning cells over the mesh needs no collectives and
-        # cannot change a single lane's arithmetic
-        run = shard_map(run, cells_mesh(sig.n_shards),
-                        in_specs=tile_specs() + (P(), P()),
-                        out_specs=(P("cells"),) * 3)
-    return jax.jit(run)
-
-
 def _build_bank_tile_fn(sig: TileSignature) -> Callable:
-    """Banked tile program: in-jit XLA gather from the device-resident
-    bank columns, then the shared ``_scan_wv`` blocked scan -- the same
+    """The tile program: in-jit XLA gather from the device-resident
+    bank columns, then the blocked scan ``_scan_wv`` -- the same
     program on every backend. Tiles ship only the two ``int32``
     row-index vectors.
 
@@ -496,7 +395,7 @@ def _build_bank_tile_fn(sig: TileSignature) -> Callable:
     owner's slot block) runs against shard-resident rows with zero
     cross-shard communication, through the SAME program as the
     replicated layout. Gathering a local row moves the identical bits
-    the global gather would, so the planes stay ``==``."""
+    the global gather would, so both layouts stay ``==``."""
 
     def run(a_bank, w_bank, v_bank, p_bank, trace_idx, wv_idx):
         global _TRACE_COUNT
@@ -506,14 +405,12 @@ def _build_bank_tile_fn(sig: TileSignature) -> Callable:
             # reshape on device: axis 0 is size 1 inside shard_map, and
             # the full local plane at n_shards=1)
             w_bank, v_bank, p_bank = w_bank[0], v_bank[0], p_bank[0]
-        # the shared gather (one row memcpy per cell + the same cheap
-        # device transpose as the stacked plane) -- and NO per-tile
-        # precompute: w/v were collapsed on the host, once per unique
-        # row
+        # the gather (one row memcpy per lane + a cheap device
+        # transpose) -- and NO per-tile precompute: w/v were collapsed
+        # on the host, once per unique row
         a, w, v, p = _bank_gather(a_bank, w_bank, v_bank, p_bank,
                                   trace_idx, wv_idx)
-        return _scan_wv(a, w, v, p, None, sig.sb_max, sig.chunk,
-                        sig.sb_uniform)
+        return _scan_wv(a, w, v, p, sig.chunk, sig.sb_uniform)
 
     if sig.n_shards > 1:
         # replicated: banks replicated (gathers stay local), indices
@@ -530,13 +427,13 @@ def _build_bank_tile_fn(sig: TileSignature) -> Callable:
 def _tile_fn(sig: TileSignature) -> Callable:
     fn = _TILE_FNS.get(sig)
     if fn is None:
-        fn = _TILE_FNS.setdefault(sig, _build_tile_fn(sig))
+        fn = _TILE_FNS.setdefault(sig, _build_bank_tile_fn(sig))
     return fn
 
 
 #: Public alias of the signature-keyed tile-program cache lookup. The
 #: scenario-serving daemon (``repro.core.serving``) batches queries
-#: into the SAME canonical tile shapes as the streaming engine and
+#: into the SAME full-size tile shapes as the streaming engine and
 #: calls the programs through this entry, so steady-state serving adds
 #: zero compiles beyond the signatures :func:`warm_signatures` warmed
 #: (``trace_count()`` counts serve-path traces too -- tests pin it).
@@ -552,42 +449,13 @@ def _clear_engine_caches() -> None:
 # Double-buffered streaming executor
 # ---------------------------------------------------------------------------
 
-def _stack_tile(cells: List[_CellInputs], b_pad: int) -> tuple:
-    """Stack one tile's cells **cell-major** ``(B, n_stores)``.
-
-    Unlike the one-shot batch's time-major stacking (a strided scatter
-    per cell), cell-major stacking is a contiguous row memcpy per cell;
-    the device transposes to time-major inside the tile program, where
-    it costs a fraction of the host scatter. Padding repeats cell 0.
-    """
-    padded = cells + [cells[0]] * (b_pad - len(cells))
-    return (
-        np.stack([c.arrivals for c in padded], axis=0),
-        np.stack([c.coalesce for c in padded], axis=0),
-        np.stack([c.exposed for c in padded], axis=0),
-        np.stack([c.t_repl_i for c in padded], axis=0),
-        np.stack([c.svc_i for c in padded], axis=0),
-        np.asarray([c.config_idx for c in padded], np.int32),
-        np.asarray([c.sb_size for c in padded], np.int32),
-    )
-
-
-def _prep_tile(tile: Tile, n_stores: int, cluster: ClusterConfig
-               ) -> Tuple[List[_CellInputs], tuple]:
-    """Host-side prep for one stacked-plane tile (runs on the prefetch
-    thread): ``_prepare_cell`` per cell + the PR-3 cell-major array
-    stacking. The banked plane's prep lives in :func:`run_grid` (it
-    needs the lane->cells map) and ships only index vectors."""
-    cells = [_prepare_cell(s, _trace_cached(s.workload, n_stores, s.seed,
-                                            cluster), n_stores, cluster)
-             for s in tile.specs]
-    return cells, _stack_tile(cells, tile.sig.b_pad)
+def _tile_payload_bytes(sig: TileSignature) -> int:
+    """Host->device bytes of one tile: its two int32 index vectors."""
+    return 8 * sig.b_pad
 
 
 def _place_tile(np_args: tuple, sig: TileSignature) -> tuple:
-    """Put one tile's per-tile host arrays on the mesh, cell axis
-    sharded (index vectors on the banked plane, the five stacked arrays
-    plus per-cell vectors on the stacked plane).
+    """Put one tile's two index vectors on the mesh, cell axis sharded.
 
     All callers (the streaming loop AND the compile-warming thread) go
     through here so every call of a tile program sees identically
@@ -595,17 +463,14 @@ def _place_tile(np_args: tuple, sig: TileSignature) -> tuple:
     a mismatch would silently compile each program twice."""
     if sig.n_shards == 1:
         return np_args
-    mesh = cells_mesh(sig.n_shards)
-    shardings = index_shardings(mesh) if sig.data_plane == "bank" \
-        else tile_shardings(mesh)
-    return jax.device_put(np_args, shardings)
+    return jax.device_put(np_args, index_shardings(cells_mesh(sig.n_shards)))
 
 
 def _place_bank(bank: TraceBank, n_shards: int) -> Tuple[int, tuple]:
     """Device-resident bank columns for one mesh size: replicated over
     the ``cells`` mesh (gathers stay shard-local), plain committed
     arrays on a single device. Memoized on the bank -- one upload per
-    (bank, mesh), shared by every tile and engine that sweeps the grid.
+    (bank, mesh), shared by every tile and sweep of the grid.
 
     Replication is staged: the host arrays cross to device 0 ONCE (the
     only host->device transfer -- what ``h2d_bytes`` counts), and the
@@ -689,8 +554,7 @@ def _measured_device_bytes(arrays: Sequence[jax.Array]) -> Tuple[int, int]:
     return sum(per_dev.values()), max(per_dev.values())
 
 
-def warm_signatures(sigs: List[TileSignature], t_l1, t_wt,
-                    bank_dev: Optional[tuple] = None) -> None:
+def warm_signatures(sigs: List[TileSignature], bank_dev: tuple) -> None:
     """Compile every distinct tile program with zero inputs (runs on the
     compile thread, so XLA compilation -- which releases the GIL --
     overlaps the first tiles' host prep and device compute; jax's
@@ -700,60 +564,17 @@ def warm_signatures(sigs: List[TileSignature], t_l1, t_wt,
     the first live query never pays a compile.
 
     Warming goes through a real call, so the warmed entry is the one
-    the streaming loop's calls hit. Banked programs warm against the
-    REAL device-resident bank (placed on the main thread before this
-    runs -- a zero bank of the right shape would hit the same program
-    but duplicating the replicated placement measured slower than the
+    the streaming loop's calls hit. Programs warm against the REAL
+    device-resident bank (placed on the main thread before this runs --
+    a zero bank of the right shape would hit the same program but
+    duplicating the replicated placement measured slower than the
     compile it hides) with zero index vectors: row 0 is a valid gather
     everywhere, and the warm call sees exactly the shardings of the
     streaming loop's calls."""
     for sig in sigs:
-        if sig.data_plane == "bank":
-            idx = (np.zeros((sig.b_pad,), np.int32),
-                   np.zeros((sig.b_pad,), np.int32))
-            _tile_fn(sig)(*bank_dev, *_place_tile(idx, sig))
-            continue
-        args = (np.zeros((sig.b_pad, sig.n_stores), np.float32),
-                np.zeros((sig.b_pad, sig.n_stores), bool),
-                np.zeros((sig.b_pad, sig.n_stores), np.float32),
-                np.zeros((sig.b_pad, sig.n_stores), np.float32),
-                np.zeros((sig.b_pad, sig.n_stores), np.float32),
-                np.zeros((sig.b_pad,), np.int32),
-                np.full((sig.b_pad,), sig.sb_uniform, np.int32))
-        _tile_fn(sig)(*_place_tile(args, sig), t_l1, t_wt)
-
-
-_warm_signatures = warm_signatures        # internal alias (streaming loop)
-
-
-def _stacked_tile_bytes(sig: TileSignature) -> int:
-    """Host bytes of one stacked tile's payload (5 per-store arrays at
-    ~17 B per cell-store + the two per-cell i32 vectors)."""
-    return sig.b_pad * (17 * sig.n_stores + 8)
-
-
-def _stacked_plane_h2d(specs: Sequence[ScenarioSpec],
-                       cluster: ClusterConfig, n_stores: int,
-                       tile_cells: int, n_shards: int) -> int:
-    """Bytes the stacked plane would ship for this grid: the cell-tiling
-    byte sum of :func:`plan_tiles`, computed from the per-SB group
-    sizes alone (same alignment + canonical-pad rules, no Tile
-    objects). The banked plane's accounting baseline."""
-    align = _align(n_shards)
-    tile_cells = max(align, -(-tile_cells // align) * align)
-    sizes = _canonical_sizes(tile_cells, align)
-    groups: Dict[int, int] = {}
-    for s in specs:
-        sb = s.sb_size if s.sb_size is not None else cluster.store_buffer
-        groups[sb] = groups.get(sb, 0) + 1
-    per_cell = 17 * n_stores + 8
-    total = 0
-    for m in groups.values():
-        full, rem = divmod(m, tile_cells)
-        total += full * tile_cells * per_cell
-        if rem:
-            total += next(c for c in sizes if c >= rem) * per_cell
-    return total
+        idx = (np.zeros((sig.b_pad,), np.int32),
+               np.zeros((sig.b_pad,), np.int32))
+        _tile_fn(sig)(*bank_dev, *_place_tile(idx, sig))
 
 
 def run_grid(specs: Sequence[ScenarioSpec],
@@ -762,35 +583,32 @@ def run_grid(specs: Sequence[ScenarioSpec],
              chunk_size: Optional[int] = None,
              tile_cells: Optional[int] = None,
              n_shards: Optional[int] = None,
-             data_plane: Optional[str] = None,
              bank_partition: Optional[str] = None,
              k_replicas: Optional[int] = None,
              worker_timeout_s: Optional[float] = None) -> List[SimResult]:
-    """Stream a (mega-)grid through the sharded tile engine.
+    """Stream a grid through the sharded tile engine.
 
-    Results come back in ``specs`` order, bit-identical to
-    ``simulate_batch`` and the serial oracle. ``chunk_size=None`` uses
-    the :func:`auto_chunk` heuristic per SB group; ``tile_cells``
-    defaults to the :data:`DEFAULT_TILE_BYTES` budget (capped at
-    :data:`DEFAULT_TILE_CELLS`); ``n_shards`` defaults to every local
+    Results come back in ``specs`` order, bit-identical to the serial
+    oracle. One device-resident columnar bank serves the grid, tiles
+    ship index vectors, the tile program gathers, and only unique *scan
+    lanes* (cells with distinct ``(SB, trace, max-plus row)`` triples
+    -- the only inputs a timeline consumes) are scanned, with lane
+    outputs scattered to member cells. ``chunk_size=None`` uses the
+    :func:`auto_chunk` heuristic per SB group; ``tile_cells`` defaults
+    to the :data:`DEFAULT_TILE_BYTES` budget (capped at
+    :data:`DEFAULT_TILE_CELLS`), narrowed to the widest SB group's lane
+    count when that fits one tile; ``n_shards`` defaults to every local
     device (1 falls back to single-device streaming -- still tiled,
-    cached and double-buffered). ``data_plane`` is ``"bank"`` by
-    default -- one device-resident columnar bank per grid, tiles ship
-    index vectors, the kernel gathers, and only unique *scan lanes*
-    (cells with distinct ``(SB, trace, max-plus row)`` triples -- the
-    only inputs a timeline consumes) are scanned, with lane outputs
-    scattered to member cells -- or ``"stacked"`` for the PR-3
-    per-cell-copies plane (the measured baseline); results are
-    bit-identical either way.
+    cached and double-buffered).
 
-    ``bank_partition`` picks the banked plane's device layout:
-    ``"sub"`` (the default) partitions the three max-plus columns into
-    per-shard sub-banks -- one copy of each row fleet-wide, scan lanes
-    scheduled onto their owning shard with shard-local wv indices, so
-    resident bank device bytes are ~``1/n_shards`` of the replicated
-    layout with the gather still shard-local -- while ``"replicated"``
-    keeps the PR-4 one-copy-per-shard layout (the measured baseline).
-    Both partitions are bit-identical: they gather the same rows.
+    ``bank_partition`` picks the bank's device layout: ``"sub"`` (the
+    default) partitions the three max-plus columns into per-shard
+    sub-banks -- one copy of each row fleet-wide, scan lanes scheduled
+    onto their owning shard with shard-local wv indices, so resident
+    bank device bytes are ~``1/n_shards`` of the replicated layout with
+    the gather still shard-local -- while ``"replicated"`` holds one
+    full bank per shard (the degraded-mesh recovery uses it). Both
+    partitions are bit-identical: they gather the same rows.
 
     The loop overlaps three stages: the prefetch thread derives tile
     k+1's host payload while tile k's is placed cell-sharded on the
@@ -821,15 +639,14 @@ def run_grid(specs: Sequence[ScenarioSpec],
     """
     with _tm.span("engine/run", cells=len(specs)):
         return _run_grid(specs, cluster, n_stores, chunk_size, tile_cells,
-                         n_shards, data_plane, bank_partition, k_replicas,
+                         n_shards, bank_partition, k_replicas,
                          worker_timeout_s)
 
 
 def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
               n_stores: int, chunk_size: Optional[int],
               tile_cells: Optional[int], n_shards: Optional[int],
-              data_plane: Optional[str], bank_partition: Optional[str],
-              k_replicas: Optional[int],
+              bank_partition: Optional[str], k_replicas: Optional[int],
               worker_timeout_s: Optional[float]) -> List[SimResult]:
     """:func:`run_grid`'s body, inside its ``engine/run`` span."""
     if not specs:
@@ -837,17 +654,12 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
     if chunk_size is not None and chunk_size < 1:
         raise ValueError(
             f"chunk_size must be >= 1 (or None for auto), got {chunk_size}")
-    plane = data_plane or "bank"
-    if plane not in ("bank", "stacked"):
-        raise ValueError(f"unknown data_plane {data_plane!r}")
     partition = bank_partition or "sub"
     if partition not in ("sub", "replicated"):
         raise ValueError(f"unknown bank_partition {bank_partition!r}")
-    if k_replicas is not None and k_replicas != 1 and \
-            (plane != "bank" or partition != "sub"):
+    if k_replicas is not None and k_replicas != 1 and partition != "sub":
         raise ValueError("k_replicas > 1 applies to the sub-partitioned "
-                         f"bank plane only (got plane={plane!r}, "
-                         f"partition={partition!r})")
+                         f"bank only (got partition={partition!r})")
     n_dev = len(jax.devices())
     if n_shards is None:
         # all local devices: even oversubscribed virtual CPU devices
@@ -858,102 +670,88 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
     if not 1 <= n_shards <= n_dev:
         raise ValueError(f"n_shards must be in [1, {n_dev}], got {n_shards}")
 
-    from repro.core.simulator import _plane_keys, bank_row_maps
-
     bank = bank_dev = None
     bank_fresh = 0
-    sub = False
     k_eff = 1
     local_rows = 0
     lane_members: List[List[int]] = []
-    costs = _commit_cost_ns("proactive", cluster)
-    t_l1 = np.float32(costs["t_l1"])
-    t_wt = np.float32(costs["t_wt"])
     results: List[Optional[SimResult]] = [None] * len(specs)
-
-    # --- data-plane accounting (bank_stats / SimResult.meta) -----------
-    def tile_payload_bytes(sig: TileSignature) -> int:
-        return 8 * sig.b_pad if plane == "bank" else _stacked_tile_bytes(sig)
 
     with _tm.span("engine/plan"):
         for s in specs:
             s.validate(cluster)
-        plan_kw = dict(cluster=cluster, n_stores=n_stores,
-                       chunk_size=chunk_size,
-                       tile_cells=tile_cells or _default_tile_cells(n_stores),
-                       n_shards=n_shards)
-        if plane == "bank":
-            # --- scan-lane dedup ---------------------------------------
-            # A cell's timeline consumes exactly (arrivals row, max-plus
-            # row, SB depth) -- nothing else. Cells sharing that triple
-            # (e.g. the whole CN axis of a sweep, or WB/WT cells across
-            # replication knobs) therefore have bit-identical timelines:
-            # the engine scans each unique LANE once and scatters the
-            # lane outputs to every member cell (work_scale and the
-            # bandwidth / log metrics are per-cell host math in
-            # ``_finish_result``, as on every other tier). The
-            # mega-grid's 12 960 cells collapse to ~2 700 scanned lanes.
-            lane_of: Dict[tuple, int] = {}
-            lane_specs: List[ScenarioSpec] = []
-            lane_wv_keys: List[tuple] = []
-            for i, s in enumerate(specs):
-                sb = s.sb_size if s.sb_size is not None \
-                    else cluster.store_buffer
-                key = (sb,) + _plane_keys(s, cluster)
-                j = lane_of.setdefault(key, len(lane_specs))
-                if j == len(lane_specs):
-                    lane_specs.append(s)
-                    lane_wv_keys.append(key[2])
-                    lane_members.append([i])
-                else:
-                    lane_members[j].append(i)
-            # the bank's SHAPE comes from a cheap key pass, so the tile
-            # signatures -- and therefore compile warming -- do not wait
-            # for the heavy row materialization below
-            trace_map, wv_map = bank_row_maps(specs, cluster)
-            sub = partition == "sub"
-            if sub:
-                # per-shard sub-banks: the signature carries the LOCAL
-                # (per-shard) wv row count, and the scheduler places each
-                # lane in the slot block of the shard owning its wv row.
-                # k_eff > 1 (chaos/recovery runs only) appends the
-                # Replica set blocks along the local axis -- the
-                # signature sees the widened stack (jit specializes on
-                # the bank shape), while indices keep targeting the
-                # primary block
-                k_eff = _chaos.resolve_k_replicas(k_replicas, n_shards)
-                local_rows = sub_bank_rows(len(wv_map), n_shards)
-                shape = (len(trace_map), k_eff * local_rows)
-                owners = [wv_map[wk] % n_shards for wk in lane_wv_keys]
+        # --- scan-lane dedup -------------------------------------------
+        # A cell's timeline consumes exactly (arrivals row, max-plus row,
+        # SB depth) -- nothing else. Cells sharing that triple (e.g. the
+        # whole CN axis of a sweep, or WB/WT cells across replication
+        # knobs) therefore have bit-identical timelines: the engine scans
+        # each unique LANE once and scatters the lane outputs to every
+        # member cell (work_scale and the bandwidth / log metrics are
+        # per-cell host math in ``_finish_result``). The mega-grid's
+        # 12 960 cells collapse to ~2 700 scanned lanes.
+        lane_of: Dict[tuple, int] = {}
+        lane_specs: List[ScenarioSpec] = []
+        lane_wv_keys: List[tuple] = []
+        for i, s in enumerate(specs):
+            sb = s.sb_size if s.sb_size is not None else cluster.store_buffer
+            key = (sb,) + _plane_keys(s, cluster)
+            j = lane_of.setdefault(key, len(lane_specs))
+            if j == len(lane_specs):
+                lane_specs.append(s)
+                lane_wv_keys.append(key[2])
+                lane_members.append([i])
             else:
-                shape = (len(trace_map), len(wv_map))
-                owners = None
-            tiles = [dataclasses.replace(
-                t, sig=dataclasses.replace(t.sig, data_plane="bank",
-                                           bank_shape=shape, bank_sub=sub))
-                for t in plan_tiles(lane_specs, small_pad=False,
-                                    owners=owners, **plan_kw)]
-            # what the stacked plane would ship for the SAME grid (it
-            # tiles cells, not lanes) -- the dedup_ratio baseline,
-            # counted from the per-SB group sizes without materializing
-            # a throwaway tiling
-            stacked_h2d = _stacked_plane_h2d(specs, cluster, n_stores,
-                                             plan_kw["tile_cells"], n_shards)
+                lane_members[j].append(i)
+        # the bank's SHAPE comes from a cheap key pass, so the tile
+        # signatures -- and therefore compile warming -- do not wait for
+        # the heavy row materialization below
+        trace_map, wv_map = bank_row_maps(specs, cluster)
+        sub = partition == "sub"
+        if sub:
+            # per-shard sub-banks: the signature carries the LOCAL
+            # (per-shard) wv row count, and the scheduler places each
+            # lane in the slot block of the shard owning its wv row.
+            # k_eff > 1 (chaos/recovery runs only) appends the Replica
+            # set blocks along the local axis -- the signature sees the
+            # widened stack (jit specializes on the bank shape), while
+            # indices keep targeting the primary block
+            k_eff = _chaos.resolve_k_replicas(k_replicas, n_shards)
+            local_rows = sub_bank_rows(len(wv_map), n_shards)
+            shape = (len(trace_map), k_eff * local_rows)
+            owners = [wv_map[wk] % n_shards for wk in lane_wv_keys]
         else:
-            tiles = plan_tiles(specs, **plan_kw)
-            stacked_h2d = sum(_stacked_tile_bytes(t.sig) for t in tiles)
+            shape = (len(trace_map), len(wv_map))
+            owners = None
+        width = tile_cells
+        if width is None:
+            # a grid whose widest SB group (per owning shard) fits one
+            # default tile pads only to that width: a small grid scans,
+            # and picks its chunk for, its own lanes, not a full tile
+            per_group: Dict[tuple, int] = {}
+            for j, key in enumerate(lane_of):
+                g = (key[0], owners[j] if owners else 0)
+                per_group[g] = per_group.get(g, 0) + 1
+            width = min(_default_tile_cells(n_stores),
+                        max(per_group.values()) * (n_shards if owners
+                                                   else 1))
+        tiles = [dataclasses.replace(
+            t, sig=dataclasses.replace(t.sig, bank_shape=shape,
+                                       bank_sub=sub))
+            for t in plan_tiles(
+                lane_specs, cluster=cluster, n_stores=n_stores,
+                chunk_size=chunk_size, tile_cells=width,
+                n_shards=n_shards, owners=owners)]
     _tm.count("engine/cells", len(specs))
-    _tm.count("engine/lanes", len(lane_members) if plane == "bank"
-              else len(specs))
+    _tm.count("engine/lanes", len(lane_members))
     _tm.count("engine/tiles", len(tiles))
-    h2d_bytes = sum(tile_payload_bytes(t.sig) for t in tiles)
+    h2d_bytes = sum(_tile_payload_bytes(t.sig) for t in tiles)
     live_bytes = 0
     hwm_bytes = 0
     fabric_bytes = 0
     bank_dev_total = bank_dev_per = 0
 
-    def prep_banked(tile: Tile):
-        """Banked tile prep (prefetch thread): the two padded int32
+    def prep(tile: Tile):
+        """Tile prep (prefetch thread): the two padded int32
         row-index vectors, plus per-MEMBER-cell result scalars grouped
         by lane (the scatter targets -- ``_cell_scalars`` builds no
         per-store array: the bank's rows already hold them).
@@ -977,12 +775,6 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                   for lane in tile.indices]
         return groups, (trace_idx, wv_idx)
 
-    def prep_stacked(tile: Tile):
-        cells, np_args = _prep_tile(tile, n_stores, cluster)
-        return [[(i, c)] for i, c in zip(tile.indices, cells)], np_args
-
-    prep = prep_banked if plane == "bank" else prep_stacked
-
     def finish(entry) -> None:
         """Drain one dispatched tile: blocks until its device compute is
         done, releasing its input buffers, and scatters each lane's
@@ -998,7 +790,7 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
             exec_ns = np.asarray(exec_ns)
             at_head = np.asarray(at_head)
             sb_full = np.asarray(sb_full)
-        live_bytes -= tile_payload_bytes(tile.sig)
+        live_bytes -= _tile_payload_bytes(tile.sig)
         slots = tile.slots if tile.slots is not None \
             else range(len(tile.indices))
         with _tm.span("tile/finish", tile=kt):
@@ -1010,11 +802,9 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                             "auto_chunk": chunk_size is None,
                             "tile_cells": tile.sig.b_pad,
                             "n_shards": tile.sig.n_shards,
-                            "data_plane": plane,
-                            "bank_partition": (partition if plane == "bank"
-                                               else None),
-                            "bank_rows": (bank.n_rows if bank is not None
-                                          else 0),
+                            "data_plane": "bank",
+                            "bank_partition": partition,
+                            "bank_rows": bank.n_rows,
                             "h2d_bytes": h2d_bytes,
                             "bank_fabric_bytes": fabric_bytes}
                     results[i] = _finish_result(cell, exec_ns[pos],
@@ -1048,7 +838,7 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
             st.on_thread("warm")
         try:
             with _tm.span("compile/warm", signatures=len(sigs)):
-                _warm_signatures(sigs, t_l1, t_wt, bank_dev)
+                warm_signatures(sigs, bank_dev)
         except ChaosError:
             raise
         except Exception as e:
@@ -1089,7 +879,7 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
         tile's wv rows against the host truth before dispatch. Chaos
         verification runs only -- the production path never reads
         device rows back."""
-        if st is None or not st.wants_verify() or bank is None:
+        if st is None or not st.wants_verify():
             return
         rows = sorted({bank.rows_for(sp)[1] for sp in tile.specs})
         _chaos.verify_rows(bank, bank_dev, rows[:VERIFY_ROWS_PER_TILE],
@@ -1145,8 +935,8 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
             # elastic-scaling policy it shares with the trainer tier
             from repro.distributed.elastic import cells_spare_replacement
             cells_spare_replacement(n_shards, lost)
-        source = "redispatch"
-        if bank is not None and sub and lost is not None:
+        source = "host"
+        if sub and lost is not None:
             with _tm.span("recover/rebuild", shard=lost):
                 if k_eff >= 2:
                     rebuilt = _chaos.replica_rebuild(
@@ -1162,12 +952,9 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                     source = "host"
                 if rebuilt is not None:
                     _chaos.verify_rebuild(bank, rebuilt, lost, n_shards)
-        elif bank is not None:
-            source = "host"
-        if bank is not None:
-            with _tm.span("recover/replace", source=source):
-                bank.drop_placement(bank_place_key())
-                place_bank_now()
+        with _tm.span("recover/replace", source=source):
+            bank.drop_placement(bank_place_key())
+            place_bank_now()
         if st is not None:
             st.note_recovery(source, (time.monotonic() - t0) * 1e3,
                              lost, "spare")
@@ -1180,25 +967,23 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
     prep_pool = ThreadPoolExecutor(max_workers=1)
     compile_pool = ThreadPoolExecutor(max_workers=1)
     try:
-        if plane == "bank":
-            # materialize + upload the bank before warming: the warm
-            # calls (and every tile call) gather from the one resident
-            # placement, and compilation overlaps the first tiles' loop
-            bank = get_trace_bank(specs, n_stores, cluster)
-            place_bank_now()
-            if st is not None:
-                # chaos row corruption lands on the DEVICE copy only
-                # (the host columns stay the truth the CRC digests and
-                # rebuilds verify against)
-                bank_dev = st.tamper_bank(
-                    bank_dev, n_shards=n_shards,
-                    k_replicas=k_eff if sub else 1,
-                    local_cap=local_rows if sub else 0,
-                    wv_rows=bank.wv_rows)
-            live_bytes = hwm_bytes = bank_dev_total
-        # host derivations the tiles cause, past the bank build (which
-        # counts its own): per-store cell arrays, per-trace scalars
-        arrays0 = _CELL_ARRAY_CACHE.misses
+        # materialize + upload the bank before warming: the warm calls
+        # (and every tile call) gather from the one resident placement,
+        # and compilation overlaps the first tiles' loop
+        bank = get_trace_bank(specs, n_stores, cluster)
+        place_bank_now()
+        if st is not None:
+            # chaos row corruption lands on the DEVICE copy only (the
+            # host columns stay the truth the CRC digests and rebuilds
+            # verify against)
+            bank_dev = st.tamper_bank(
+                bank_dev, n_shards=n_shards,
+                k_replicas=k_eff if sub else 1,
+                local_cap=local_rows if sub else 0,
+                wv_rows=bank.wv_rows)
+        live_bytes = hwm_bytes = bank_dev_total
+        # per-trace result scalars the tiles derive, past the bank
+        # build (which counts its own rows)
         scalars0 = _TRACE_SCALAR_CACHE.misses
         sigs = list(dict.fromkeys(t.sig for t in tiles))
         warm = compile_pool.submit(warm_guarded)
@@ -1225,7 +1010,7 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                     verify_tile(tile)
 
                     def place_dispatch(args=np_args, sig=tile.sig):
-                        _h2d_hook(tile_payload_bytes(sig))
+                        _h2d_hook(_tile_payload_bytes(sig))
                         return _place_tile(args, sig)
 
                     with _tm.span("tile/h2d", tile=kt):
@@ -1240,15 +1025,13 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                                      else "tile/dispatch")
                     redispatch_pending = False
                     with _tm.span(dispatch_span, tile=kt):
-                        out = _tile_fn(tile.sig)(*bank_dev, *placed) \
-                            if bank is not None \
-                            else _tile_fn(tile.sig)(*placed, t_l1, t_wt)
+                        out = _tile_fn(tile.sig)(*bank_dev, *placed)
                     in_flight.append((kt, tile, groups, out))
                     _tm.gauge("engine/in_flight_tiles",
                               len(in_flight))
                     _tm.gauge("engine/prefetch_queue_depth",
                               len(pending) - pi - 1)
-                    live_bytes += tile_payload_bytes(tile.sig)
+                    live_bytes += _tile_payload_bytes(tile.sig)
                     hwm_bytes = max(hwm_bytes, live_bytes)
                     # backpressure: dispatch runs ahead of the devices,
                     # so without a bound every dispatched tile's input
@@ -1271,13 +1054,12 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                     with _tm.span("recover/rollback",
                                   tiles=len(in_flight)):
                         for (_kt, t_, _g, _o) in in_flight:
-                            live_bytes -= tile_payload_bytes(t_.sig)
+                            live_bytes -= _tile_payload_bytes(t_.sig)
                         in_flight.clear()
                     recover_attempts += 1
                     if st is None or recover_attempts > MAX_RECOVERIES:
                         raise
                     if (isinstance(e, ShardLossError) and n_shards > 1
-                            and plane == "bank"
                             and st.cfg.recovery == "degraded"):
                         degraded_from = e.shard
                         break
@@ -1291,7 +1073,6 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
     finally:
         prep_pool.shutdown(wait=True)
         compile_pool.shutdown(wait=True)
-    _tm.count("engine/cell_arrays_built", _CELL_ARRAY_CACHE.misses - arrays0)
     _tm.count("engine/result_scalars_built",
               _TRACE_SCALAR_CACHE.misses - scalars0)
 
@@ -1307,7 +1088,6 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
                            n_stores=n_stores, chunk_size=chunk_size,
                            tile_cells=tile_cells,
                            n_shards=cells_degraded_shards(n_shards),
-                           data_plane="bank",
                            bank_partition="replicated")
         for i, r in zip(left, sub_res):
             results[i] = r
@@ -1318,19 +1098,17 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
 
     _BANK_STATS.clear()
     _BANK_STATS.update({
-        "data_plane": plane, "cells": len(specs), "n_shards": n_shards,
-        "bank_partition": partition if plane == "bank" else None,
-        "scan_lanes": len(lane_members) if plane == "bank" else len(specs),
-        "trace_rows": bank.trace_rows if bank is not None else 0,
-        "wv_rows": bank.wv_rows if bank is not None else 0,
-        "bank_rows": bank.n_rows if bank is not None else 0,
-        "bank_bytes": bank.nbytes if bank is not None else 0,
+        "cells": len(specs), "n_shards": n_shards,
+        "bank_partition": partition,
+        "scan_lanes": len(lane_members),
+        "trace_rows": bank.trace_rows,
+        "wv_rows": bank.wv_rows,
+        "bank_rows": bank.n_rows,
+        "bank_bytes": bank.nbytes,
         "bank_dev_bytes_per_shard": bank_dev_per,
         "bank_dev_bytes": bank_dev_total,
         "h2d_bytes": h2d_bytes,
         "bank_fabric_bytes": fabric_bytes,
-        "stacked_h2d_bytes": stacked_h2d,
-        "dedup_ratio": stacked_h2d / max(h2d_bytes, 1),
         "dev_mem_hwm_bytes": hwm_bytes,
         "k_replicas": k_eff,
         "degraded": degraded_from is not None,
@@ -1347,73 +1125,3 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
             if r is not None and r.meta is not None:
                 r.meta.setdefault("telemetry", summ)
     return results
-
-
-# ---------------------------------------------------------------------------
-# Tier selection
-# ---------------------------------------------------------------------------
-
-def simulate_grid(specs: Sequence[ScenarioSpec],
-                  cluster: ClusterConfig = PAPER_CLUSTER,
-                  n_stores: int = 50_000,
-                  engine: str = "auto",
-                  chunk_size: Optional[int] = None,
-                  tile_cells: Optional[int] = None,
-                  n_shards: Optional[int] = None,
-                  data_plane: Optional[str] = None,
-                  bank_partition: Optional[str] = None,
-                  k_replicas: Optional[int] = None,
-                  worker_timeout_s: Optional[float] = None
-                  ) -> List[SimResult]:
-    """Run a scenario grid on the right engine tier.
-
-    ``engine``:
-
-    * ``"auto"`` (default) -- blocked one-shot batch below
-      :data:`STREAM_THRESHOLD` cells, streaming sharded tier at or
-      above it;
-    * ``"serial"`` -- the per-cell oracle loop (differential testing);
-    * ``"perstep"`` -- the PR-1 per-step batched scan;
-    * ``"blocked"`` -- one-shot blocked batch (``simulate_batch``);
-    * ``"stream"`` -- the tiled sharded/streaming engine
-      (:func:`run_grid`).
-
-    ``data_plane`` (blocked and stream tiers) selects the columnar bank
-    (default) or the stacked per-cell-copies baseline;
-    ``bank_partition`` (stream tier only -- the one with a sharded
-    placement) selects per-shard sub-banks (default) or the replicated
-    layout, see :func:`run_grid`. All tiers and planes return
-    bit-identical results in ``specs`` order; ``SimResult.meta``
-    records what actually ran.
-    """
-    if engine == "auto":
-        engine = "stream" if len(specs) >= STREAM_THRESHOLD else "blocked"
-    if bank_partition is not None and engine != "stream":
-        raise ValueError(
-            f"bank_partition applies to the stream tier only, not {engine!r}")
-    if (k_replicas is not None or worker_timeout_s is not None) \
-            and engine != "stream":
-        raise ValueError("k_replicas / worker_timeout_s apply to the "
-                         f"stream tier only, not {engine!r}")
-    if engine == "serial":
-        for s in specs:
-            s.validate(cluster)
-        return [simulate_spec(s, cluster=cluster, n_stores=n_stores)
-                for s in specs]
-    if engine == "perstep":
-        # forwarded so an explicit data_plane="bank" raises (the
-        # per-step engine has no banked plane) instead of silently
-        # running stacked
-        return simulate_batch(specs, cluster=cluster, n_stores=n_stores,
-                              chunk_size=0, data_plane=data_plane)
-    if engine == "blocked":
-        return simulate_batch(specs, cluster=cluster, n_stores=n_stores,
-                              chunk_size=chunk_size, data_plane=data_plane)
-    if engine == "stream":
-        return run_grid(specs, cluster=cluster, n_stores=n_stores,
-                        chunk_size=chunk_size, tile_cells=tile_cells,
-                        n_shards=n_shards, data_plane=data_plane,
-                        bank_partition=bank_partition,
-                        k_replicas=k_replicas,
-                        worker_timeout_s=worker_timeout_s)
-    raise ValueError(f"unknown engine {engine!r}")

@@ -6,7 +6,7 @@ code:
 * **Sweep scenarios** -- grids of :class:`~repro.core.simulator.ScenarioSpec`
   cells over the paper's sensitivity space (Figs. 10/16/17/18). The grid
   builders here are consumed by ``benchmarks/protocol_benches.py`` and by
-  the property tests, and every grid runs as ONE ``simulate_batch`` call.
+  the property tests, and every grid runs through :func:`run_sweep`.
 
 * **Fault scenarios** -- end-to-end resilience runs on a real device mesh:
   train steps replicate state through the :class:`ReplicationEngine`,
@@ -129,8 +129,8 @@ def mega_grid(seeds: Sequence[int] = (0, 1, 2),
               sb_sizes: Sequence[int] = (72, 48)) -> List[ScenarioSpec]:
     """The full cross-product sensitivity space of Figs. 10/16-18 as one
     grid: (workload x config x seed x N_r x bw x CN x SB). At the
-    defaults this is 12 960 cells -- the mega-grid scale the streaming
-    engine tier exists for (``fig10/megagrid/*`` bench rows run it)."""
+    defaults this is 12 960 cells -- the ``megagrid`` benchmark cell
+    (``fig10/megagrid/*`` bench rows run it too)."""
     return sweep_grid(seeds=seeds, n_replicas=replicas,
                       link_bw_gbps=bandwidths, n_cns=cn_counts,
                       sb_sizes=sb_sizes)
@@ -181,10 +181,10 @@ def contention_mega_grid(workloads: Sequence[str] = tuple(WORKLOADS),
                          (0.0, 0.6),
                          schedules: Sequence[Optional[str]] =
                          ("lazy", "epoch", "eager")) -> List[ScenarioSpec]:
-    """The contention cross-product at streaming-tier scale
+    """The contention cross-product at mega-grid scale
     (workload x config x seed x N_r x CN x conflict x read-share x
-    schedule -- 2 592 cells at the defaults, >= ``STREAM_THRESHOLD`` so
-    ``run_sweep`` picks the banked streaming engine). The neutral
+    schedule -- 2 592 cells at the defaults, the ``contention``
+    benchmark cell). The neutral
     ``(0.0, 0.0, "lazy")`` cells are bit-identical to the uncontended
     semantics and serve as in-grid normalization; the CN axis exercises
     scan-lane dedup (contention keys deliberately exclude ``n_cns``).
@@ -206,10 +206,10 @@ def directory_mega_grid(workloads: Sequence[str] = tuple(WORKLOADS),
                         (0.0, 0.2, 0.4, 0.7),
                         sb_sizes: Sequence[Optional[int]] = (72, 48)
                         ) -> List[ScenarioSpec]:
-    """The directory-coupling cross-product at streaming-tier scale
+    """The directory-coupling cross-product at mega-grid scale
     (workload x config x seed x N_r x CN x load x SB -- 2 592 cells at
-    the defaults, >= ``STREAM_THRESHOLD``; the 4-CN column exercises
-    the clamped directory census). ``directory_load=0.0``
+    the defaults; the 4-CN column exercises the clamped directory
+    census). ``directory_load=0.0``
     cells are bit-identical to the axis-off semantics and serve as the
     in-grid normalization baseline of the ``fig17/directory/*``
     slowdown rows; ``baseline`` pays the shard's queueing wait serially
@@ -230,25 +230,35 @@ def run_sweep(specs: Sequence[ScenarioSpec],
               n_stores: int = 50_000,
               engine: str = "auto",
               **engine_kw) -> List[SimResult]:
-    """Run a sweep grid on the right engine tier.
+    """Run a sweep grid: the one entry point for every grid.
 
-    The canonical entry point for every grid this module builds:
-    delegates to :func:`repro.core.engine.simulate_grid`, which picks
-    the one-shot blocked batch for ordinary figure grids and the
-    sharded streaming tier for mega-grids (>=
-    ``repro.core.engine.STREAM_THRESHOLD`` cells); ``engine=`` forces a
-    tier and ``engine_kw`` passes tile/shard/data-plane knobs through.
-    Results are in ``specs`` order and bit-identical across tiers.
+    ``engine="auto"`` (the default) and ``"stream"`` both run the grid
+    on :func:`repro.core.engine.run_grid`, the sharded streaming
+    engine, with ``engine_kw`` (tile, shard, chunk, partition and
+    resilience knobs) passed through; ``"serial"`` runs the per-cell
+    serial oracle (``simulator.simulate_spec``) for differential
+    testing and takes no ``engine_kw``. Any other value raises
+    ``ValueError``. Results are in ``specs`` order and bit-identical
+    either way.
 
-    Data plane: both banked tiers resolve the grid's columnar
-    :class:`~repro.core.simulator.TraceBank` through one digest-keyed
-    memo, so sweeping the same grid through several engines (or
-    repeatedly) builds and uploads the bank ONCE -- use
-    :func:`grid_bank` to pre-build it (or inspect its dedup) explicitly.
+    The engine resolves the grid's columnar
+    :class:`~repro.core.simulator.TraceBank` through a digest-keyed
+    memo, so sweeping the same grid repeatedly builds and uploads the
+    bank ONCE -- use :func:`grid_bank` to pre-build it (or inspect its
+    dedup) explicitly.
     """
-    from repro.core.engine import simulate_grid
-    return simulate_grid(specs, cluster=cluster, n_stores=n_stores,
-                         engine=engine, **engine_kw)
+    if engine in ("auto", "stream"):
+        from repro.core.engine import run_grid
+        return run_grid(specs, cluster=cluster, n_stores=n_stores,
+                        **engine_kw)
+    if engine != "serial":
+        raise ValueError(f"unknown engine {engine!r}")
+    if engine_kw:
+        raise ValueError(f"{sorted(engine_kw)} apply to the stream engine "
+                         "only, not 'serial'")
+    from repro.core.simulator import simulate_spec
+    return [simulate_spec(s, cluster=cluster, n_stores=n_stores)
+            for s in specs]
 
 
 def grid_bank(specs: Sequence[ScenarioSpec],
@@ -260,8 +270,7 @@ def grid_bank(specs: Sequence[ScenarioSpec],
     sweep-builder level: pre-building the bank before a timed or
     latency-sensitive sweep moves the one-off column materialization
     out of the measured path, and the returned handle is the SAME
-    object every banked engine tier will use (``clear_sim_caches``
-    drops it)."""
+    object the engine will use (``clear_sim_caches`` drops it)."""
     from repro.core.simulator import get_trace_bank
     return get_trace_bank(specs, n_stores, cluster)
 

@@ -1,6 +1,6 @@
 """Scenario-serving daemon: the engine as a long-lived service.
 
-Every batch tier (``simulate_batch``, ``run_grid``) is call-oriented:
+The sweep engine (``run_grid``) is call-oriented:
 one grid in, results out, state dropped. The ROADMAP's north star is
 the opposite shape -- millions of small "what if" queries against ONE
 persistent platform, the way the paper's SS VIII evaluation amortizes
@@ -18,7 +18,7 @@ How a query is served (docs/serving.md has the lifecycle diagram):
    scanned before (by any earlier query or the warm grid), the answer
    is pure host math over the cached lane outputs: no device work, no
    upload, bit-identical to a cold run because ``_finish_result`` is
-   the same code every other tier ends with.
+   the same code the sweep engine and the serial oracle end with.
 
 2. **Incremental bank diffs.** A miss extends the server's
    :class:`~repro.core.simulator.TraceBank` in place
@@ -39,8 +39,8 @@ How a query is served (docs/serving.md has the lifecycle diagram):
    ``concatenate``, no cross-device traffic.
 
 3. **Canonical batching.** Miss lanes are grouped and padded by
-   ``engine.plan_tiles(small_pad=False)`` into the SAME canonical
-   SB-uniform tile shapes the streaming engine compiles, and executed
+   ``engine.plan_tiles`` into the SAME full-size SB-uniform tile
+   shapes the streaming engine compiles, and executed
    through ``engine.tile_fn`` -- so the compiled-program cache, the
    ``trace_count()`` accounting and the capacity-shape trick together
    give **zero new compiles in steady state**: once :meth:`warm` has
@@ -102,7 +102,6 @@ from repro.core.scenarios import downtime_query, sweep_grid
 from repro.core.simulator import (
     ScenarioSpec,
     SimResult,
-    _commit_cost_ns,
     _cell_scalars,
     _finish_result,
     _plane_keys,
@@ -149,7 +148,7 @@ class ScenarioServer:
     lets the daemon thread batch concurrent queries within
     ``batch_window_ms``. Every protocol answer is bit-identical
     (``==`` on every physics field) to the cold
-    ``simulate_grid``/``simulate_spec`` oracle for the same spec --
+    serial ``simulate_spec`` oracle for the same spec --
     the server only ever reorganizes *which compiled program computes
     which lane when*, never the arithmetic.
 
@@ -184,7 +183,7 @@ class ScenarioServer:
     dead daemon thread, and fails a wedged flush's futures instead of
     blocking callers forever. The server always recovers on the
     spare-replacement path (its mesh never shrinks); the degraded-mesh
-    fallback is the batch engine's.
+    fallback is ``engine.run_grid``'s.
     """
 
     def __init__(self, cluster: ClusterConfig = PAPER_CLUSTER,
@@ -505,14 +504,12 @@ class ScenarioServer:
                                    n_stores=self.n_stores,
                                    chunk_size=self.chunk_size,
                                    tile_cells=self.batch_cells,
-                                   n_shards=self.n_shards, small_pad=False,
-                                   owners=owners)
+                                   n_shards=self.n_shards, owners=owners)
         # the signature sees the DEVICE local axis: k_replicas capacity
         # blocks (identical to self._cap at k=1 -- the resilient and
         # plain layouts share programs only with themselves)
         shape = (self._cap[0], self.k_replicas * self._cap[1])
-        return [(t, dataclasses.replace(t.sig, data_plane="bank",
-                                        bank_shape=shape,
+        return [(t, dataclasses.replace(t.sig, bank_shape=shape,
                                         bank_sub=True))
                 for t in tiles]
 
@@ -784,11 +781,8 @@ class ScenarioServer:
                 lanes.setdefault(self._lane_key(s), s)
             sigs = list(dict.fromkeys(
                 sig for _, sig in self._serve_sigs(list(lanes.values()))))
-            costs = _commit_cost_ns("proactive", self.cluster)
             compiled0 = _engine.trace_count()
-            _engine.warm_signatures(sigs, np.float32(costs["t_l1"]),
-                                    np.float32(costs["t_wt"]),
-                                    bank_dev=self._dev)
+            _engine.warm_signatures(sigs, self._dev)
             self._sigs.update(sigs)
             self._stats["compiled_programs"] += \
                 _engine.trace_count() - compiled0

@@ -29,94 +29,90 @@ Everything is deterministic given (workload, seed). Calibration targets
 are the paper's headline numbers (PAPER_CLAIMS in configs/recxl_paper.py);
 tests assert the reproduced geomeans land inside acceptance bands.
 
-Batched sweeps -- the ScenarioSpec API
---------------------------------------
+Grid sweeps -- the ScenarioSpec API
+-----------------------------------
 
 A whole evaluation grid (Figs. 10-18: workload x config x sensitivity
-knob) is ONE jitted call:
+knob) runs through one entry, ``repro.core.scenarios.run_sweep``:
 
     specs = [ScenarioSpec(w, c) for w in WORKLOADS for c in CONFIGS]
-    results = simulate_batch(specs)          # List[SimResult], same order
+    results = run_sweep(specs)               # List[SimResult], same order
 
 :class:`ScenarioSpec` names one grid cell: ``(workload, config, seed,
 n_replicas, link_bw_gbps, n_cns, sb_size, coalescing)``; ``None`` knobs
-default to the :class:`ClusterConfig`. ``simulate_batch`` synthesizes
-each unique ``(workload, seed)`` trace once, derives the per-cell cost
-arrays on the host, pads the batch (size to a multiple of 8, store-buffer
-rings to the widest cell), and runs one branch-free ``lax.scan`` over the
-stacked ``(B, n_stores)`` arrays in which all five commit rules are
-computed and the per-cell rule selected by config index.
+default to the :class:`ClusterConfig`. The sweep runs on the streaming
+engine (``repro.core.engine.run_grid``), which scans the grid's unique
+timelines over the columnar trace bank below with the blocked scan;
+this module holds the pieces it is built from and the serial oracle
+(:func:`simulate`) it is pinned against.
 
 The blocked scan
 ----------------
 
-The per-step batched scan (PR 1) is CPU-bound on ``lax.scan`` step
-overhead: every store is one scan step of a handful of tiny ``(B,)``
-ops. ``simulate_batch`` therefore defaults to a **blocked** formulation
-(``chunk_size`` stores per block -- the :func:`auto_chunk` heuristic
-when ``None``, always clamped to the narrowest SB in the batch: the SB
-depth bounds how far back the retire recurrence can look, so within a
-block every ``c_{i-sb}`` read refers to a *previous* block):
+A per-step scan is CPU-bound on ``lax.scan`` step overhead: every store
+would be one scan step of a handful of tiny ``(B,)`` ops. The blocked
+formulation (:func:`_scan_wv`; ``chunk`` stores per block -- the
+:func:`auto_chunk` heuristic by default, always clamped to the SB
+depth, which bounds how far back the retire recurrence can look, so
+within a block every ``c_{i-sb}`` read refers to a *previous* block)
+removes it:
 
 * everything that does not feed back into the commit recurrence is
-  precomputed **vectorized over the whole (B, n_stores) arrays** before
-  the scan: arrival times (one host-side ``np.cumsum`` per trace,
-  shared verbatim with the serial oracle), and the coalesce-mask
-  selects / exposed-latency terms of all five commit rules collapsed --
-  exactly, because IEEE-754 addition is monotone, so ``max(r, c) + e ==
-  max(r + e, c + e)`` and ``max(r + a, r + b) == r + max(a, b)`` hold
-  bit-for-bit -- into one shared max-plus recurrence
-  ``c_i = max(r_i + w_i, c_{i-1} + v_i)`` (see ``_blocked_precompute``);
+  precomputed before the scan: arrival times (one host-side
+  ``np.cumsum`` per trace, shared verbatim with the serial oracle), and
+  the coalesce-mask selects / exposed-latency terms of all five commit
+  rules collapsed -- exactly, because IEEE-754 addition is monotone, so
+  ``max(r, c) + e == max(r + e, c + e)`` and ``max(r + a, r + b) == r +
+  max(a, b)`` hold bit-for-bit -- into one shared max-plus recurrence
+  ``c_i = max(r_i + w_i, c_{i-1} + v_i)`` (:func:`_make_wv_row`, the
+  host form of :func:`_blocked_precompute`);
 * ``lax.scan`` runs only over **chunk boundaries** (``n_stores /
-  chunk_size`` steps); within a block, the SB-ring reads collapse to a
-  single vectorized gather from the carried commit history, retire
-  times and both censuses (SB-full, Fig. 11 REPL-at-head) are computed
-  as ``(B, K)`` block ops, and only the irreducible 2-op max-plus core
-  runs per store (an unrolled, fully fusible chain of ``(B,)`` ops);
-* a ragged tail (``n_stores % chunk_size``) is processed once after the
-  scan with the same step function, so every chunk size is exact.
+  chunk`` steps); the store-buffer ring is carried as a tuple of the
+  last ``sb`` commit times, so its reads are static indexing, both
+  censuses (SB-full, Fig. 11 REPL-at-head) accumulate in-scan, and only
+  the irreducible 2-op max-plus core runs per store (an unrolled, fully
+  fusible chain of ``(B,)`` ops);
+* a ragged tail (``n_stores % chunk``) is processed once after the scan
+  with the same step function, so every chunk size is exact.
 
-The result is **bit-identical** to the per-step scan and to the serial
-oracle, for every chunk size (tests/test_batch_sim.py enforces ``==``).
+The result is **bit-identical** to the serial oracle for every chunk
+size (tests/test_batch_sim.py enforces ``==``).
 
 The columnar trace-bank data plane
 ----------------------------------
 
-Stacking per-cell copies of the five per-store arrays scales host prep,
-H2D transfer and device memory with ``cells x n_stores`` even though
-arrivals are identical across every cell of one trace and the
-reduced-key :func:`_cell_arrays` memo already shares most derivations.
-The **bank** data plane (default for the blocked engine and the
-streaming tier) collapses that to ``unique_rows x n_stores``:
+Per-store inputs would scale host prep, H2D transfer and device memory
+with ``cells x n_stores``, even though arrivals are identical across
+every cell of one trace and most cells share their derivations. The
+**bank** collapses that to ``unique_rows x n_stores``:
 
 * one ``arrivals`` column per unique ``(workload, seed)`` trace;
 * one ``(w, v, pr_nc)`` column per unique *max-plus row key* --
   ``(config-rule, workload, seed, N_r, bw, coalescing)``, with the
   constant WB/WT rules collapsing to a single constant column each.
-  The max-plus collapse of :func:`_blocked_precompute` is applied **on
-  the host, once per unique row** (IEEE add/max/select are exactly
-  defined, so host numpy and XLA produce identical bits), so the device
-  never re-derives ``w``/``v`` per cell;
-* cells carry only two ``int32`` row indices; the jitted timeline
-  gathers its columns on device (:func:`_timeline_banked`), and the
-  streaming engine keeps one device-resident bank per mega-grid;
-* on the host a banked cell is only its result scalars
-  (:func:`_cell_scalars`, memoized per trace): its per-store arrays are
-  built once per unique row, by the bank build, and never per cell.
+  The max-plus collapse is applied **on the host, once per unique
+  row** (IEEE add/max/select are exactly defined, so host numpy and
+  XLA produce identical bits), so the device never re-derives
+  ``w``/``v`` per cell;
+* cells carry only two ``int32`` row indices; the tile program gathers
+  its columns on device (:func:`_bank_gather`), and the engine keeps
+  one device-resident bank per grid;
+* on the host a cell is only its result scalars (:func:`_cell_scalars`,
+  memoized per trace): its per-store arrays are built once per unique
+  row, by the bank build, and never per cell.
 
 :func:`get_trace_bank` builds (and memoizes) the bank;
-``tests/test_trace_bank.py`` property-tests that bank-gathered inputs
-reconstruct the stacked inputs bit-exactly.
+``tests/test_trace_bank.py`` property-tests that bank rows reconstruct
+the per-cell inputs bit-exactly.
 
-Batched-vs-serial contract: ``simulate()`` (the differential-testing
-oracle) and ``simulate_batch`` share trace synthesis and the per-cell
-cost derivation, and their timelines apply identical arithmetic -- every
-``SimResult`` field from the batched paths (blocked and per-step) must
-match the serial path bit-for-bit (tests/test_batch_sim.py enforces
-this across chunk sizes, including ragged tails). The serial path stays
-the readable reference; new commit rules must be added to
-``_timeline``, ``_timeline_batch`` and ``_blocked_precompute``/
-``_blocked_steps``.
+Engine-vs-serial contract: ``simulate()`` (the differential-testing
+oracle) and the engine share trace synthesis and the per-cell cost
+derivation, and their timelines apply identical arithmetic -- every
+``SimResult`` field from the engine must match the serial path
+bit-for-bit (tests/test_batch_sim.py enforces this across chunk sizes,
+including ragged tails). The serial path stays the readable reference;
+new commit rules must be added to ``_timeline``, ``_make_wv_row`` and
+``_blocked_precompute``.
 
 Contention & crash-consistency axes
 -----------------------------------
@@ -128,10 +124,10 @@ and sharer invalidations are added to the exposed coherence latency
 (the ``w`` side of the max-plus recurrence absorbs them through the
 store's ready time), persist barriers to the REPL-ack / drain terms
 (the ``v`` side), all inside :func:`_make_cell_arrays` BEFORE the
-collapse -- so every engine tier and both data planes work unchanged
-and stay bit-identical. Active axes append the
-resolved params to the bank's max-plus row key; all-``None`` axes
-change neither outputs nor dedup keys, bit-for-bit.
+collapse -- so the scan works unchanged and stays bit-identical to
+the serial oracle. Active axes append the resolved params to the bank's
+max-plus row key; all-``None`` axes change neither outputs nor dedup
+keys, bit-for-bit.
 
 Two-level recurrence (queueing-coupled directory)
 -------------------------------------------------
@@ -152,12 +148,11 @@ span on the arrival clock. Each epoch's waiting time (carried backlog
 + an M/D/1 in-epoch wait) is folded into every directory-transacting
 store's ``w`` side (:func:`_directory_delay_row`, host-side inside
 :func:`_make_cell_arrays` BEFORE the collapse) -- so the level-1
-collapse, every engine tier and both data planes again work
-unchanged. ``directory_load=None`` keeps outputs AND dedup
-keys bit-identical; active coupling appends the resolved
-:class:`~repro.core.directory.DirectoryParams` to the wv key, so cells
-sharing a (shard, epoch-profile) still dedup to one bank row / scan
-lane. :func:`_resolve_coupling` is the single resolution point shared
+collapse and the scan again work unchanged. ``directory_load=None``
+keeps outputs AND dedup keys bit-identical; active coupling appends the
+resolved :class:`~repro.core.directory.DirectoryParams` to the wv key,
+so cells sharing a (shard, epoch-profile) still dedup to one bank row /
+scan lane. :func:`_resolve_coupling` is the single resolution point shared
 by :func:`_prepare_cell` and :func:`_plane_keys`, so data and keys
 cannot drift.
 
@@ -225,7 +220,7 @@ class SimResult:
     #: Engine metadata (not part of the simulated physics): which engine
     #: produced the cell, the blocked-scan ``chunk`` actually used (the
     #: auto heuristic's pick when ``chunk_size=None``), tile/shard info
-    #: from the streaming tier. Excluded from equality comparisons.
+    #: from the streaming engine. Excluded from equality comparisons.
     meta: Optional[Dict[str, object]] = dataclasses.field(
         default=None, compare=False)
 
@@ -312,7 +307,7 @@ def synthesize_trace(wl: WorkloadProfile, n_stores: int, seed: int,
     * ``gaps``        -- inter-arrival gap to the previous store (ns, f32)
     * ``arrivals``    -- absolute arrival time ``cumsum(gaps)`` (ns, f32;
       a single host-side ``np.cumsum`` shared by the serial oracle and
-      both batched engines, so all three consume bit-identical inputs)
+      the engine's bank, so both consume bit-identical inputs)
     * ``coalesce``    -- store coalesces with the previous SB entry (bool)
     * ``in_burst``    -- store is inside a flush burst (bool)
     * ``burst_pos``   -- index distance into the current burst (f32)
@@ -417,11 +412,6 @@ _CELL_ARRAY_CACHE = _BoundedCache(maxsize=512)
 #: Per-trace result scalars (see :func:`_trace_scalars`): three numbers
 #: per ``(workload, seed, coalescing)``, 54 entries a mega-grid sweep.
 _TRACE_SCALAR_CACHE = _BoundedCache(maxsize=256)
-#: Whole-batch stacked device inputs (see :func:`_batch_inputs`). One
-#: entry holds five ``(n_stores, B)`` f32 arrays plus the host cells
-#: (~50 MB for the Fig. 10 grid at the default store count), so the
-#: bound stays small.
-_BATCH_INPUT_CACHE = _BoundedCache(maxsize=4)
 #: Precollapsed max-plus rows (see :func:`_wv_row`): one ``(w, v,
 #: pr_nc)`` triple per unique row key, ~9 bytes x n_stores each.
 _WV_ROW_CACHE = _BoundedCache(maxsize=1024)
@@ -429,9 +419,6 @@ _WV_ROW_CACHE = _BoundedCache(maxsize=1024)
 #: bank is a few hundred MB of host columns plus its device placements,
 #: so at most two stay alive.
 _BANK_CACHE = _BoundedCache(maxsize=2)
-#: Banked per-batch index vectors + cell scalars (the banked
-#: counterpart of :data:`_BATCH_INPUT_CACHE`; entries are tiny).
-_BANKED_INPUT_CACHE = _BoundedCache(maxsize=8)
 
 _CACHE_CLEARERS: List[Callable[[], None]] = []
 
@@ -446,25 +433,23 @@ def register_cache_clearer(fn: Callable[[], None]) -> Callable[[], None]:
 
 def clear_sim_caches() -> None:
     """Drop every host-side simulator memo: synthesized traces, reduced-
-    key cell arrays, stacked batch inputs, and any registered engine
-    caches (compiled tile programs, tile rings). Benchmarks call this
-    between engines so no engine's timing rides on caches another
-    engine warmed; long-lived processes can call it to release pinned
-    memory after a mega-grid sweep."""
+    key cell arrays, per-trace scalars, max-plus rows, banks, and any
+    registered engine caches (compiled tile programs). Benchmarks call
+    this between runs so no timing rides on caches an earlier run
+    warmed; long-lived processes can call it to release pinned memory
+    after a mega-grid sweep."""
     _trace_cached.cache_clear()
     _CELL_ARRAY_CACHE.clear()
     _TRACE_SCALAR_CACHE.clear()
-    _BATCH_INPUT_CACHE.clear()
     _WV_ROW_CACHE.clear()
     _BANK_CACHE.clear()       # drops host columns AND device placements
-    _BANKED_INPUT_CACHE.clear()
     clear_contention_caches()   # conflict draws + delay rows
     for fn in list(_CACHE_CLEARERS):
         fn()
 
 
 # ---------------------------------------------------------------------------
-# Per-cell cost derivation (shared by the serial and batched paths)
+# Per-cell cost derivation (shared by the serial oracle and the bank)
 # ---------------------------------------------------------------------------
 
 def _commit_cost_ns(config: str, cluster: ClusterConfig) -> Dict[str, float]:
@@ -497,7 +482,8 @@ class _CellScalars:
 
 @dataclasses.dataclass
 class _CellInputs(_CellScalars):
-    """Everything _timeline{,_batch} and result assembly need for one cell."""
+    """Everything the serial ``_timeline`` and result assembly need for
+    one cell."""
     config_idx: int
     # per-store timeline inputs, each (n_stores,)
     arrivals: np.ndarray
@@ -844,7 +830,7 @@ def _make_wv_row(wv_key: tuple, n_stores: int, cluster: ClusterConfig
 
 def _wv_row(wv_key: tuple, n_stores: int, cluster: ClusterConfig):
     """Memoized :func:`_make_wv_row` (rows recur across banks and across
-    engines sweeping the same grid)."""
+    sweeps of the same grid)."""
     return _WV_ROW_CACHE.get_or_put(
         (wv_key, n_stores, cluster),
         lambda: _make_wv_row(wv_key, n_stores, cluster))
@@ -858,8 +844,8 @@ class TraceBank:
     a device gather along axis 0 is then one row memcpy per cell (XLA
     lowers whole-row gathers to copies -- measured ~3x faster on CPU
     than a column gather out of a time-major bank), and the transpose
-    into the scan's time-major layout is a cheap local device op, as on
-    the stacked plane. ``arrivals[trace_row[k]]`` is the arrivals row
+    into the scan's time-major layout is a cheap local device op.
+    ``arrivals[trace_row[k]]`` is the arrivals row
     of trace key ``k``; ``w / v / pr_nc[wv_row[k]]`` the precollapsed
     max-plus row of row key ``k``. Host rows are built once per grid
     (memoized by :func:`get_trace_bank`) and placed on device at most
@@ -922,10 +908,10 @@ class TraceBank:
         ``place`` maps the host tuple onto devices (the streaming engine
         passes a replicating ``device_put`` over its ``cells`` mesh);
         the default commits to the default device. Placements are
-        memoized by ``key``, so a grid swept by several engines uploads
-        once. Returns ``(bytes_uploaded_now, arrays)`` --
+        memoized by ``key``, so a grid swept repeatedly uploads once.
+        Returns ``(bytes_uploaded_now, arrays)`` --
         ``bytes_uploaded_now`` is 0 on a placement-cache hit, which is
-        what the engines' ``h2d_bytes`` accounting reports.
+        what the engine's ``h2d_bytes`` accounting reports.
 
         After :meth:`extend` grew the bank, a resident placement is
         refreshed **incrementally**: only the appended row slices cross
@@ -1192,10 +1178,10 @@ def get_trace_bank(specs: Sequence[ScenarioSpec], n_stores: int,
                    cluster: ClusterConfig = PAPER_CLUSTER) -> TraceBank:
     """Build (or fetch) the memoized columnar bank of a grid.
 
-    Digest-keyed like :func:`_batch_inputs`, so ``simulate_batch`` and
-    the streaming engine running the same grid share ONE bank handle
-    (and therefore one device upload per placement) across engine
-    switches. :func:`clear_sim_caches` drops it."""
+    Digest-keyed (:func:`_specs_key`), so repeated sweeps of the same
+    grid -- and ``scenarios.grid_bank`` ahead of a sweep -- share ONE
+    bank handle, and therefore one device upload per placement.
+    :func:`clear_sim_caches` drops it."""
     with _tm.span("bank/get"):
         key = ("bank",) + _specs_key(tuple(specs), n_stores, cluster)
         return _BANK_CACHE.get_or_put(
@@ -1205,12 +1191,12 @@ def get_trace_bank(specs: Sequence[ScenarioSpec], n_stores: int,
 def _cell_scalars(spec: ScenarioSpec, n_stores: int,
                   cluster: ClusterConfig) -> _CellScalars:
     """Resolve a ScenarioSpec into the scalars of its result, without
-    the per-store arrays: the bank plane's consumers (the streaming
-    engine's banked tiles, ``simulate_batch``'s banked tier, the
-    serving daemon) scan precollapsed bank rows and read only these.
-    The per-trace part is memoized (:func:`_trace_scalars`); the rest
-    is a few float operations per cell. :func:`_prepare_cell` builds
-    its scalars here too, so both planes' results agree bit for bit.
+    the per-store arrays: the bank's consumers (the streaming engine's
+    tiles, the serving daemon) scan precollapsed bank rows and read
+    only these. The per-trace part is memoized (:func:`_trace_scalars`);
+    the rest is a few float operations per cell. :func:`_prepare_cell`
+    builds its scalars here too, so the engine's and the serial
+    oracle's results agree bit for bit.
     Contention and directory coupling change only the per-store
     arrays, never these scalars."""
     config = spec.config
@@ -1247,9 +1233,8 @@ def _prepare_cell(spec: ScenarioSpec, trace: Dict[str, np.ndarray],
     """Resolve a ScenarioSpec against a synthesized trace into the exact
     per-store arrays the timeline consumes, plus its result scalars
     (:func:`_cell_scalars`). Pure host-side numpy; used verbatim by
-    ``simulate``, the stacked plane of ``simulate_batch`` and of the
-    streaming engine, and the contention oracle (which validate the
-    specs up front) so the paths cannot drift. The heavy array work
+    ``simulate`` and the contention oracle (which validate the specs up
+    front) so the references cannot drift. The heavy array work
     lives in :func:`_cell_arrays` and is shared across every cell with
     the same reduced key."""
     config = spec.config
@@ -1309,7 +1294,7 @@ def _timeline(arrivals: jax.Array, coalesce: jax.Array, exposed: jax.Array,
     """Returns (exec_time_ns, repl_at_head_count, sb_full_count).
 
     ``arrivals``: absolute store arrival times (ns), precomputed on the
-    host so all engines share one bit-identical input.
+    host so the oracle and the engine share one bit-identical input.
     ``t_repl_i``: per-store REPL->ACK latency (congestion/N_r adjusted).
     ``svc_i``: per-store replica Logging-Unit service time -- the
     throughput floor of commit draining during cluster-wide bursts (every
@@ -1359,85 +1344,12 @@ def _timeline(arrivals: jax.Array, coalesce: jax.Array, exposed: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Store-buffer timeline -- batched (one lax.scan for the whole grid)
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("sb_max",))
-def _timeline_batch(arrivals: jax.Array, coalesce: jax.Array,
-                    exposed: jax.Array,
-                    t_repl_i: jax.Array, svc_i: jax.Array,
-                    config_idx: jax.Array, sb_size: jax.Array, sb_max: int,
-                    t_l1: float, t_wt: float
-                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Per-step batched timeline over time-major ``(n_stores, B)`` cell
-    arrays (the PR-1 engine; kept as the ``chunk_size=0`` differential
-    path and the speedup baseline for ``fig10/sweep/*`` bench rows).
-
-    All five commit rules are evaluated per step (they share the retire
-    recurrence and are each a couple of flops on a (B,)-vector) and the
-    per-cell rule is selected by ``config_idx`` -- cheaper and simpler
-    than a ``lax.switch`` which would lower to the same selects under
-    batching anyway. The SB ring is a circular (B, sb_max) buffer with a
-    per-cell read offset, so cells with different ``sb_size`` share one
-    scan: slot ``(i - sb) % sb_max`` was last written at step ``i - sb``
-    (or never, for i < sb, where it still holds the zero init), which is
-    exactly the serial oracle's ``c_{i-sb}``.
-
-    Returns per-cell (exec_time_ns, repl_at_head_count, sb_full_count).
-    """
-    n_b = arrivals.shape[1]
-    # loop-invariant per-cell config masks, hoisted out of the scan body
-    is_wt = config_idx == _CONFIG_IDX["wt"]
-    is_bl = config_idx == _CONFIG_IDX["baseline"]
-    is_pl = config_idx == _CONFIG_IDX["parallel"]
-    is_pr = config_idx == _CONFIG_IDX["proactive"]
-
-    def body(carry, inp):
-        ring, last_c, at_head, sb_full, i = carry
-        a_i, co_i, coh_i, tr_i, sv_i = inp            # each (B,)
-        read = (i - sb_size) % sb_max                  # (B,)
-        oldest = jnp.take_along_axis(ring, read[:, None], axis=1)[:, 0]
-        r_i = jnp.maximum(a_i, oldest)
-        sb_full = sb_full + (oldest > a_i).astype(jnp.int32)
-
-        serial = jnp.maximum(r_i, last_c)
-        c_wb = serial + t_l1
-        c_wt = serial + t_wt
-        c_bl = serial + jnp.where(co_i, t_l1, coh_i + tr_i)
-        c_pl = serial + jnp.where(co_i, t_l1, jnp.maximum(coh_i, tr_i))
-        c_pr_raw = jnp.maximum(jnp.maximum(r_i + tr_i, r_i + coh_i),
-                               last_c + sv_i)
-        c_pr = jnp.where(co_i, serial + t_l1, c_pr_raw)
-        c_i = jnp.where(is_pr, c_pr,
-                        jnp.where(is_pl, c_pl,
-                                  jnp.where(is_bl, c_bl,
-                                            jnp.where(is_wt, c_wt, c_wb))))
-
-        at_head = at_head + (is_pr & ~co_i
-                             & (r_i >= last_c)).astype(jnp.int32)
-        ring = ring.at[:, i % sb_max].set(c_i)
-        return (ring, c_i, at_head, sb_full, i + 1), None
-
-    init = (jnp.zeros((n_b, sb_max), jnp.float32),
-            jnp.zeros((n_b,), jnp.float32),
-            jnp.zeros((n_b,), jnp.int32),
-            jnp.zeros((n_b,), jnp.int32),
-            jnp.int32(0))
-    xs = (arrivals, coalesce, exposed, t_repl_i, svc_i)
-    (_, last_c, at_head, sb_full, _), _ = jax.lax.scan(body, init, xs)
-    return last_c, at_head, sb_full
-
-
-# ---------------------------------------------------------------------------
 # Store-buffer timeline -- blocked scan (chunk the store stream, scan over
-# chunk boundaries, vectorized intra-chunk precomputation)
+# chunk boundaries, max-plus inputs collapsed before the scan)
 # ---------------------------------------------------------------------------
 
-#: Hard ceiling on explicit chunk requests' sanity and the PR-2 era
-#: default block length (the auto heuristic now caps at
-#: :data:`AUTO_CHUNK_CAP`, which measures faster on every axis; the
-#: ``fig10/megagrid/pr2_blocked_s`` bench row still runs this value to
-#: keep the old path comparable).
+#: Deepest block the auto heuristic picks for narrow tiles (see
+#: :func:`auto_chunk`; wide tiles cap at :data:`AUTO_CHUNK_CAP`).
 DEFAULT_CHUNK_SIZE = 128
 
 
@@ -1447,7 +1359,9 @@ def _blocked_precompute(coalesce: jax.Array, exposed: jax.Array,
                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Collapse all five commit rules into one max-plus recurrence.
 
-    Every rule is exactly (bit-for-bit) of the form
+    The device-side reference of the host collapse :func:`_make_wv_row`
+    (tests/test_trace_bank.py pins the two ``==``). Every rule is
+    exactly (bit-for-bit) of the form
 
         c_i = max(r_i + w_i,  c_{i-1} + v_i)
 
@@ -1486,56 +1400,19 @@ def _blocked_precompute(coalesce: jax.Array, exposed: jax.Array,
     return w, v, pr_nc
 
 
-def _blocked_steps(carry, a_b, w_b, v_b, sb_size: jax.Array):
+def _blocked_steps_uniform(carry, a_b, w_b, v_b, p_b):
     """Advance the blocked timeline by one block of ``K`` stores.
 
-    ``carry`` = (hist (H, B) f32 -- the last H commit times, oldest
-    first, H = padded max SB depth; last (B,) f32 -- ``c_{i-1}``).
-    Block inputs are time-major ``(K, B)`` slices of the precomputed
-    arrays, with K <= min(sb_size): the SB depth bounds how far back a
-    retire can look, so every ``c_{i-sb}`` a block needs was committed
-    in a *previous* block and sits in ``hist``. That makes the SB-ring
-    reads for the whole block ONE vectorized gather (``hist[H - sb + k]``
-    is exactly the oracle's ``c_{i-sb}``, still the 0.0 init for
-    i < sb), leaves ``u = max(a, oldest) + w`` vectorized over the
-    block, and reduces the per-store sequential work to the irreducible
-    2-op max-plus core ``c = max(u_k, c + v_k)`` -- an unrolled chain of
-    contiguous (B,) row ops.
-
-    Returns the new carry and the per-block ``(c, oldest)`` matrices;
-    both censuses (SB-full, Fig. 11 REPL-at-head) are recovered
-    vectorized from them *outside* the scan.
-    """
-    hist, last = carry
-    k_len = a_b.shape[0]
-    h = hist.shape[0]
-    idx = (h - sb_size)[None, :] + jnp.arange(k_len)[:, None]      # (K, B)
-    oldest = jnp.take_along_axis(hist, idx, axis=0)                # (K, B)
-    u = jnp.maximum(a_b, oldest) + w_b
-
-    cs = []
-    for k in range(k_len):
-        last = jnp.maximum(u[k], last + v_b[k])
-        cs.append(last)
-    c = jnp.stack(cs, axis=0)                                      # (K, B)
-    hist = c if k_len == h else jnp.concatenate([hist[k_len:], c], axis=0)
-    return (hist, last), (c, oldest)
-
-
-def _blocked_steps_uniform(carry, a_b, w_b, v_b, p_b):
-    """Uniform-SB fast path for one block of ``K`` stores.
-
-    When every cell shares one store-buffer depth ``sb`` (the common
-    case -- Table II fixes SB = 72 unless the sweep varies it), the
-    commit history is carried as a *tuple* of ``sb`` ``(B,)`` arrays
-    (oldest first), so the SB-ring read for store ``k`` is the plain
-    Python indexing ``hist[k]`` (``c_{i-sb}`` exactly, K <= sb) and the
-    history shift is static tuple slicing -- no gather, no stacked
-    commit matrix, no materialized per-store timeline. Both censuses
-    accumulate in-scan (integer adds, order-exact). The per-store work
-    is ~7 tiny fusible ``(B,)`` ops; applies the same arithmetic as
-    :func:`_blocked_steps` element-for-element, so results stay
-    bit-identical across paths.
+    Every lane shares one store-buffer depth ``sb`` (the tile planner
+    groups lanes by SB), so the commit history is carried as a *tuple*
+    of ``sb`` ``(B,)`` arrays (oldest first): the SB-ring read for
+    store ``k`` is the plain Python indexing ``hist[k]`` (``c_{i-sb}``
+    exactly, K <= sb) and the history shift is static tuple slicing --
+    no gather, no stacked commit matrix, no materialized per-store
+    timeline. Both censuses accumulate in-scan (integer adds,
+    order-exact). The per-store work is ~7 tiny fusible ``(B,)`` ops,
+    the serial oracle's arithmetic on the collapsed rows, so results
+    stay bit-identical to it.
 
     ``carry`` = (hist tuple, last (B,), at_head (B,) i32, sb_full (B,)
     i32); block inputs are time-major ``(K, B)`` slices.
@@ -1553,56 +1430,23 @@ def _blocked_steps_uniform(carry, a_b, w_b, v_b, p_b):
     return (hist[k_len:] + tuple(cs), last, at_head, sb_full)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("sb_max", "chunk", "sb_uniform"))
-def _timeline_batch_blocked(arrivals: jax.Array, coalesce: jax.Array,
-                            exposed: jax.Array, t_repl_i: jax.Array,
-                            svc_i: jax.Array, config_idx: jax.Array,
-                            sb_size: jax.Array, sb_max: int, chunk: int,
-                            sb_uniform: Optional[int],
-                            t_l1: float, t_wt: float
-                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Blocked batched timeline: ``lax.scan`` over chunk boundaries only.
-
-    Same inputs/outputs as ``_timeline_batch`` plus two statics:
-    ``chunk`` (stores per block; the caller clamps it to
-    ``min(sb_size)``) and ``sb_uniform`` (the shared SB depth when every
-    cell has the same one, else None). ``n_stores // chunk`` full blocks
-    run inside one scan -- in the time-major layout the blocking
-    reshape is free -- and the ragged tail (``n_stores % chunk``
-    stores) is processed once after the scan with the same step
-    function, so results are exact for every chunk size.
-
-    With ``sb_uniform`` set, the tuple-history fast path
-    (:func:`_blocked_steps_uniform`) runs with censuses accumulated
-    in-scan. The general path (:func:`_blocked_steps`, per-cell SB
-    depths) emits the full commit / SB-read timelines and computes both
-    censuses vectorized over the whole ``(n_stores, B)`` arrays
-    afterwards. Both are bit-identical to the per-step engine and the
-    serial oracle by construction (see module docstring).
-
-    Returns per-cell (exec_time_ns, repl_at_head_count, sb_full_count).
-    """
-    w, v, pr_nc = _blocked_precompute(
-        coalesce, exposed, t_repl_i, svc_i, config_idx, t_l1, t_wt)
-    return _scan_wv(arrivals, w, v, pr_nc, sb_size, sb_max, chunk,
-                    sb_uniform)
-
-
 def _scan_wv(arrivals: jax.Array, w: jax.Array, v: jax.Array,
-             pr_nc: jax.Array, sb_size: Optional[jax.Array], sb_max: int,
-             chunk: int, sb_uniform: Optional[int]
+             pr_nc: jax.Array, chunk: int, sb_uniform: int
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The blocked scan proper, over already-collapsed max-plus inputs.
+    """The blocked scan over collapsed max-plus inputs.
 
     Inputs are time-major ``(n_stores, B)``: ``arrivals`` plus the
-    ``(w, v, pr_nc)`` of :func:`_blocked_precompute` -- whether those
-    came from the in-jit precompute (stacked plane) or from a bank
-    gather of host-precollapsed columns (banked plane), the arithmetic
-    from here on is identical, so both planes are bit-identical.
-    ``sb_size`` is only read on the general (mixed-SB) path and may be
-    ``None`` when ``sb_uniform`` is set. Must be called inside jit
-    (shapes/statics as in :func:`_timeline_batch_blocked`).
+    ``(w, v, pr_nc)`` max-plus rows, gathered from the bank
+    (:func:`_bank_gather`). Every lane shares the store-buffer depth
+    ``sb_uniform`` (the tile planner groups lanes by SB), and ``chunk``
+    (stores per block) is at most that depth. ``n_stores // chunk``
+    full blocks run inside one ``lax.scan`` -- in the time-major layout
+    the blocking reshape is free -- and the ragged tail (``n_stores %
+    chunk`` stores) is processed once after the scan with the same step
+    function (:func:`_blocked_steps_uniform`), so results are exact for
+    every chunk size. Must be called inside jit.
+
+    Returns per-lane (exec_time_ns, repl_at_head_count, sb_full_count).
     """
     n, n_b = arrivals.shape
     n_main = (n // chunk) * chunk
@@ -1613,54 +1457,23 @@ def _scan_wv(arrivals: jax.Array, w: jax.Array, v: jax.Array,
         # (n_blocks, chunk, B)
         return x[:n_main].reshape(-1, chunk, n_b)
 
-    if sb_uniform is not None:
-        carry = (tuple(jnp.zeros((n_b,), jnp.float32)
-                       for _ in range(sb_uniform)),
-                 jnp.zeros((n_b,), jnp.float32),
-                 jnp.zeros((n_b,), jnp.int32),
-                 jnp.zeros((n_b,), jnp.int32))
-        if n_main:
-            xs = tuple(to_blocks(x) for x in (arrivals, w, v, pr_nc))
-
-            def body(c, blk):
-                return _blocked_steps_uniform(c, *blk), None
-
-            carry, _ = jax.lax.scan(body, carry, xs)
-        if rem:
-            tail = tuple(x[n_main:] for x in (arrivals, w, v, pr_nc))
-            carry = _blocked_steps_uniform(carry, *tail)
-        _, last_c, at_head, sb_full = carry
-        return last_c, at_head, sb_full
-
-    carry = (jnp.zeros((sb_max, n_b), jnp.float32),
-             jnp.zeros((n_b,), jnp.float32))
-    parts_c, parts_old = [], []
+    carry = (tuple(jnp.zeros((n_b,), jnp.float32)
+                   for _ in range(sb_uniform)),
+             jnp.zeros((n_b,), jnp.float32),
+             jnp.zeros((n_b,), jnp.int32),
+             jnp.zeros((n_b,), jnp.int32))
     if n_main:
-        xs = tuple(to_blocks(x) for x in (arrivals, w, v))
+        xs = tuple(to_blocks(x) for x in (arrivals, w, v, pr_nc))
 
         def body(c, blk):
-            return _blocked_steps(c, *blk, sb_size=sb_size)
+            return _blocked_steps_uniform(c, *blk), None
 
-        carry, (c_blks, old_blks) = jax.lax.scan(body, carry, xs)
-        parts_c.append(c_blks.reshape(n_main, n_b))
-        parts_old.append(old_blks.reshape(n_main, n_b))
+        carry, _ = jax.lax.scan(body, carry, xs)
     if rem:
-        tail = tuple(x[n_main:] for x in (arrivals, w, v))
-        carry, (c_tail, old_tail) = _blocked_steps(carry, *tail,
-                                                   sb_size=sb_size)
-        parts_c.append(c_tail)
-        parts_old.append(old_tail)
-    c = parts_c[0] if len(parts_c) == 1 else jnp.concatenate(parts_c, axis=0)
-    oldest = parts_old[0] if len(parts_old) == 1 \
-        else jnp.concatenate(parts_old, axis=0)
-
-    # post-hoc vectorized censuses (identical f32 ops, so identical bits)
-    r = jnp.maximum(arrivals, oldest)
-    sb_full = jnp.sum(oldest > arrivals, axis=0, dtype=jnp.int32)
-    prev = jnp.concatenate([jnp.zeros((1, n_b), jnp.float32), c[:-1]],
-                           axis=0)
-    at_head = jnp.sum(pr_nc & (r >= prev), axis=0, dtype=jnp.int32)
-    return c[-1], at_head, sb_full
+        tail = tuple(x[n_main:] for x in (arrivals, w, v, pr_nc))
+        carry = _blocked_steps_uniform(carry, *tail)
+    _, last_c, at_head, sb_full = carry
+    return last_c, at_head, sb_full
 
 
 def _bank_gather(a_bank: jax.Array, w_bank: jax.Array, v_bank: jax.Array,
@@ -1668,35 +1481,14 @@ def _bank_gather(a_bank: jax.Array, w_bank: jax.Array, v_bank: jax.Array,
                  ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """In-jit bank gather into the scan's time-major layout.
 
-    One row memcpy per cell (whole-row gathers lower to copies) plus
-    the same cheap device transpose the stacked streaming plane uses.
-    The SINGLE definition of how bank rows become scan inputs -- both
-    the one-shot banked timeline below and the streaming engine's tile
-    programs call it, so the two banked planes cannot drift. Must be
-    called inside jit."""
+    One row memcpy per lane (whole-row gathers lower to copies) plus a
+    cheap device transpose to time-major. The engine's tile program
+    (``engine._build_bank_tile_fn``) calls it ahead of
+    :func:`_scan_wv`. Must be called inside jit."""
     return (jnp.take(a_bank, trace_idx, axis=0).T,
             jnp.take(w_bank, wv_idx, axis=0).T,
             jnp.take(v_bank, wv_idx, axis=0).T,
             jnp.take(p_bank, wv_idx, axis=0).T)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("sb_max", "chunk", "sb_uniform"))
-def _timeline_banked(a_bank: jax.Array, w_bank: jax.Array, v_bank: jax.Array,
-                     p_bank: jax.Array, trace_idx: jax.Array,
-                     wv_idx: jax.Array, sb_size: jax.Array, sb_max: int,
-                     chunk: int, sb_uniform: Optional[int]
-                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Blocked timeline over the columnar bank: in-jit gather + scan.
-
-    ``*_bank`` are the store-contiguous :class:`TraceBank` rows; the
-    two ``int32`` index vectors select each cell's rows (no stacked
-    host copies, no H2D of per-cell arrays). Gathering moves identical
-    bits, so results match the stacked plane ``==``.
-    """
-    a, w, v, p = _bank_gather(a_bank, w_bank, v_bank, p_bank,
-                              trace_idx, wv_idx)
-    return _scan_wv(a, w, v, p, sb_size, sb_max, chunk, sb_uniform)
 
 
 # ---------------------------------------------------------------------------
@@ -1724,9 +1516,9 @@ def simulate(workload: str, config: str,
     ``conflict_rate`` / ``consistency_schedule`` -- see
     ``repro.core.contention``) and the directory-coupling axis
     (``directory_load`` -- see ``repro.core.directory``). This is the
-    serial oracle the batched engines are differentially tested
-    against; returns a :class:`SimResult` (times in ns, log sizes in
-    bytes, bandwidths in GB/s).
+    serial oracle the engine is differentially tested against; returns
+    a :class:`SimResult` (times in ns, log sizes in bytes, bandwidths in
+    GB/s).
     """
     spec = ScenarioSpec(workload, config, seed=seed, n_replicas=n_replicas,
                         link_bw_gbps=link_bw_gbps, n_cns=n_cns,
@@ -1756,7 +1548,7 @@ def simulate_spec(spec: ScenarioSpec,
 
     The single place that maps EVERY spec knob -- including the
     contention axes -- onto :func:`simulate`'s keyword surface, so
-    differential callers (the engine's ``serial`` tier, benchmark
+    differential callers (``run_sweep(engine="serial")``, benchmark
     oracle checks) cannot silently drop a new axis."""
     return simulate(spec.workload, spec.config, cluster=cluster,
                     n_stores=n_stores, seed=spec.seed,
@@ -1769,133 +1561,14 @@ def simulate_spec(spec: ScenarioSpec,
                     directory_load=spec.directory_load)
 
 
-def _pad_len(n: int, mult: int = 8) -> int:
-    return max(((n + mult - 1) // mult) * mult, mult)
-
-
-def _stack_cells(cells: List[_CellInputs]):
-    """Stack prepared cells into time-major batch arrays (host numpy).
-
-    The batch is padded to the next multiple of 8 cells by repeating
-    cell 0, and SB rings to the widest cell (multiple of 8). Per-store
-    arrays are stacked time-major ``(n_stores, B)``: the natural layout
-    for both one-shot scans (xs slices and block reshapes are
-    contiguous). The streaming engine does NOT use this -- its tiles
-    stack cell-major (``engine._stack_tile``) and transpose on device.
-
-    Returns ``(args, sb_max, sb_min, sb_uniform)`` where ``args`` is
-    the 7-tuple the batched timelines consume.
-    """
-    n_pad = _pad_len(len(cells))
-    padded = cells + [cells[0]] * (n_pad - len(cells))
-    sb_max = _pad_len(max(c.sb_size for c in padded))
-    args = (
-        np.stack([c.arrivals for c in padded], axis=1),
-        np.stack([c.coalesce for c in padded], axis=1),
-        np.stack([c.exposed for c in padded], axis=1),
-        np.stack([c.t_repl_i for c in padded], axis=1),
-        np.stack([c.svc_i for c in padded], axis=1),
-        np.asarray([c.config_idx for c in padded], np.int32),
-        np.asarray([c.sb_size for c in padded], np.int32),
-    )
-    sb_min = min(c.sb_size for c in padded)
-    sb_uniform = sb_min if sb_min == max(c.sb_size for c in padded) else None
-    return args, sb_max, sb_min, sb_uniform
-
-
-def _make_batch_inputs(specs: Tuple[ScenarioSpec, ...], n_stores: int,
-                       cluster: ClusterConfig):
-    cells = [_prepare_cell(s, _trace_cached(s.workload, n_stores, s.seed,
-                                            cluster), n_stores, cluster)
-             for s in specs]
-    np_args, sb_max, sb_min, sb_uniform = _stack_cells(cells)
-    args = tuple(jnp.asarray(a) for a in np_args)
-    return cells, args, sb_max, sb_min, sb_uniform
-
-
-def _batch_inputs(specs: Tuple[ScenarioSpec, ...], n_stores: int,
-                  cluster: ClusterConfig):
-    """Memoized host-side prep for one batch: synthesizes/derives every
-    cell and stacks the padded device arrays. Sweeps that re-run the
-    same grid (benchmarks, repeated scenario evaluation) skip straight
-    to the timeline.
-
-    The memo is digest-keyed (:func:`_specs_key`) and size-bounded
-    (:data:`_BATCH_INPUT_CACHE`): a mega-grid's 10^4-spec tuple never
-    becomes a dictionary key, and at most ``maxsize`` batches' device
-    arrays stay pinned. :func:`clear_sim_caches` drops it."""
-    key = _specs_key(specs, n_stores, cluster)
-    return _BATCH_INPUT_CACHE.get_or_put(
-        key, lambda: _make_batch_inputs(specs, n_stores, cluster))
-
-
 def _specs_key(specs: Sequence[ScenarioSpec], n_stores: int,
                cluster: ClusterConfig) -> Tuple[int, int, str]:
-    """Constant-size digest key for a (specs, n_stores, cluster) batch."""
+    """Constant-size digest key for a (specs, n_stores, cluster) grid."""
     h = hashlib.blake2b(digest_size=16)
     h.update(repr((n_stores, cluster)).encode())
     for s in specs:
         h.update(repr(s).encode())
     return (len(specs), n_stores, h.hexdigest())
-
-
-_batch_inputs.cache_clear = _BATCH_INPUT_CACHE.clear   # lru_cache-compat
-
-
-def _make_banked_inputs(specs: Tuple[ScenarioSpec, ...], n_stores: int,
-                        cluster: ClusterConfig):
-    # the bank handle is deliberately NOT part of the returned (cached)
-    # tuple: row indices are deterministic (first-seen order over the
-    # same specs), so callers re-resolve the bank through
-    # get_trace_bank and _BANK_CACHE's small bound stays the ONLY thing
-    # keeping multi-hundred-MB banks alive
-    bank = get_trace_bank(specs, n_stores, cluster)
-    cells = [_cell_scalars(s, n_stores, cluster) for s in specs]
-    # scan-lane dedup (same reduction as the streaming engine's): a
-    # timeline consumes only (arrivals row, max-plus row, SB depth), so
-    # cells sharing that triple are ONE lane -- gathered and scanned
-    # once, with the lane outputs scattered back to member cells by
-    # ``cell_lane``. The one-shot tier no longer gathers (and pads) the
-    # full (n_stores, B) batch on device when the grid repeats lanes
-    # (e.g. the whole CN axis of a sweep): device gather width, scan
-    # width and the shipped index bytes all shrink to unique lanes.
-    lane_of: Dict[tuple, int] = {}
-    lane_rows: List[Tuple[int, int]] = []
-    lane_sb: List[int] = []
-    cell_lane: List[int] = []
-    for c in cells:
-        tr, wv = bank.rows_for(c.spec)
-        key = (c.sb_size, tr, wv)
-        j = lane_of.setdefault(key, len(lane_rows))
-        if j == len(lane_rows):
-            lane_rows.append((tr, wv))
-            lane_sb.append(c.sb_size)
-        cell_lane.append(j)
-    n_lanes = len(lane_rows)
-    pad = _pad_len(n_lanes) - n_lanes
-    trace_idx = np.asarray([r[0] for r in lane_rows]
-                           + [lane_rows[0][0]] * pad, np.int32)
-    wv_idx = np.asarray([r[1] for r in lane_rows]
-                        + [lane_rows[0][1]] * pad, np.int32)
-    sb_list = lane_sb + [lane_sb[0]] * pad
-    sb_arr = np.asarray(sb_list, np.int32)
-    sb_max = _pad_len(max(sb_list))
-    sb_min = min(sb_list)
-    sb_uniform = sb_min if sb_min == max(sb_list) else None
-    return (cells, np.asarray(cell_lane, np.int64), n_lanes, trace_idx,
-            wv_idx, sb_arr, sb_max, sb_min, sb_uniform)
-
-
-def _banked_inputs(specs: Tuple[ScenarioSpec, ...], n_stores: int,
-                   cluster: ClusterConfig):
-    """Memoized banked host prep for one batch: the padded ``int32``
-    lane-index vectors, the cell->lane scatter map, plus each cell's
-    :class:`_CellScalars` (the banked counterpart of :func:`_batch_inputs` -- entries are a
-    few KB instead of stacked array copies, and hold NO reference to
-    the bank itself)."""
-    key = _specs_key(specs, n_stores, cluster)
-    return _BANKED_INPUT_CACHE.get_or_put(
-        key, lambda: _make_banked_inputs(specs, n_stores, cluster))
 
 
 #: Cap for the auto-chunk heuristic on *wide* batches. The per-block
@@ -1917,14 +1590,14 @@ def auto_chunk(n_stores: int, sb_min: int,
     """Blocked-scan chunk heuristic (used when ``chunk_size=None``).
 
     The SB depth bounds how far back the retire recurrence can look
-    (``c_{i-sb}``), so a block may never exceed the narrowest SB in the
-    batch. Beyond that, two measured regimes (CPU):
+    (``c_{i-sb}``), so a block may never exceed the tile's SB depth.
+    Beyond that, two measured regimes (CPU):
 
-    * **narrow** batches (``n_cells`` < :data:`AUTO_CHUNK_WIDE_CELLS`,
-      e.g. the 45-cell Fig. 10 grid): ``lax.scan`` step overhead
-      dominates the tiny per-store row ops, so the deepest legal block
-      wins -- ``min(sb, n_stores, DEFAULT_CHUNK_SIZE)``;
-    * **wide** batches (mega-grid tiles, one-shot mega-batches, or
+    * **narrow** tiles (``n_cells`` < :data:`AUTO_CHUNK_WIDE_CELLS`):
+      ``lax.scan`` step overhead dominates the tiny per-store row ops,
+      so the deepest legal block wins -- ``min(sb, n_stores,
+      DEFAULT_CHUNK_SIZE)``;
+    * **wide** tiles (``n_cells`` at or past the threshold, or
       ``n_cells=None``): the unrolled block body and its carried
       history dominate, so the cap is :data:`AUTO_CHUNK_CAP` -- and a
       chunk that divides ``n_stores`` exactly is preferred, because a
@@ -1943,117 +1616,10 @@ def auto_chunk(n_stores: int, sb_min: int,
     return max(1, cap)
 
 
-def simulate_batch(specs: Sequence[ScenarioSpec],
-                   cluster: ClusterConfig = PAPER_CLUSTER,
-                   n_stores: int = 50_000,
-                   chunk_size: Optional[int] = None,
-                   data_plane: Optional[str] = None) -> List[SimResult]:
-    """Simulate a whole scenario grid in one jitted call.
-
-    Results come back in ``specs`` order (one :class:`SimResult` per
-    spec; times in ns, log sizes in bytes, bandwidths in GB/s). Unique
-    ``(workload, seed)`` traces are synthesized once and shared across
-    every cell that scans them; the batch is padded to a multiple of 8
-    cells (and SB rings to the widest cell, rounded to a multiple of 8)
-    so sweeps of similar size reuse one compiled program.
-
-    ``chunk_size`` selects the engine: ``None`` (default) runs the
-    blocked scan with the :func:`auto_chunk` heuristic deriving the
-    block from the narrowest ``sb_size`` in the batch; an explicit
-    ``>= 1`` value requests that many stores per block (still clamped
-    to ``n_stores`` and the narrowest SB, since a block may not look
-    back past the carried commit history); ``0`` runs the PR-1 per-step
-    scan. ``data_plane`` selects how per-store inputs reach the device:
-    ``"bank"`` (the blocked default) ships the deduplicated columnar
-    :class:`TraceBank` plus ``int32`` row indices, gathers in-jit, and
-    -- like the streaming tier -- scans only unique **lanes** (cells
-    sharing ``(SB, trace row, max-plus row)`` have bit-identical
-    timelines, so their outputs are scattered from one scanned lane;
-    ``meta["scan_lanes"]`` reports the count); ``"stacked"`` ships one
-    full array copy per cell (the pre-bank plane, kept as the
-    comparison baseline -- and the only plane of the per-step engine). All engines and planes are bit-identical to each
-    other and to the serial :func:`simulate` oracle; the blocked one is
-    several times faster on CPU (see ``fig10/sweep/*`` bench rows).
-    The engine, chunk and data plane actually used are reported in
-    ``SimResult.meta`` (plus ``bank_rows`` / ``h2d_bytes`` -- the
-    plane's cold per-call H2D footprint). Grids much larger than a few
-    thousand cells should go through the streaming tier
-    (``repro.core.engine.simulate_grid``) instead.
-    """
-    if not specs:
-        return []
-    if chunk_size is not None and chunk_size < 0:
-        raise ValueError(f"chunk_size must be >= 0, got {chunk_size}")
-    if data_plane not in (None, "bank", "stacked"):
-        raise ValueError(f"unknown data_plane {data_plane!r}")
-    if data_plane == "bank" and chunk_size is not None and chunk_size == 0:
-        raise ValueError("the per-step engine has no banked plane")
-    for s in specs:
-        s.validate(cluster)
-
-    costs = _commit_cost_ns("proactive", cluster)   # t_l1/t_wt are shared
-    cell_lane = None
-    if chunk_size is None or chunk_size:
-        plane = data_plane or "bank"
-        if plane == "bank":
-            (cells, cell_lane, n_lanes, trace_idx, wv_idx, sb_arr, sb_max,
-             sb_min, sb_uniform) = _banked_inputs(tuple(specs), n_stores,
-                                                  cluster)
-            bank = get_trace_bank(specs, n_stores, cluster)
-            idx_bytes = trace_idx.nbytes + wv_idx.nbytes + sb_arr.nbytes
-            batch_width = len(trace_idx)        # padded unique lanes
-        else:
-            cells, args, sb_max, sb_min, sb_uniform = _batch_inputs(
-                tuple(specs), n_stores, cluster)
-            batch_width = _pad_len(len(specs))
-        # a block may not reach past the carried history: the SB depth
-        # bounds the lookback (c_{i-sb}), so clamp to the narrowest cell
-        chunk = auto_chunk(n_stores, sb_min, batch_width) \
-            if chunk_size is None else min(chunk_size, n_stores, sb_min)
-        meta = {"engine": "blocked", "chunk": chunk,
-                "auto_chunk": chunk_size is None, "data_plane": plane,
-                "bank_partition": None}   # one device: nothing to shard
-        if plane == "bank":
-            meta["bank_rows"] = bank.n_rows
-            meta["scan_lanes"] = n_lanes
-            meta["h2d_bytes"] = bank.nbytes + idx_bytes
-            _, bank_dev = bank.device_args()
-            exec_ns, at_head, sb_full = _timeline_banked(
-                *bank_dev, jnp.asarray(trace_idx), jnp.asarray(wv_idx),
-                jnp.asarray(sb_arr), sb_max, chunk, sb_uniform)
-        else:
-            meta["h2d_bytes"] = sum(int(a.nbytes) for a in args)
-            exec_ns, at_head, sb_full = _timeline_batch_blocked(
-                *args, sb_max, chunk, sb_uniform, costs["t_l1"],
-                costs["t_wt"])
-    else:
-        cells, args, sb_max, sb_min, sb_uniform = _batch_inputs(
-            tuple(specs), n_stores, cluster)
-        meta = {"engine": "perstep", "chunk": 0, "auto_chunk": False,
-                "data_plane": "stacked", "bank_partition": None,
-                "h2d_bytes": sum(int(a.nbytes) for a in args)}
-        exec_ns, at_head, sb_full = _timeline_batch(
-            *args, sb_max, costs["t_l1"], costs["t_wt"])
-    exec_ns = np.asarray(exec_ns)
-    at_head = np.asarray(at_head)
-    sb_full = np.asarray(sb_full)
-    if cell_lane is not None:
-        # scatter each deduplicated lane's outputs to its member cells
-        exec_ns = exec_ns[cell_lane]
-        at_head = at_head[cell_lane]
-        sb_full = sb_full[cell_lane]
-
-    # fresh meta per result: SimResult is frozen but a shared dict would
-    # alias annotations across the whole batch
-    return [_finish_result(c, exec_ns[i], int(at_head[i]), int(sb_full[i]),
-                           meta=dict(meta))
-            for i, c in enumerate(cells)]
-
-
 def slowdowns_from_results(results: Sequence[SimResult],
                            baseline: str = "wb"
                            ) -> Dict[str, Dict[str, float]]:
-    """Group batched SimResults into a per-workload slowdown table
+    """Group a sweep's SimResults into a per-workload slowdown table
     normalized to ``baseline`` (one ``baseline`` cell per workload must
     be present; cells are keyed by (workload, config), so pass results
     from a grid that does not repeat a cell with different knobs)."""
@@ -2076,15 +1642,17 @@ def slowdown_table(configs: Tuple[str, ...] = CONFIGS,
     """Fig. 2 / Fig. 10: per-workload slowdowns normalized to WB.
 
     ``batched=True`` (default) runs the whole grid as ONE
-    ``simulate_batch`` call; ``batched=False`` keeps the serial per-cell
-    oracle loop for differential testing. ``kw`` takes any ScenarioSpec
-    knob (seed, n_replicas, link_bw_gbps, n_cns, sb_size, coalescing).
+    ``scenarios.run_sweep`` call; ``batched=False`` keeps the serial
+    per-cell oracle loop for differential testing. ``kw`` takes any
+    ScenarioSpec knob (seed, n_replicas, link_bw_gbps, n_cns, sb_size,
+    coalescing).
     """
     workloads = workloads or tuple(WORKLOADS)
     cfgs = tuple(dict.fromkeys(("wb",) + tuple(configs)))
     if batched:
+        from repro.core.scenarios import run_sweep
         specs = [ScenarioSpec(w, c, **kw) for w in workloads for c in cfgs]
-        results = simulate_batch(specs, cluster=cluster, n_stores=n_stores)
+        results = run_sweep(specs, cluster=cluster, n_stores=n_stores)
         table = slowdowns_from_results(results)
         return {w: {c: table[w][c] for c in configs} for w in workloads}
     out: Dict[str, Dict[str, float]] = {}

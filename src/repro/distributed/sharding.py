@@ -308,39 +308,15 @@ def cache_specs(cache: Any, cfg: ModelConfig,
 # Protocol-simulator tile sharding (cells axis of the streaming engine)
 # ---------------------------------------------------------------------------
 
-#: PartitionSpecs for one simulator tile, matching the engine's tile
-#: layout: five cell-major ``(B, n_stores)`` per-store arrays (stacked
-#: row-contiguous on the host -- a plain memcpy per cell -- and
-#: transposed to the scan's time-major layout on device, where the
-#: transpose is a fast local reshuffle), then the per-cell
-#: ``config_idx`` / ``sb_size`` vectors. Only the cell axis is sharded;
-#: the store axis stays local, so the blocked scan runs communication-
-#: free on every device.
-TILE_CELL_MAJOR_SPEC = P("cells", None)
-TILE_PER_CELL_SPEC = P("cells")
-
-
-def tile_specs() -> Tuple[P, ...]:
-    """In/out PartitionSpecs for the 7 tile input arrays (spec order =
-    the engine's ``_stack_tile`` order)."""
-    return (TILE_CELL_MAJOR_SPEC,) * 5 + (TILE_PER_CELL_SPEC,) * 2
-
-
-def tile_shardings(mesh: jax.sharding.Mesh) -> Tuple[NamedSharding, ...]:
-    """NamedShardings for ``jax.device_put`` of one tile's input arrays
-    onto a :func:`repro.distributed.context.cells_mesh` -- placing tiles
-    explicitly (instead of letting jit reshard) lets the streaming loop
-    overlap the host->device copy of tile k+1 with tile k's compute."""
-    return tuple(NamedSharding(mesh, s) for s in tile_specs())
-
-
-#: Banked data plane (the default): the four store-contiguous
-#: ``(rows, n_stores)`` trace-bank arrays are REPLICATED across the
-#: ``cells`` mesh -- any shard's cells may gather any row, and a
-#: replicated bank keeps the in-kernel gather local (sharding the row
-#: axis would force collectives and break the engine's
-#: zero-communication contract). The per-cell ``int32`` row-index
-#: vectors are the only sharded tile inputs.
+#: Replicated bank (``bank_partition="replicated"``, the degraded-mesh
+#: recovery's layout): the four store-contiguous ``(rows, n_stores)``
+#: trace-bank arrays are REPLICATED across the ``cells`` mesh -- any
+#: shard's cells may gather any row, and a replicated bank keeps the
+#: in-kernel gather local (sharding the row axis would force
+#: collectives and break the engine's zero-communication contract).
+#: The per-lane ``int32`` row-index vectors are the only cell-sharded
+#: tile inputs, placed explicitly (``index_shardings``) so the
+#: streaming loop overlaps tile k+1's upload with tile k's compute.
 BANK_COLUMN_SPEC = P(None, None)
 TILE_INDEX_SPEC = P("cells")
 

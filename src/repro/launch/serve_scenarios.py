@@ -13,8 +13,8 @@ novel cells (diff-upload misses), a grid-delta request and a couple of
 downtime queries -- the daemon's three query shapes -- and prints
 p50/p99 latency, throughput, cache-hit ratio and the marginal
 host->device bytes per query. ``--check`` re-runs every served cell
-through the cold ``simulate_grid`` oracle and asserts bit-identity
-(the same pin tests/test_serving.py holds under hypothesis).
+through the serial ``simulate_spec`` oracle and asserts bit-identity
+(the same pin tests/test_serving.py holds).
 """
 
 import argparse
@@ -90,8 +90,9 @@ def main() -> None:
 
     from repro.core import chaos
     from repro.core import telemetry
-    from repro.core.engine import simulate_grid, trace_count
+    from repro.core.engine import trace_count
     from repro.core.serving import ScenarioServer
+    from repro.core.simulator import simulate_spec
 
     if args.trace_out:
         telemetry.enable()
@@ -165,10 +166,10 @@ def main() -> None:
 
         if args.check:
             served = srv.query_batch(stream)
-            oracle = simulate_grid(stream, n_stores=args.stores,
-                                   engine="blocked")
-            for a, b in zip(served, oracle):
-                assert a == b, (a.meta, a, b)
+            oracle = {s: simulate_spec(s, n_stores=args.stores)
+                      for s in dict.fromkeys(stream)}
+            for s, a in zip(stream, served):
+                assert a == oracle[s], (a.meta, a, oracle[s])
             print(f"oracle check: {len(stream)} answers bit-identical")
 
         if args.trace_out:

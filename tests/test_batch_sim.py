@@ -1,25 +1,27 @@
-"""Differential tests: ``simulate_batch`` vs the serial ``simulate()``
-oracle, over a grid spanning every config and every sensitivity knob.
+"""Differential tests: the sweep engine (``run_sweep`` -> ``run_grid``)
+vs the serial ``simulate()`` oracle, over a grid spanning every config
+and every sensitivity knob.
 
-The contract (simulator.py module docstring): both batched engines --
-the blocked scan (default; uniform-SB fast path and general mixed-SB
-path) and the PR-1 per-step scan (``chunk_size=0``) -- share trace
-synthesis + cost derivation with the serial oracle and apply identical
-f32 arithmetic, so all paths agree **bit-for-bit**, for every chunk
-size including ragged tails. The exactness tests below assert ``==``;
-the older grid tests keep the (looser) documented 1e-5 band.
+The contract (simulator.py module docstring): the blocked scan shares
+trace synthesis + cost derivation with the serial oracle and applies
+identical f32 arithmetic, so the two agree **bit-for-bit**, for every
+chunk size including ragged tails and for grids that mix store-buffer
+depths (the tile planner gives each depth its own SB-uniform tiles).
+The exactness tests below assert ``==``; the older grid tests keep the
+(looser) documented 1e-5 band.
 """
 
 import numpy as np
 import pytest
 
+from repro.core.engine import run_grid
+from repro.core.scenarios import run_sweep
 from repro.core.simulator import (
     CONFIGS,
     DEFAULT_CHUNK_SIZE,
     ScenarioSpec,
     geomean_slowdowns,
     simulate,
-    simulate_batch,
     slowdown_table,
 )
 
@@ -45,6 +47,12 @@ GRID = (
 )
 
 
+def _sweep(specs, **kw):
+    """One engine sweep at the test size, on one shard."""
+    return run_sweep(specs, n_stores=kw.pop("n_stores", N), n_shards=1,
+                     tile_cells=16, **kw)
+
+
 def _serial(spec: ScenarioSpec):
     return simulate(spec.workload, spec.config, n_stores=N, seed=spec.seed,
                     n_replicas=spec.n_replicas,
@@ -54,7 +62,7 @@ def _serial(spec: ScenarioSpec):
 
 @pytest.fixture(scope="module")
 def batch_results():
-    return simulate_batch(GRID, n_stores=N)
+    return _sweep(GRID)
 
 
 def test_batch_matches_serial_on_grid(batch_results):
@@ -75,19 +83,19 @@ def test_batch_results_preserve_spec_order(batch_results):
 
 
 def test_batch_deterministic(batch_results):
-    again = simulate_batch(GRID, n_stores=N)
+    again = _sweep(GRID)
     for a, b in zip(batch_results, again):
         assert a.exec_time_ns == b.exec_time_ns
         assert a.repl_at_head_frac == b.repl_at_head_frac
 
 
 def test_odd_batch_sizes_padded_correctly():
-    """Non-multiple-of-8 batches must pad internally without leaking
-    padding cells into the output."""
+    """Grids smaller than a tile must pad internally without leaking
+    padding lanes into the output."""
     specs = [ScenarioSpec("ycsb", "proactive"),
              ScenarioSpec("raytrace", "wb"),
              ScenarioSpec("barnes", "wt", seed=1)]
-    out = simulate_batch(specs, n_stores=N)
+    out = _sweep(specs)
     assert len(out) == 3
     for spec, rb in zip(specs, out):
         rs = _serial(spec)
@@ -97,43 +105,43 @@ def test_odd_batch_sizes_padded_correctly():
 
 def test_single_cell_batch_matches_serial():
     spec = ScenarioSpec("ocean_ncp", "proactive", sb_size=24)
-    (rb,) = simulate_batch([spec], n_stores=N)
+    (rb,) = _sweep([spec])
     rs = _serial(spec)
     np.testing.assert_allclose(rb.exec_time_ns, rs.exec_time_ns, rtol=RTOL)
     np.testing.assert_allclose(rb.sb_full_frac, rs.sb_full_frac, rtol=RTOL)
 
 
 def test_empty_batch():
-    assert simulate_batch([], n_stores=N) == []
+    assert _sweep([]) == []
 
 
 def test_invalid_specs_rejected():
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("ycsb", "nosuch")], n_stores=N)
+        _sweep([ScenarioSpec("ycsb", "nosuch")])
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("nosuch", "wb")], n_stores=N)
+        _sweep([ScenarioSpec("nosuch", "wb")])
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("ycsb", "wb", sb_size=0)], n_stores=N)
+        _sweep([ScenarioSpec("ycsb", "wb", sb_size=0)])
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("ycsb", "wb", n_replicas=0)], n_stores=N)
+        _sweep([ScenarioSpec("ycsb", "wb", n_replicas=0)])
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("ycsb", "wb", n_cns=0)], n_stores=N)
+        _sweep([ScenarioSpec("ycsb", "wb", n_cns=0)])
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("ycsb", "wb", link_bw_gbps=0.0)],
-                       n_stores=N)
+        _sweep([ScenarioSpec("ycsb", "wb", link_bw_gbps=0.0)])
 
 
 # ---------------------------------------------------------------------------
-# Blocked-scan differential tests: blocked vs per-step vs serial oracle,
-# bit-identical across chunk sizes (ragged tails included)
+# Blocked-scan differential tests: engine vs serial oracle, bit-identical
+# across chunk sizes (ragged tails included)
 # ---------------------------------------------------------------------------
 
-# uniform SB -> tuple-history fast path; N % 72 != 0 exercises the tail
+# one SB depth -> one tile group; N % 72 != 0 exercises the tail
 UNIFORM_GRID = [ScenarioSpec(w, c)
                 for w in ("ycsb", "raytrace", "ocean_ncp")
                 for c in CONFIGS] + [ScenarioSpec("canneal", "proactive",
                                                   seed=3)]
-# mixed SB depths -> general gather path (chunk clamps to min sb = 16)
+# mixed SB depths -> one tile group per depth, each clamping the chunk
+# to its own depth
 MIXED_GRID = UNIFORM_GRID[:6] + [
     ScenarioSpec("ycsb", "parallel", sb_size=16),
     ScenarioSpec("barnes", "proactive", sb_size=24),
@@ -166,22 +174,24 @@ def _assert_bit_identical(specs, batch, oracle, ctx):
             assert getattr(rb, f) == getattr(rs, f), (ctx, spec, f)
 
 
-@pytest.mark.parametrize("chunk", [0, 1, 7, 72, 4 * DEFAULT_CHUNK_SIZE])
+@pytest.mark.parametrize("chunk", [1, 7, 72, 4 * DEFAULT_CHUNK_SIZE])
 def test_uniform_sb_engines_bit_identical(chunk, serial_by_spec):
-    """Fast path (and per-step engine at chunk=0) vs serial, ``==``.
+    """The blocked scan vs serial, ``==``.
 
     chunk=72 divides nothing evenly at N=6000 (83 blocks + 24-store
     tail); chunk > sb clamps to the SB depth; chunk=1 degenerates to
     per-store blocks.
     """
-    out = simulate_batch(UNIFORM_GRID, n_stores=N, chunk_size=chunk)
+    out = run_grid(UNIFORM_GRID, n_stores=N, chunk_size=chunk, n_shards=1,
+                   tile_cells=16)
     _assert_bit_identical(UNIFORM_GRID, out, serial_by_spec, f"chunk={chunk}")
 
 
-@pytest.mark.parametrize("chunk", [0, 1, 7, 64])
+@pytest.mark.parametrize("chunk", [1, 7, 64])
 def test_mixed_sb_engines_bit_identical(chunk, serial_by_spec):
-    """General gather path (per-cell SB depths) vs serial, ``==``."""
-    out = simulate_batch(MIXED_GRID, n_stores=N, chunk_size=chunk)
+    """Per-cell SB depths (one tile group per depth) vs serial, ``==``."""
+    out = run_grid(MIXED_GRID, n_stores=N, chunk_size=chunk, n_shards=1,
+                   tile_cells=16)
     _assert_bit_identical(MIXED_GRID, out, serial_by_spec, f"chunk={chunk}")
 
 
@@ -191,7 +201,7 @@ def test_short_trace_edge_cases(serial_by_spec):
     specs = [ScenarioSpec("ycsb", "proactive"),
              ScenarioSpec("raytrace", "baseline")]
     for n in (50, 100):
-        out = simulate_batch(specs, n_stores=n)
+        out = _sweep(specs, n_stores=n)
         for spec, rb in zip(specs, out):
             rs = serial_by_spec(spec, n)
             for f in FLOAT_FIELDS:
@@ -200,8 +210,7 @@ def test_short_trace_edge_cases(serial_by_spec):
 
 def test_blocked_chunk_size_validation():
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("ycsb", "wb")], n_stores=N,
-                       chunk_size=-1)
+        _sweep([ScenarioSpec("ycsb", "wb")], chunk_size=-1)
 
 
 def test_slowdown_table_batched_matches_serial():

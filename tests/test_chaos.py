@@ -28,7 +28,7 @@ from repro.core.retry import (
     backoff_delays,
     retry_call,
 )
-from repro.core.scenarios import chaos_grid, sweep_grid
+from repro.core.scenarios import chaos_grid, run_sweep, sweep_grid
 from repro.core.serving import ScenarioServer
 from repro.core.simulator import (
     CONFIGS,
@@ -36,7 +36,6 @@ from repro.core.simulator import (
     ScenarioSpec,
     clear_sim_caches,
     get_trace_bank,
-    simulate_batch,
     sub_bank_rows,
 )
 
@@ -47,6 +46,11 @@ FLOAT_FIELDS = ("exec_time_ns", "repl_at_head_frac", "sb_full_frac",
 SHARD_COUNTS = sorted({1, min(8, jax.device_count())})
 FAULT_KINDS = ("shard-loss", "corrupt-row", "upload-failure",
                "kill-prefetch", "kill-warm")
+
+
+def _oracle(specs):
+    """The fault-free answers: the serial oracle, cell by cell."""
+    return run_sweep(specs, n_stores=N, engine="serial")
 
 
 def _assert_bit_identical(got, want, ctx):
@@ -89,26 +93,25 @@ def ragged_grids(draw):
 
 
 # ---------------------------------------------------------------------------
-# Engine: every fault x both data planes x 1 and 8 shards
+# Engine: every fault x 1 and 8 shards
 # ---------------------------------------------------------------------------
 
 
 @settings(max_examples=4, deadline=None)
 @given(ragged_grids(),
        st.sampled_from(FAULT_KINDS),
-       st.sampled_from(SHARD_COUNTS),
-       st.sampled_from(("bank", "stacked")))
-def test_engine_faults_recover_bit_identical(grid, kind, n_shards, plane):
+       st.sampled_from(SHARD_COUNTS))
+def test_engine_faults_recover_bit_identical(grid, kind, n_shards):
     """The headline differential: a fault injected mid-grid recovers to
-    results ``==`` the fault-free oracle on every plane/shard combo."""
-    oracle = simulate_batch(grid, n_stores=N)
+    results ``==`` the fault-free oracle on every shard count."""
+    oracle = _oracle(grid)
     with chaos.inject(_fault_cfg(kind, n_shards)) as cs:
         got = E.run_grid(grid, n_stores=N, tile_cells=16,
-                         n_shards=n_shards, data_plane=plane)
-    _assert_bit_identical(got, oracle, (kind, n_shards, plane))
+                         n_shards=n_shards)
+    _assert_bit_identical(got, oracle, (kind, n_shards))
     rep = cs.report()
     if kind == "shard-loss":
-        assert rep["recoveries"], (kind, n_shards, plane)
+        assert rep["recoveries"], (kind, n_shards)
         assert rep["recoveries"][0]["shard"] == n_shards - 1
     if kind == "upload-failure":
         assert rep["upload_retries"] == 2
@@ -125,7 +128,7 @@ def test_engine_shard_loss_zero_recompiles_on_spare_path():
         pytest.skip("needs >= 2 shards for a surviving replica")
     grid = chaos_grid()
     clear_sim_caches()
-    oracle = simulate_batch(grid, n_stores=N)
+    oracle = _oracle(grid)
     with chaos.inject(ChaosConfig(lose_shard=2, lose_at_dispatch=2)) as cs:
         warm = E.run_grid(grid, n_stores=N, tile_cells=16,
                           n_shards=n_shards)
@@ -151,7 +154,7 @@ def test_engine_degraded_mesh_recovery():
         pytest.skip("cannot shrink a single-shard mesh")
     grid = sweep_grid(workloads=("ycsb", "barnes"),
                       configs=("wb", "proactive"), n_replicas=(None, 2))
-    oracle = simulate_batch(grid, n_stores=N)
+    oracle = _oracle(grid)
     with chaos.inject(ChaosConfig(lose_shard=0, lose_at_dispatch=1,
                                   recovery="degraded")) as cs:
         got = E.run_grid(grid, n_stores=N, tile_cells=16,
@@ -189,7 +192,7 @@ def test_poisoned_tile_surfaces_with_context(monkeypatch):
     clear_sim_caches()
     _assert_bit_identical(E.run_grid(grid, n_stores=N, tile_cells=16,
                                      n_shards=1),
-                          simulate_batch(grid, n_stores=N), "after-poison")
+                          _oracle(grid), "after-poison")
 
 
 # ---------------------------------------------------------------------------
@@ -378,7 +381,7 @@ def test_server_faults_recover_bit_identical(kind):
     stays ``==`` the cold oracle."""
     n_shards = min(8, jax.device_count())
     clear_sim_caches()
-    oracle = simulate_batch(SERVE_NOVEL, n_stores=N)
+    oracle = _oracle(SERVE_NOVEL)
     cfg = (ChaosConfig(kill_thread="daemon") if kind == "kill-daemon"
            else ChaosConfig(lose_shard=n_shards - 1, lose_at_dispatch=2)
            if kind == "shard-loss" else _fault_cfg(kind, n_shards))

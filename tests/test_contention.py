@@ -3,9 +3,8 @@
 The contract (docs/contention.md):
 
 * contended timelines are **bit-identical** (``==``) across the
-  pure-Python pre-collapse oracle, the jitted serial oracle, the
-  blocked batch (both data planes) and the banked streaming engine, on
-  ragged mixed-SB grids;
+  pure-Python pre-collapse oracle, the jitted serial oracle and the
+  streaming engine, on ragged mixed-SB grids;
 * all-``None`` contention axes are inert -- outputs AND bank dedup
   keys reproduce today's bit-exactly (no row churn on legacy grids:
   the 12 960-cell mega-grid keeps its 27+1298 bank rows);
@@ -42,12 +41,12 @@ from repro.core.scenarios import (
     contention_mega_grid,
     mega_grid,
     recovery_sweep,
+    run_sweep,
 )
 from repro.core.simulator import (
     ScenarioSpec,
     bank_row_maps,
     clear_sim_caches,
-    simulate_batch,
     simulate_spec,
 )
 
@@ -55,6 +54,11 @@ N = 700                                  # N % 72 != 0: ragged store tail
 FLOAT_FIELDS = ("exec_time_ns", "repl_at_head_frac", "sb_full_frac",
                 "max_log_bytes", "cxl_mem_bw_gbps", "log_dump_bw_gbps")
 WORKLOAD_POOL = ("ycsb", "canneal", "barnes", "raytrace")
+
+
+def _sweep(specs):
+    """One engine sweep at the test size, on one shard."""
+    return run_sweep(specs, n_stores=N, n_shards=1, tile_cells=16)
 
 
 def _assert_identical(a, b, ctx):
@@ -83,7 +87,7 @@ def test_contention_validation_rejected():
                 ScenarioSpec("ycsb", "proactive",
                              consistency_schedule="nosuch")):
         with pytest.raises(ValueError):
-            simulate_batch([bad], n_stores=N)
+            _sweep([bad])
     with pytest.raises(ValueError):
         C.schedule_flush_ns("nosuch", 8, S.PAPER_CLUSTER)
 
@@ -115,15 +119,11 @@ def contended_grids(draw):
 @settings(max_examples=6, deadline=None)
 @given(contended_grids())
 def test_contended_paths_bit_identical(specs):
-    banked = simulate_batch(specs, n_stores=N)
-    stacked = simulate_batch(specs, n_stores=N, data_plane="stacked")
     stream = E.run_grid(specs, n_stores=N, tile_cells=16)
     for i, s in enumerate(specs):
         serial = simulate_spec(s, n_stores=N)
         oracle = serial_oracle(s, n_stores=N)
         _assert_identical(oracle, serial, (s, "oracle-vs-serial"))
-        _assert_identical(banked[i], serial, (s, "banked-vs-serial"))
-        _assert_identical(stacked[i], serial, (s, "stacked-vs-serial"))
         _assert_identical(stream[i], serial, (s, "stream-vs-serial"))
 
 
@@ -133,7 +133,7 @@ def test_neutral_axes_reproduce_legacy_bits_in_new_row():
     legacy = ScenarioSpec("ycsb", "proactive")
     neutral = ScenarioSpec("ycsb", "proactive", read_share=0.0,
                            conflict_rate=0.0, consistency_schedule="lazy")
-    a, b = simulate_batch([legacy, neutral], n_stores=N)
+    a, b = _sweep([legacy, neutral])
     _assert_identical(a, b, "neutral-vs-legacy")
     bank = S.get_trace_bank([legacy, neutral], N)
     assert bank.rows_for(legacy)[1] != bank.rows_for(neutral)[1]
@@ -148,7 +148,7 @@ def test_wb_wt_rows_stay_constant_under_contention():
              for c in ("wb", "wt") for cr in (None, 0.6)]
     bank = S.get_trace_bank(specs, N)
     assert bank.wv_rows == 2
-    res = simulate_batch(specs, n_stores=N)
+    res = _sweep(specs)
     _assert_identical(res[0], res[1], "wb-contended")
     _assert_identical(res[2], res[3], "wt-contended")
 
@@ -194,7 +194,7 @@ def test_slowdown_monotone_in_conflict_rate():
     rates = (0.0, 0.25, 0.6)
     specs = [ScenarioSpec("ycsb", "proactive", conflict_rate=r)
              for r in rates]
-    t = [r.exec_time_ns for r in simulate_batch(specs, n_stores=N)]
+    t = [r.exec_time_ns for r in _sweep(specs)]
     assert t[0] < t[1] < t[2], t
 
 
@@ -203,7 +203,7 @@ def test_schedule_ordering_and_epoch_barriers():
              for sc in CONSISTENCY_SCHEDULES]
     t = {sc: r.exec_time_ns
          for sc, r in zip(CONSISTENCY_SCHEDULES,
-                          simulate_batch(specs, n_stores=N))}
+                          _sweep(specs))}
     assert t["lazy"] < t["epoch"] < t["eager"], t
     flush = C.schedule_flush_ns("epoch", 3 * C.EPOCH_LEN, S.PAPER_CLUSTER)
     assert np.count_nonzero(flush) == 3
@@ -216,17 +216,16 @@ def test_cn_axis_shares_contended_lanes():
     specs = [ScenarioSpec("ycsb", "proactive", n_cns=ncn,
                           conflict_rate=0.4, consistency_schedule="epoch")
              for ncn in (16, 8, 4, 2)]
-    res = simulate_batch(specs, n_stores=N)
-    assert res[0].meta["scan_lanes"] == 1
-    E.run_grid(specs, n_stores=N, tile_cells=16)
+    res = E.run_grid(specs, n_stores=N, tile_cells=16)
     assert E.bank_stats()["scan_lanes"] == 1
+    # one scanned lane, scattered with each cell's own CN work scale
+    assert len({r.exec_time_ns for r in res}) == len(specs)
 
 
 def test_contention_grid_builders():
     assert len(contention_grid()) == 3 * 2 * 3 * 2 * 3
     specs = contention_mega_grid()
     assert len(specs) == len(WORKLOADS) * 2 * 2 * 2 * 2 * 3 * 2 * 3
-    assert len(specs) >= E.STREAM_THRESHOLD   # auto-routes to streaming
     assert any(s.conflict_rate == 0.5 for s in specs)
     # the neutral normalization corner is present
     assert any(s.conflict_rate == 0.0 and s.read_share == 0.0
@@ -246,7 +245,7 @@ def test_contended_streaming_compiles_and_dedup():
     assert E.trace_count() - t0 <= 3
     stats = E.bank_stats()
     assert stats["scan_lanes"] < stats["cells"] == len(specs)
-    assert stats["data_plane"] == "bank"
+    assert stats["bank_partition"] == "sub"
 
 
 # ---------------------------------------------------------------------------
@@ -290,7 +289,7 @@ def test_clear_sim_caches_drops_contention_memos():
     clear_sim_caches()
     spec = ScenarioSpec("ycsb", "proactive", conflict_rate=0.4,
                         read_share=0.3)
-    simulate_batch([spec], n_stores=N)
+    _sweep([spec])
     draws, delays = C.contention_cache_sizes()
     assert draws > 0 and delays > 0
     d = C.conflict_draws(N, 0, 0.4, 0.3)       # cache hit

@@ -3,9 +3,9 @@
 The contract (docs/simulator.md, docs/contention.md):
 
 * directory-coupled timelines are **bit-identical** (``==``) across the
-  pure-Python pre-collapse oracle, the jitted serial oracle, the
-  blocked batch (both data planes) and the banked streaming engine, on
-  ragged mixed-SB grids that also span the contention axes;
+  pure-Python pre-collapse oracle, the jitted serial oracle and the
+  streaming engine, on ragged mixed-SB grids that also span the
+  contention axes;
 * ``directory_load=None`` is inert -- outputs AND bank dedup keys
   reproduce the PR-5 bits exactly (zero row churn on legacy grids);
 * ``directory_load=0.0`` yields bit-identical *outputs* while
@@ -37,11 +37,11 @@ from repro.core.scenarios import (
     directory_mega_grid,
     mega_grid,
     recovery_sweep,
+    run_sweep,
 )
 from repro.core.simulator import (
     ScenarioSpec,
     bank_row_maps,
-    simulate_batch,
     simulate_spec,
 )
 
@@ -49,6 +49,11 @@ N = 700                                  # N % 72 != 0: ragged store tail
 FLOAT_FIELDS = ("exec_time_ns", "repl_at_head_frac", "sb_full_frac",
                 "max_log_bytes", "cxl_mem_bw_gbps", "log_dump_bw_gbps")
 WORKLOAD_POOL = ("ycsb", "canneal", "barnes", "raytrace")
+
+
+def _sweep(specs):
+    """One engine sweep at the test size, on one shard."""
+    return run_sweep(specs, n_stores=N, n_shards=1, tile_cells=16)
 
 
 def _assert_identical(a, b, ctx):
@@ -73,8 +78,7 @@ def test_resolve_directory_load():
         with pytest.raises(ValueError):
             resolve_directory_load(bad, 16, 3)
     with pytest.raises(ValueError):
-        simulate_batch([ScenarioSpec("ycsb", "proactive",
-                                     directory_load=1.0)], n_stores=N)
+        _sweep([ScenarioSpec("ycsb", "proactive", directory_load=1.0)])
 
 
 def test_sharer_pool_clamped_to_cluster():
@@ -143,15 +147,11 @@ def coupled_grids(draw):
 @settings(max_examples=6, deadline=None)
 @given(coupled_grids())
 def test_coupled_paths_bit_identical(specs):
-    banked = simulate_batch(specs, n_stores=N)
-    stacked = simulate_batch(specs, n_stores=N, data_plane="stacked")
     stream = E.run_grid(specs, n_stores=N, tile_cells=16)
     for i, s in enumerate(specs):
         serial = simulate_spec(s, n_stores=N)
         oracle = serial_oracle(s, n_stores=N)
         _assert_identical(oracle, serial, (s, "oracle-vs-serial"))
-        _assert_identical(banked[i], serial, (s, "banked-vs-serial"))
-        _assert_identical(stacked[i], serial, (s, "stacked-vs-serial"))
         _assert_identical(stream[i], serial, (s, "stream-vs-serial"))
 
 
@@ -161,7 +161,7 @@ def test_load_zero_reproduces_legacy_bits_in_new_row():
     bank row (the in-grid normalization cell)."""
     legacy = ScenarioSpec("ycsb", "proactive")
     zero = ScenarioSpec("ycsb", "proactive", directory_load=0.0)
-    a, b = simulate_batch([legacy, zero], n_stores=N)
+    a, b = _sweep([legacy, zero])
     _assert_identical(a, b, "zero-load-vs-legacy")
     bank = S.get_trace_bank([legacy, zero], N)
     assert bank.rows_for(legacy)[1] != bank.rows_for(zero)[1]
@@ -175,7 +175,7 @@ def test_wb_wt_rows_stay_constant_under_directory_load():
              for c in ("wb", "wt") for dl in (None, 0.7)]
     bank = S.get_trace_bank(specs, N)
     assert bank.wv_rows == 2
-    res = simulate_batch(specs, n_stores=N)
+    res = _sweep(specs)
     _assert_identical(res[0], res[1], "wb-coupled")
     _assert_identical(res[2], res[3], "wt-coupled")
 
@@ -220,8 +220,8 @@ def test_load_zero_cells_share_one_lane_across_cn_counts():
     specs = [ScenarioSpec("ycsb", "proactive", n_cns=ncn,
                           directory_load=0.0)
              for ncn in (16, 8, 4, 2)]
-    res = simulate_batch(specs, n_stores=N)
-    assert res[0].meta["scan_lanes"] == 1
+    _sweep(specs)
+    assert E.bank_stats()["scan_lanes"] == 1
     # loaded cells at different CN counts resolve different rho_bg and
     # must NOT share a lane
     keys = {S._plane_keys(ScenarioSpec("ycsb", "proactive", n_cns=ncn,
@@ -261,7 +261,6 @@ def test_proactive_absorbs_directory_wait():
 def test_directory_mega_grid_builder():
     specs = directory_mega_grid()
     assert len(specs) == 2592
-    assert len(specs) >= E.STREAM_THRESHOLD   # auto-routes to streaming
     assert any(s.directory_load == 0.0 for s in specs)   # normalization
     assert any(s.n_cns == 4 for s in specs)              # clamp exercise
     assert {s.config for s in specs} >= {"baseline", "proactive"}

@@ -1,20 +1,20 @@
-"""Sharded streaming engine tier: differential + scheduling tests.
+"""Sharded streaming engine: differential + scheduling tests.
 
 The contract (engine.py module docstring): ``run_grid`` -- tiled,
 cell-sharded over the local devices (8 host devices here, set up by
 conftest.py), double-buffered -- must be **bit-identical** (``==``) to
-the one-shot blocked batch and to the serial ``simulate()`` oracle, for
-ragged grids whose cell count divides neither the device count nor the
-tile size, and must reuse one compiled program per
+the serial ``simulate()`` oracle, for ragged grids whose cell count
+divides neither the device count nor the tile size and for grids in
+which some shards own no rows, and must reuse one compiled program per
 :class:`TileSignature` across all tiles (the compile cache is observed
 through ``trace_count()``).
 """
 
 import jax
-import numpy as np
 import pytest
 
 from repro.core import engine as E
+from repro.core.scenarios import run_sweep
 from repro.core.simulator import (
     AUTO_CHUNK_WIDE_CELLS,
     CONFIGS,
@@ -23,7 +23,7 @@ from repro.core.simulator import (
     auto_chunk,
     clear_sim_caches,
     simulate,
-    simulate_batch,
+    simulate_spec,
 )
 
 N = 2500                       # N % chunk != 0 -> ragged store tail too
@@ -59,33 +59,25 @@ def _assert_bit_identical(specs, got, want, ctx):
 
 
 @pytest.fixture(scope="module")
-def blocked_results():
-    return simulate_batch(RAGGED_GRID, n_stores=N)
+def serial_results():
+    return [simulate_spec(s, n_stores=N) for s in RAGGED_GRID]
 
 
-def test_stream_bit_identical_to_blocked_and_serial(blocked_results):
+def test_stream_bit_identical_to_blocked_and_serial(serial_results):
     out = E.run_grid(RAGGED_GRID, n_stores=N, tile_cells=16)
-    _assert_bit_identical(RAGGED_GRID, out, blocked_results, "stream-vs-blocked")
-    # spot-check straight against the serial oracle as well
-    for i in (0, 7, 17, 30, 36):
-        s = RAGGED_GRID[i]
-        rs = simulate(s.workload, s.config, n_stores=N, seed=s.seed,
-                      n_replicas=s.n_replicas, link_bw_gbps=s.link_bw_gbps,
-                      n_cns=s.n_cns, sb_size=s.sb_size,
-                      coalescing=s.coalescing)
-        for f in FLOAT_FIELDS:
-            assert getattr(out[i], f) == getattr(rs, f), (s, f)
+    _assert_bit_identical(RAGGED_GRID, out, serial_results,
+                          "stream-vs-serial")
 
 
-def test_stream_single_shard_matches_sharded(blocked_results):
+def test_stream_single_shard_matches_sharded(serial_results):
     if jax.device_count() < 2:
         pytest.skip("needs >= 2 devices for a sharded run")
     sharded = E.run_grid(RAGGED_GRID, n_stores=N, tile_cells=16,
                          n_shards=min(8, jax.device_count()))
     single = E.run_grid(RAGGED_GRID, n_stores=N, tile_cells=16, n_shards=1)
     _assert_bit_identical(RAGGED_GRID, sharded, single, "sharded-vs-single")
-    _assert_bit_identical(RAGGED_GRID, single, blocked_results,
-                          "single-vs-blocked")
+    _assert_bit_identical(RAGGED_GRID, single, serial_results,
+                          "single-vs-serial")
     assert sharded[0].meta["engine"] == "sharded"
     assert sharded[0].meta["n_shards"] > 1
     assert single[0].meta["engine"] == "streamed"
@@ -111,14 +103,63 @@ def test_bank_placement_layout_bytes_and_oracle(n_shards):
     stacks = 0 if n_shards == 1 else sum(
         x.nbytes for x in bank.sub_bank_host(n_shards)[1:])
     assert counters["bank/layout_bytes"] == stacks
-    for s, got in zip(grid, out):
-        rs = simulate(s.workload, s.config, n_stores=N, seed=s.seed,
-                      n_replicas=s.n_replicas, link_bw_gbps=s.link_bw_gbps,
-                      n_cns=s.n_cns, sb_size=s.sb_size,
-                      coalescing=s.coalescing)
-        assert got.n_repl_msgs == rs.n_repl_msgs, s
-        for f in FLOAT_FIELDS:
-            assert getattr(got, f) == getattr(rs, f), (s, f)
+    _assert_bit_identical(grid, out, [simulate_spec(s, n_stores=N)
+                                      for s in grid], "layout")
+
+
+#: Distinct cells with distinct max-plus rows, so every cell is its own
+#: lane and rows spread over the shards round-robin.
+SIZE_POOL = [ScenarioSpec(w, c, seed=s, n_replicas=nr)
+             for nr in (None, 2) for s in (0, 1)
+             for w in ("ycsb", "raytrace", "canneal", "barnes", "ocean_cp")
+             for c in CONFIGS]
+
+
+@pytest.mark.parametrize("n_shards", [1, 8])
+@pytest.mark.parametrize("n_cells", [1, 7, 9, 65])
+def test_grid_sizes_match_serial(n_cells, n_shards):
+    """Grids of 1, 7, 9 and 65 cells at 1 and 8 shards ``==`` the
+    serial oracle: at 8 shards the 1- and 7-cell grids leave shards
+    with no rows, and 9 and 65 cells leave every tile ragged."""
+    if jax.device_count() < n_shards:
+        pytest.skip(f"needs {n_shards} devices")
+    grid = SIZE_POOL[:n_cells]
+    out = E.run_grid(grid, n_stores=N, tile_cells=16, n_shards=n_shards)
+    assert E.bank_stats()["n_shards"] == n_shards
+    _assert_bit_identical(grid, out, [simulate_spec(s, n_stores=N)
+                                      for s in grid], (n_cells, n_shards))
+
+
+@pytest.mark.parametrize("n_shards", [1, 8])
+def test_default_tile_fits_small_grids(serial_results, monkeypatch,
+                                       n_shards):
+    """Without ``tile_cells`` a grid whose SB groups each fit one default
+    tile runs one tile per group, padded to the widest group rather than
+    the full default tile; a group wider than the default tile keeps the
+    default width. Answers stay ``==`` the serial oracle either way."""
+    from repro.core import telemetry as tm
+    if jax.device_count() < n_shards:
+        pytest.skip(f"needs {n_shards} devices")
+    n_groups = len({s.sb_size for s in RAGGED_GRID})
+    with tm.recording() as rec:
+        out = E.run_grid(RAGGED_GRID, n_stores=N, n_shards=n_shards)
+        tiles = rec.summary()["counters"]["engine/tiles"]
+    assert tiles == n_groups
+    (width,) = {r.meta["tile_cells"] for r in out}
+    assert width % 8 == 0 and width < E._default_tile_cells(N)
+    assert len(RAGGED_GRID) // n_groups <= width
+    for r, s in zip(out, RAGGED_GRID):
+        sb = s.sb_size or 72
+        assert r.meta["chunk"] == auto_chunk(N, sb, width)
+    _assert_bit_identical(RAGGED_GRID, out, serial_results, "narrowed")
+
+    monkeypatch.setattr(E, "_default_tile_cells", lambda n_stores: 16)
+    with tm.recording() as rec:
+        out = E.run_grid(RAGGED_GRID, n_stores=N, n_shards=n_shards)
+        tiles = rec.summary()["counters"]["engine/tiles"]
+    assert tiles > n_groups
+    assert {r.meta["tile_cells"] for r in out} == {16}
+    _assert_bit_identical(RAGGED_GRID, out, serial_results, "default")
 
 
 def test_compile_cache_hits_across_tiles():
@@ -189,26 +230,20 @@ def test_auto_chunk_heuristic_and_meta():
     assert auto_chunk(50_000, 72, AUTO_CHUNK_WIDE_CELLS) == 40
 
     specs = [ScenarioSpec("ycsb", "proactive")]
-    (r,) = simulate_batch(specs, n_stores=N)
-    want = {"engine": "blocked", "chunk": auto_chunk(N, 72, 8),
+    (r,) = E.run_grid(specs, n_stores=N, tile_cells=16, n_shards=1)
+    want = {"engine": "streamed", "chunk": auto_chunk(N, 72, 16),
             "auto_chunk": True, "data_plane": "bank"}
     assert want.items() <= r.meta.items()
     assert r.meta["bank_rows"] == 2 and r.meta["h2d_bytes"] > 0
-    (r,) = simulate_batch(specs, n_stores=N, chunk_size=7)
-    assert {"engine": "blocked", "chunk": 7,
+    (r,) = E.run_grid(specs, n_stores=N, tile_cells=16, n_shards=1,
+                      chunk_size=7)
+    assert {"engine": "streamed", "chunk": 7,
             "auto_chunk": False}.items() <= r.meta.items()
-    (r,) = simulate_batch(specs, n_stores=N, chunk_size=0)
-    assert r.meta["engine"] == "perstep"
-    assert r.meta["data_plane"] == "stacked"
     assert simulate("ycsb", "proactive", n_stores=N).meta == {
         "engine": "serial", "data_plane": "stacked",
         "bank_partition": None}
-    # the narrow-SB cell bounds the auto chunk of the whole batch
-    (r, _) = simulate_batch([ScenarioSpec("ycsb", "proactive", sb_size=8),
-                             ScenarioSpec("ycsb", "wb")], n_stores=N)
-    assert r.meta["chunk"] == 8
-    # ...but the streaming tier groups by SB, so the wide group keeps
-    # its own chunk
+    # the engine groups by SB, so a narrow-SB cell clamps only its own
+    # group's chunk and the wide group keeps its own
     out = E.run_grid([ScenarioSpec("ycsb", "proactive", sb_size=8),
                       ScenarioSpec("ycsb", "wb")], n_stores=N, tile_cells=16)
     assert out[0].meta["chunk"] == 8
@@ -217,68 +252,47 @@ def test_auto_chunk_heuristic_and_meta():
 
 def test_tier_selection_and_validation():
     small = RAGGED_GRID[:4]
-    out = E.simulate_grid(small, n_stores=N)
-    assert out[0].meta["engine"] == "blocked"
-    out = E.simulate_grid(small, n_stores=N, engine="stream", tile_cells=16)
+    out = run_sweep(small, n_stores=N, tile_cells=16)
     assert out[0].meta["engine"] in ("sharded", "streamed")
-    out = E.simulate_grid(small, n_stores=N, engine="serial")
+    out = run_sweep(small, n_stores=N, engine="stream", tile_cells=16)
+    assert out[0].meta["engine"] in ("sharded", "streamed")
+    out = run_sweep(small, n_stores=N, engine="serial")
     assert out[0].meta["engine"] == "serial"
-    assert E.simulate_grid([], n_stores=N) == []
+    assert run_sweep([], n_stores=N) == []
     with pytest.raises(ValueError):
-        E.simulate_grid(small, n_stores=N, engine="nosuch")
-    with pytest.raises(ValueError):
-        E.run_grid(small, n_stores=N, chunk_size=0)   # no per-step tier
+        run_sweep(small, n_stores=N, engine="nosuch")
+    with pytest.raises(ValueError):       # engine knobs are not serial's
+        run_sweep(small, n_stores=N, engine="serial", tile_cells=16)
     with pytest.raises(ValueError):
         E.run_grid(small, n_stores=N, n_shards=jax.device_count() + 1)
     with pytest.raises(ValueError):
         E.run_grid([ScenarioSpec("ycsb", "nosuch")], n_stores=N)
 
 
-def test_run_sweep_routes_through_engine():
-    from repro.core.scenarios import run_sweep
+@pytest.mark.parametrize("engine", ["blocked", "perstep"])
+def test_removed_engines_rejected(engine):
+    """The one-shot batch and per-step engines are gone: asking for
+    them is an error, not a silent fallback to the stream engine."""
+    with pytest.raises(ValueError, match=engine):
+        run_sweep(RAGGED_GRID[:2], n_stores=N, engine=engine)
 
+
+def test_run_sweep_routes_through_engine(serial_results):
     specs = RAGGED_GRID[:6]
-    got = run_sweep(specs, n_stores=N)
-    want = simulate_batch(specs, n_stores=N)
+    want = serial_results[:6]
+    got = run_sweep(specs, n_stores=N, n_shards=1)
     _assert_bit_identical(specs, got, want, "run_sweep")
-    got = run_sweep(specs, n_stores=N, engine="stream", tile_cells=16)
+    got = run_sweep(specs, n_stores=N, engine="stream", tile_cells=16,
+                    n_shards=1)
     _assert_bit_identical(specs, got, want, "run_sweep-stream")
-
-
-def test_stacked_plane_bit_identical_and_observable(blocked_results):
-    """The PR-3 stacked plane stays available (``data_plane="stacked"``)
-    and bit-identical to the banked default, for both the streaming and
-    one-shot tiers; meta + bank_stats() record which plane ran."""
-    out = E.run_grid(RAGGED_GRID, n_stores=N, tile_cells=16,
-                     data_plane="stacked")
-    _assert_bit_identical(RAGGED_GRID, out, blocked_results,
-                          "stacked-vs-banked")
-    assert out[0].meta["data_plane"] == "stacked"
-    assert out[0].meta["bank_rows"] == 0
-    stats = E.bank_stats()
-    assert stats["data_plane"] == "stacked"
-    assert stats["dedup_ratio"] == 1.0
-    assert stats["h2d_bytes"] == stats["stacked_h2d_bytes"]
-
-    one_shot = simulate_batch(RAGGED_GRID, n_stores=N, data_plane="stacked")
-    _assert_bit_identical(RAGGED_GRID, one_shot, blocked_results,
-                          "oneshot-stacked-vs-banked")
-    assert one_shot[0].meta["data_plane"] == "stacked"
-
-    with pytest.raises(ValueError):
-        E.run_grid(RAGGED_GRID[:2], n_stores=N, data_plane="nosuch")
-    with pytest.raises(ValueError):
-        simulate_batch(RAGGED_GRID[:2], n_stores=N, data_plane="nosuch")
-    with pytest.raises(ValueError):    # the per-step engine has no bank
-        simulate_batch(RAGGED_GRID[:2], n_stores=N, chunk_size=0,
-                       data_plane="bank")
+    got = run_sweep(specs, n_stores=N, engine="serial")
+    _assert_bit_identical(specs, got, want, "run_sweep-serial")
 
 
 def test_bank_stats_and_meta_on_banked_run():
-    """bank_stats() reports the last run's data-plane accounting --
-    MEASURED resident device bytes from the live buffers, sub vs
-    replicated -- and the banked plane ships measurably fewer H2D bytes
-    than stacking."""
+    """bank_stats() reports the last run's bank accounting -- MEASURED
+    resident device bytes from the live buffers, sub vs replicated --
+    and the scanned lanes never outnumber the cells."""
     out = E.run_grid(RAGGED_GRID, n_stores=N, tile_cells=16)
     meta = out[0].meta
     assert meta["data_plane"] == "bank"
@@ -290,9 +304,9 @@ def test_bank_stats_and_meta_on_banked_run():
     assert stats["bank_rows"] == stats["trace_rows"] + stats["wv_rows"]
     assert meta["bank_rows"] == stats["bank_rows"] > 0
     assert meta["h2d_bytes"] == stats["h2d_bytes"] > 0
-    # dedup: 37 cells share 12 traces / far fewer wv rows than cells
-    assert stats["h2d_bytes"] < stats["stacked_h2d_bytes"]
-    assert stats["dedup_ratio"] > 1.0
+    # dedup: 37 cells share 12 traces and fewer wv rows than cells
+    assert stats["trace_rows"] < stats["wv_rows"] < stats["cells"]
+    assert stats["scan_lanes"] <= stats["cells"]
     # measured sub-bank residency: arrivals replicated + one padded
     # copy of each max-plus row fleet-wide. Bound per-shard bytes by
     # arrivals + padded wv share, total by n_shards x that.
@@ -320,22 +334,6 @@ def test_bank_stats_and_meta_on_banked_run():
     assert rep["bank_fabric_bytes"] == rep["bank_bytes"] * (n_shards - 1)
     with pytest.raises(ValueError):
         E.run_grid(RAGGED_GRID[:2], n_stores=N, bank_partition="nosuch")
-    with pytest.raises(ValueError):   # partition is a stream-tier knob
-        E.simulate_grid(RAGGED_GRID[:2], n_stores=N, engine="blocked",
-                        bank_partition="sub")
-
-
-def test_stream_threshold_routes_large_grids():
-    """simulate_grid(auto) must stream at or above the threshold; checked
-    via meta on a synthetic just-over-threshold grid of tiny traces."""
-    n_cells = E.STREAM_THRESHOLD
-    specs = [ScenarioSpec("ycsb", CONFIGS[i % len(CONFIGS)], seed=i % 4)
-             for i in range(n_cells)]
-    out = E.simulate_grid(specs, n_stores=64, tile_cells=512)
-    assert out[0].meta["engine"] in ("sharded", "streamed")
-    assert len(out) == n_cells
-    # sampled cells against the one-shot blocked path
-    sample = [0, 7, n_cells - 1]
-    want = simulate_batch([specs[i] for i in sample], n_stores=64)
-    for i, w in zip(sample, want):
-        assert out[i].exec_time_ns == w.exec_time_ns
+    with pytest.raises(ValueError):   # replica sets need the sub layout
+        E.run_grid(RAGGED_GRID[:2], n_stores=N,
+                   bank_partition="replicated", k_replicas=2)

@@ -1,19 +1,20 @@
-"""``SimResult.meta`` provenance schema, pinned across every tier.
+"""``SimResult.meta`` provenance schema, pinned across every path.
 
 The observability contract (docs/observability.md): any result, from
-any engine tier or serving path, must say where it came from --
-``meta["engine"]``, ``meta["data_plane"]`` and ``meta["bank_partition"]``
-are always present, with the tier's documented values.  PRs 1-9 grew
-the tiers one at a time and the earlier ones predate the bank plane;
-this test is the single place that keeps the schema from drifting as
-new tiers land.
+the serial oracle, the engine (one shard, sharded, under chaos, after a
+degraded-mesh recovery) or the serving daemon, must say where it came
+from -- ``meta["engine"]``, ``meta["data_plane"]`` and
+``meta["bank_partition"]`` are always present, with the path's
+documented values. This test is the single place that keeps the schema
+from drifting.
 """
 
 import pytest
 
+from repro.core import chaos
 from repro.core import engine as E
 from repro.core.scenarios import sweep_grid
-from repro.core.simulator import simulate, simulate_batch
+from repro.core.simulator import simulate
 
 N = 500
 GRID = sweep_grid(workloads=("ycsb",), configs=("wb", "proactive"),
@@ -22,21 +23,6 @@ GRID = sweep_grid(workloads=("ycsb",), configs=("wb", "proactive"),
 
 def _serial():
     return [simulate("ycsb", "wb", n_stores=N).meta]
-
-
-def _blocked_bank():
-    return [r.meta for r in simulate_batch(GRID, n_stores=N)]
-
-
-def _blocked_stacked():
-    return [r.meta
-            for r in simulate_batch(GRID, n_stores=N,
-                                    data_plane="stacked")]
-
-
-def _perstep():
-    return [r.meta for r in simulate_batch(GRID, n_stores=N,
-                                           chunk_size=0)]
 
 
 def _streamed():
@@ -52,6 +38,31 @@ def _sharded():
     return [r.meta for r in E.run_grid(GRID, n_stores=N, n_shards=2)]
 
 
+def _two_devices():
+    import jax
+    if len(jax.devices()) < 2:
+        pytest.skip("needs >= 2 devices")
+
+
+def _chaos_replicas():
+    # the replica-set layout of a chaos run: same engine, wider sub-bank
+    _two_devices()
+    with chaos.inject(chaos.ChaosConfig()):
+        return [r.meta for r in E.run_grid(GRID, n_stores=N, n_shards=2,
+                                           k_replicas=2)]
+
+
+def _degraded():
+    # a shard lost at the first dispatch with no spare: every cell is
+    # re-run on the shrunken (one-shard) mesh over a replicated bank
+    _two_devices()
+    with chaos.inject(chaos.ChaosConfig(lose_shard=0, lose_at_dispatch=1,
+                                        recovery="degraded")):
+        out = E.run_grid(GRID, n_stores=N, n_shards=2)
+    assert E.bank_stats()["degraded"] is True
+    return [r.meta for r in out]
+
+
 def _serving():
     from repro.core.serving import ScenarioServer
     with ScenarioServer(n_stores=N, batch_cells=8) as srv:
@@ -60,11 +71,10 @@ def _serving():
 
 TIERS = {
     "serial": (_serial, "serial", "stacked", None),
-    "blocked-bank": (_blocked_bank, "blocked", "bank", None),
-    "blocked-stacked": (_blocked_stacked, "blocked", "stacked", None),
-    "perstep": (_perstep, "perstep", "stacked", None),
     "streamed": (_streamed, "streamed", "bank", "sub"),
     "sharded": (_sharded, "sharded", "bank", "sub"),
+    "chaos-replicas": (_chaos_replicas, "sharded", "bank", "sub"),
+    "degraded": (_degraded, "streamed", "bank", "replicated"),
     "serving": (_serving, "serving", "bank", "sub"),
 }
 

@@ -23,10 +23,11 @@ from repro.core.scenarios import (
     fig17_grid,
     fig18_grid,
     run_fault_scenario,
+    run_sweep,
     sweep_grid,
 )
 from repro.core.simulator import (CONFIGS, geomean_slowdowns,
-                                  simulate_batch, slowdowns_from_results)
+                                  slowdowns_from_results)
 
 needs_devices = pytest.mark.skipif(jax.device_count() < 4,
                                    reason="needs >= 4 devices")
@@ -49,7 +50,7 @@ def test_grid_builders_shapes():
 
 @pytest.fixture(scope="module")
 def fig10_results():
-    return simulate_batch(fig10_grid(), n_stores=20_000)
+    return run_sweep(fig10_grid(), n_stores=20_000, n_shards=1)
 
 
 def test_fig10_geomeans_inside_paper_bands(fig10_results):
@@ -68,7 +69,7 @@ def test_fig17_nr_overhead_band():
     """N_r=4 stays within a few percent of N_r=3 (paper Fig. 17)."""
     grid = fig17_grid(replicas=(3, 4), workloads=("bodytrack", "canneal",
                                                   "ycsb"))
-    res = simulate_batch(grid, n_stores=20_000)
+    res = run_sweep(grid, n_stores=20_000, n_shards=1)
     t = {(r.workload, s.n_replicas): r.exec_time_ns
          for r, s in zip(res, grid)}
     ratios = [t[(w, 4)] / t[(w, 3)] for w in ("bodytrack", "canneal",

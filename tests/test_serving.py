@@ -1,10 +1,10 @@
 """Differential + concurrency harness for the scenario-serving daemon.
 
 The serving contract (``repro.core.serving``): every answer the daemon
-produces -- lane-cache hit or diff-upload miss, any batching, any data
-plane on the oracle side, any interleaving with ``clear_sim_caches()``
--- is bit-identical (``==``) to the cold batch oracle for the same
-spec, and steady-state serving compiles nothing. These tests pin all
+produces -- lane-cache hit or diff-upload miss, any batching, any
+interleaving with ``clear_sim_caches()`` -- is bit-identical (``==``)
+to the serial oracle for the same spec, and steady-state serving
+compiles nothing. These tests pin all
 of it, plus the ``_plane_keys`` bank-geometry invariants PRs 4-6
 relied on implicitly.
 """
@@ -27,6 +27,7 @@ from repro.core.scenarios import (
     grid_delta,
     mega_grid,
     recovery_sweep,
+    run_sweep,
     sweep_grid,
 )
 from repro.core.serving import ScenarioServer, _row_capacity
@@ -36,7 +37,6 @@ from repro.core.simulator import (
     ScenarioSpec,
     bank_row_maps,
     clear_sim_caches,
-    simulate_batch,
 )
 
 N = 700
@@ -81,6 +81,11 @@ def query_streams(draw):
     return stream
 
 
+def _oracle(specs):
+    """The serial oracle's answers for ``specs``, cell by cell."""
+    return run_sweep(specs, n_stores=N, engine="serial")
+
+
 def lane_count(specs, cluster=PAPER_CLUSTER):
     """Unique scan lanes of a grid: the (SB, trace, wv) dedup the
     engine and the daemon both key on."""
@@ -106,17 +111,12 @@ def test_daemon_bitident_to_cold_oracle_on_random_streams(stream):
         served_again = srv.query_batch(stream)      # now pure hits
         st_ = srv.stats()
     # the daemon's flush tiles gather from the capacity-padded device
-    # bank; the oracle builds its own grid from scratch on BOTH planes
+    # bank; the serial oracle derives every cell from scratch
     clear_sim_caches()
-    oracle_banked = simulate_batch(WARM_GRID + stream, n_stores=N)
-    clear_sim_caches()
-    oracle_stacked = simulate_batch(WARM_GRID + stream, n_stores=N,
-                                    data_plane="stacked")
-    for got, a, b in zip(warm_again + served,
-                         oracle_banked, oracle_stacked):
+    oracle = _oracle(WARM_GRID + stream)
+    for got, want in zip(warm_again + served, oracle):
         for f in FLOAT_FIELDS:
-            assert getattr(got, f) == getattr(a, f), (got.meta, f)
-            assert getattr(got, f) == getattr(b, f), (got.meta, f)
+            assert getattr(got, f) == getattr(want, f), (got.meta, f)
     # the re-served stream is answered from the lane cache, identically
     for x, y in zip(served, served_again):
         assert x == y
@@ -149,7 +149,7 @@ def test_hit_and_miss_paths_and_meta_provenance():
         assert again.meta["cache"] == "hit"
         assert again.meta["h2d_bytes"] == 0
         assert again == miss
-    oracle = simulate_batch([WARM_GRID[0], novel], n_stores=N)
+    oracle = _oracle([WARM_GRID[0], novel])
     assert hit == oracle[0]
     assert miss == oracle[1]
 
@@ -161,7 +161,7 @@ def test_sharded_serving_matches_oracle():
         served = srv.query_batch(WARM_GRID)
         novel = [ScenarioSpec("barnes", "proactive", seed=2)]
         served += srv.query_batch(novel)
-    oracle = simulate_batch(WARM_GRID + novel, n_stores=N)
+    oracle = _oracle(WARM_GRID + novel)
     for a, b in zip(served, oracle):
         assert a == b, (a.meta, b.meta)
 
@@ -188,7 +188,7 @@ def test_capacity_growth_reuploads_and_stays_bitident():
             or st_["bank_capacity"][1] > st_["dev_rows"][1]
         recheck = srv.query_batch(base)             # old lanes still hit
         assert all(r.meta["cache"] == "hit" for r in recheck)
-    oracle = simulate_batch(base + grow, n_stores=N)
+    oracle = _oracle(base + grow)
     for a, b in zip(first + grown, oracle):
         assert a == b
 
@@ -291,7 +291,7 @@ def test_lru_lane_eviction_reask_bitident():
         for _ in range(4):
             srv.query_batch([grid[-1], grid[0]])
         assert srv.query(grid[0]).meta["cache"] == "hit"
-    oracle = simulate_batch(grid, n_stores=N)
+    oracle = _oracle(grid)
     for a, b in zip(first, oracle):
         assert a == b
 
@@ -312,7 +312,7 @@ def test_bank_compaction_bounds_rows_and_stays_bitident():
         # the live bank tracks the bounded lane set, not query history
         assert st_["bank_rows"] < lane_count(grid) * 2
         again = [srv.query(s) for s in grid]
-    oracle = simulate_batch(grid, n_stores=N)
+    oracle = _oracle(grid)
     for a, b, c in zip(served, again, oracle):
         assert a == c and b == c
     with pytest.raises(ValueError):
@@ -340,7 +340,7 @@ def test_grid_delta_translation():
         served = srv.query_grid(**axes)
         st_ = srv.stats()
     assert st_["lane_hits"] >= len(full) - len(delta)
-    oracle = simulate_batch(full, n_stores=N)
+    oracle = _oracle(full)
     for a, b in zip(served, oracle):
         assert a == b
 
@@ -376,7 +376,7 @@ def test_submit_futures_batch_and_resolve():
         assert st_["queries"] == 2 * len(WARM_GRID)
         # the window coalesced concurrent submissions into few flushes
         assert 1 <= st_["batches"] <= 8
-    oracle = simulate_batch(WARM_GRID, n_stores=N)
+    oracle = _oracle(WARM_GRID)
     for a, b in zip(got, oracle + oracle):
         assert a == b
     with pytest.raises(RuntimeError):
@@ -387,10 +387,10 @@ def test_concurrent_queries_race_cache_clears_bitident():
     """N threads hammer the daemon (sync + async paths) while another
     thread repeatedly drops every host/compile cache: no deadlock, no
     bank double-build, every answer still == the oracle."""
-    oracle = simulate_batch(WARM_GRID, n_stores=N)
+    oracle = _oracle(WARM_GRID)
     novel = [ScenarioSpec(w, "proactive", seed=2, n_replicas=2)
              for w in WORKLOAD_POOL]
-    novel_oracle = simulate_batch(novel, n_stores=N)
+    novel_oracle = _oracle(novel)
     want = {s: r for s, r in zip(WARM_GRID + novel,
                                  list(oracle) + list(novel_oracle))}
 
@@ -454,7 +454,7 @@ def test_submit_timeout_fails_future_with_diagnostic():
         assert srv.stats()["submit_timeouts"] >= 1
         # the daemon is still healthy afterwards
         ok = srv.submit(WARM_GRID[0], timeout_ms=60_000).result(timeout=120)
-        assert ok == simulate_batch([WARM_GRID[0]], n_stores=N)[0]
+        assert ok == _oracle([WARM_GRID[0]])[0]
     with pytest.raises(ValueError):
         ScenarioServer(n_stores=N, submit_timeout_ms=0)
 
@@ -489,7 +489,7 @@ def test_close_drains_or_fails_pending_deterministically():
     assert not closer.is_alive(), "close() hung on a pending queue"
     try:
         res = fut.result(timeout=30)        # drained during close
-        assert res == simulate_batch([WARM_GRID[0]], n_stores=N)[0]
+        assert res == _oracle([WARM_GRID[0]])[0]
     except RuntimeError:
         pass                                # or failed deterministically
     with pytest.raises(RuntimeError):

@@ -6,9 +6,9 @@ the ``cells`` mesh (wv row ``r`` owned by shard ``r % n_shards`` at
 local index ``r // n_shards``), scan lanes are scheduled into their
 owner shard's slot block by ``plan_tiles(owners=...)``, and the in-jit
 gather runs against shard-resident rows only -- while every answer
-stays bit-identical (``==``) to the replicated layout, the blocked
-batch, and the serial oracle, for ragged mixed-SB grids with the
-contention and directory axes on. Measured resident device bytes
+stays bit-identical (``==``) to the replicated layout and the serial
+oracle, for ragged mixed-SB grids with the contention and directory
+axes on. Measured resident device bytes
 (``bank_stats()``) must actually drop to ~1/n_shards.
 """
 
@@ -26,7 +26,7 @@ from repro.core.simulator import (
     ScenarioSpec,
     bank_row_maps,
     clear_sim_caches,
-    simulate_batch,
+    simulate_spec,
     sub_bank_rows,
 )
 
@@ -70,9 +70,9 @@ def ragged_grids(draw):
 @settings(max_examples=6, deadline=None)
 @given(ragged_grids())
 def test_sub_bank_bitident_across_shards_planes_partitions(grid):
-    """Differential core: sub vs replicated vs stacked vs the blocked
-    oracle, at 1 and 8 shards, on ragged contention/directory grids."""
-    oracle = simulate_batch(grid, n_stores=N)
+    """Differential core: sub vs replicated vs the serial oracle, at 1
+    and 8 shards, on ragged contention/directory grids."""
+    oracle = [simulate_spec(s, n_stores=N) for s in grid]
     for n_shards in SHARD_COUNTS:
         sub = E.run_grid(grid, n_stores=N, tile_cells=16,
                          n_shards=n_shards)
@@ -81,22 +81,19 @@ def test_sub_bank_bitident_across_shards_planes_partitions(grid):
         rep = E.run_grid(grid, n_stores=N, tile_cells=16,
                          n_shards=n_shards, bank_partition="replicated")
         _assert_bit_identical(rep, oracle, ("replicated", n_shards))
-        stacked = E.run_grid(grid, n_stores=N, tile_cells=16,
-                             n_shards=n_shards, data_plane="stacked")
-        _assert_bit_identical(stacked, oracle, ("stacked", n_shards))
 
 
 def test_plan_tiles_owner_partitioning():
     """The owner-aware scheduler must place every lane exactly once, in
     its owning shard's slot block, with per-tile padded shapes still
-    canonical (b_pad divisible by n_shards)."""
+    full-size (b_pad divisible by n_shards)."""
     n_shards = 4
     specs = [ScenarioSpec(w, c, seed=s)
              for w in WORKLOAD_POOL for c in CONFIGS for s in (0, 1)]
     rng = np.random.default_rng(0)
     owners = [int(rng.integers(n_shards)) for _ in specs]
     tiles = E.plan_tiles(specs, n_stores=N, tile_cells=16,
-                         n_shards=n_shards, small_pad=False, owners=owners)
+                         n_shards=n_shards, owners=owners)
     seen = sorted(i for t in tiles for i in t.indices)
     assert seen == list(range(len(specs)))
     for t in tiles:
@@ -111,10 +108,10 @@ def test_plan_tiles_owner_partitioning():
             assert pos // per == owners[i], (i, pos, per)
     # owners=None (or one shard) keeps the legacy identity layout
     legacy = E.plan_tiles(specs, n_stores=N, tile_cells=16,
-                          n_shards=n_shards, small_pad=False)
+                          n_shards=n_shards)
     assert all(t.slots is None for t in legacy)
     single = E.plan_tiles(specs, n_stores=N, tile_cells=16, n_shards=1,
-                          small_pad=False, owners=[0] * len(specs))
+                          owners=[0] * len(specs))
     assert all(t.slots is None for t in single)
 
 

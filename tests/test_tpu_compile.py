@@ -57,7 +57,7 @@ def topo():
 
 
 def _bank_sig(sig, bank_shape):
-    return dataclasses.replace(sig, data_plane="bank", bank_shape=bank_shape,
+    return dataclasses.replace(sig, bank_shape=bank_shape,
                                bank_sub=True)
 
 
@@ -87,7 +87,7 @@ def _mega_sigs(n_shards):
     trace_map, wv_map = bank_row_maps(specs, PAPER_CLUSTER)
     shape = (len(trace_map), sub_bank_rows(len(wv_map), n_shards))
     tiles = engine.plan_tiles(
-        specs, n_stores=N_STORES, n_shards=n_shards, small_pad=False,
+        specs, n_stores=N_STORES, n_shards=n_shards,
         tile_cells=engine._default_tile_cells(N_STORES))
     return {t.sig.sb_uniform: _bank_sig(t.sig, shape) for t in tiles}
 
@@ -108,7 +108,7 @@ def test_serving_capacity_tile_compiles_for_v5e(topo):
     shape = (_row_capacity(len(trace_map), SERVE_ROW_PAD),
              _row_capacity(len(wv_map), SERVE_ROW_PAD))
     tiles = engine.plan_tiles(warm_grid, n_stores=N_STORES,
-                              tile_cells=SERVE_BATCH_CELLS, small_pad=False)
+                              tile_cells=SERVE_BATCH_CELLS)
     sigs = {_bank_sig(t.sig, shape) for t in tiles}
     assert {s.sb_uniform for s in sigs} == {72, 48}
     one_chip = SingleDeviceSharding(topo.devices[0])

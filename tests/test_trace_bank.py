@@ -1,13 +1,12 @@
 """Property tests for the columnar trace-bank data plane.
 
 The bank contract (simulator.py "columnar trace-bank data plane"):
-gathering a cell's columns out of the bank must reconstruct the stacked
+gathering a cell's columns out of the bank must reconstruct its
 per-cell inputs **bit-exactly** -- arrivals verbatim, and the host-
 precollapsed ``(w, v, pr_nc)`` columns equal to the device
-``_blocked_precompute`` of the stacked arrays -- for arbitrary ragged
+``_blocked_precompute`` of the per-cell arrays -- for arbitrary ragged
 mixed-SB grids; and ``clear_sim_caches()`` must drop the bank cache
-including its device placements (no leaked device buffers across
-engine switches).
+including its device placements (no leaked device buffers).
 """
 
 import dataclasses
@@ -29,7 +28,7 @@ from repro.core.simulator import (
     ScenarioSpec,
     clear_sim_caches,
     get_trace_bank,
-    simulate_batch,
+    simulate_spec,
 )
 
 N = 700                                 # N % 72 != 0: ragged store tail
@@ -96,7 +95,7 @@ def test_cell_scalars_equal_prepared_cell_fields(grid):
     """The bank plane's scalar-only record (``_cell_scalars``) is ``==``
     to the same fields of ``_prepare_cell``'s full inputs, and its
     coalesced-store count agrees with the per-store coalesce array the
-    stacked plane scans; the per-trace memo holds one entry per (trace,
+    serial oracle scans; the per-trace memo holds one entry per (trace,
     coalescing class)."""
     specs = SCALAR_GRIDS[grid]
     clear_sim_caches()
@@ -125,8 +124,13 @@ def test_bank_gather_reconstructs_stacked_inputs(specs):
     cells = [S._prepare_cell(
         s, S._trace_cached(s.workload, N, s.seed, PAPER_CLUSTER), N,
         PAPER_CLUSTER) for s in specs]
-    np_args, _, _, _ = S._stack_cells(cells)
-    arrivals, coalesce, exposed, t_repl_i, svc_i, config_idx, _ = np_args
+    # the per-cell inputs stacked time-major (n, B), as the device
+    # precompute takes them
+    arrivals, coalesce, exposed, t_repl_i, svc_i = (
+        np.stack([getattr(c, f) for c in cells], axis=1)
+        for f in ("arrivals", "coalesce", "exposed", "t_repl_i", "svc_i"))
+    config_idx = np.asarray([S._CONFIG_IDX[c.spec.config] for c in cells],
+                            np.int32)
     costs = S._commit_cost_ns("proactive", PAPER_CLUSTER)
     w_dev, v_dev, p_dev = S._blocked_precompute(
         jnp.asarray(coalesce), jnp.asarray(exposed), jnp.asarray(t_repl_i),
@@ -134,14 +138,12 @@ def test_bank_gather_reconstructs_stacked_inputs(specs):
         costs["t_l1"], costs["t_wt"])
 
     bank = get_trace_bank(specs, N)
-    n_pad = S._pad_len(len(cells))
-    padded = cells + [cells[0]] * (n_pad - len(cells))
-    rows = [bank.rows_for(c.spec) for c in padded]
+    rows = [bank.rows_for(c.spec) for c in cells]
     tr = np.asarray([r[0] for r in rows])
     wv = np.asarray([r[1] for r in rows])
 
     # arrivals verbatim; w/v/pr_nc: host precollapse == device precompute
-    # (stacked arrays are time-major (n, B); bank rows store-contiguous)
+    # (the stack is time-major (n, B); bank rows store-contiguous)
     assert np.array_equal(bank.arrivals[tr], arrivals.T)
     assert np.array_equal(bank.w[wv], np.asarray(w_dev).T)
     assert np.array_equal(bank.v[wv], np.asarray(v_dev).T)
@@ -154,13 +156,13 @@ def test_bank_gather_reconstructs_stacked_inputs(specs):
 @settings(max_examples=6, deadline=None)
 @given(ragged_grids())
 def test_banked_engines_match_stacked_on_random_grids(specs):
-    want = simulate_batch(specs, n_stores=N, data_plane="stacked")
-    got_batch = simulate_batch(specs, n_stores=N)            # banked
-    got_stream = E.run_grid(specs, n_stores=N, tile_cells=16)  # banked
-    for a, b, c in zip(got_batch, got_stream, want):
+    """The engine over the bank ``==`` the serial oracle over the
+    per-cell arrays, on random ragged grids."""
+    got = E.run_grid(specs, n_stores=N, tile_cells=16, n_shards=1)
+    for s, b in zip(specs, got):
+        want = simulate_spec(s, n_stores=N)
         for f in FLOAT_FIELDS:
-            assert getattr(a, f) == getattr(c, f), (a.meta, f)
-            assert getattr(b, f) == getattr(c, f), (b.meta, f)
+            assert getattr(b, f) == getattr(want, f), (b.meta, f)
 
 
 def test_clear_sim_caches_drops_bank_device_buffers():
@@ -179,49 +181,9 @@ def test_clear_sim_caches_drops_bank_device_buffers():
     clear_sim_caches()
     gc.collect()
     assert len(S._BANK_CACHE) == 0
-    assert len(S._BANKED_INPUT_CACHE) == 0
     assert len(S._WV_ROW_CACHE) == 0
     assert buf_ref() is None, "bank device buffer leaked past cache clear"
     assert host_ref() is None, "bank host columns leaked past cache clear"
-
-
-def test_bank_rows_are_shared_across_engines():
-    """simulate_batch and run_grid on the same grid must resolve ONE
-    bank object (the digest-keyed memo -- one upload per placement)."""
-    specs = [ScenarioSpec("ycsb", c, seed=s) for c in CONFIGS
-             for s in (0, 1)]
-    simulate_batch(specs, n_stores=N)
-    bank_a = get_trace_bank(specs, N)
-    E.run_grid(specs, n_stores=N, tile_cells=16)
-    assert get_trace_bank(specs, N) is bank_a
-
-
-def test_oneshot_lane_dedup_drops_h2d_and_gather_width():
-    """The one-shot banked tier no longer gathers the full (n_stores, B)
-    batch: cells sharing a (SB, trace, max-plus row) lane are scanned
-    once (here the whole CN axis collapses to 2 lanes for 20 cells), so
-    the shipped index bytes -- and the device gather/scan width -- drop
-    from padded cells to padded lanes, bit-identically."""
-    specs = [ScenarioSpec("ycsb", c, n_cns=ncn)
-             for c in ("wb", "proactive")
-             for ncn in (16, 12, 8, 6, 4, 3, 2, 1, 24, 32)]
-    out = simulate_batch(specs, n_stores=N)
-    want = simulate_batch(specs, n_stores=N, data_plane="stacked")
-    for a, b in zip(out, want):
-        for f in FLOAT_FIELDS:
-            assert getattr(a, f) == getattr(b, f), f
-    meta = out[0].meta
-    assert meta["scan_lanes"] == 2                 # one per config
-    bank = get_trace_bank(specs, N)
-    # pre-dedup accounting: 3 int32 vectors over the padded CELL count
-    old_h2d = bank.nbytes + 3 * 4 * S._pad_len(len(specs))
-    new_h2d = bank.nbytes + 3 * 4 * S._pad_len(2)
-    assert meta["h2d_bytes"] == new_h2d < old_h2d
-    # a grid with all-distinct lanes keeps lane count == cell count
-    uniq = [ScenarioSpec(w, "proactive", seed=s)
-            for w in WORKLOAD_POOL for s in (0, 1)]
-    (r, *_) = simulate_batch(uniq, n_stores=N)
-    assert r.meta["scan_lanes"] == len(uniq)
 
 
 @settings(max_examples=10, deadline=None)
