@@ -387,8 +387,10 @@ def fetch_wv_row(dev: tuple, r: int, *, n_shards: int,
 def verify_rows(bank, dev: tuple, rows: Sequence[int], *, n_shards: int,
                 local_cap: int = 0, where: str = "") -> None:
     """Gather-path integrity check: CRC-compare the device-resident
-    primary copy of each global wv row in ``rows`` against the host
-    bank's columns.  Raises :class:`IntegrityError` listing every bad
+    primary copy of each global wv row in ``rows`` against the row's
+    host truth (``bank.host_row``: collapsed on the host by
+    ``simulator._make_wv_row``, without materializing the bank's
+    planes).  Raises :class:`IntegrityError` listing every bad
     row.  Cost is one row readback per checked row -- callers sample
     (the rows the next tile will gather, capped)."""
     bad = []
@@ -396,7 +398,7 @@ def verify_rows(bank, dev: tuple, rows: Sequence[int], *, n_shards: int,
         if not 0 <= r < bank.wv_rows:
             continue
         got = fetch_wv_row(dev, r, n_shards=n_shards, local_cap=local_cap)
-        want = (bank.w[r], bank.v[r], bank.pr_nc[r])
+        want = bank.host_row(r)
         if any(row_digest(g) != row_digest(h) for g, h in zip(got, want)):
             bad.append(r)
     if bad:
@@ -487,8 +489,8 @@ def _verify_rebuild(bank, rebuilt: Dict[str, np.ndarray], lost: int,
                     n_shards: int) -> None:
     rows = owned_rows(lost, n_shards, bank.wv_rows)
     bad = [r for i, r in enumerate(rows)
-           if any(row_digest(rebuilt[name][i]) !=
-                  row_digest(getattr(bank, name)[r])
-                  for name in ("w", "v", "pr_nc"))]
+           if any(row_digest(rebuilt[name][i]) != row_digest(want)
+                  for name, want in zip(("w", "v", "pr_nc"),
+                                        bank.host_row(r)))]
     if bad:
         raise IntegrityError(bad, "rebuilt rows fail digests")

@@ -11,9 +11,10 @@ mega-grid of Figs. 10/16-18 -- and returns results ``==`` the serial
    its outputs are scattered to the member cells (the 12 960-cell
    mega-grid scans ~2 700 lanes). The grid's
    :class:`~repro.core.simulator.TraceBank` holds each unique arrivals
-   row and each host-collapsed ``(w, v, pr_nc)`` max-plus row once; it
-   is uploaded once per grid and stays device-resident, and tiles ship
-   only two ``int32`` row-index vectors. The tile program gathers its
+   row and each ``(w, v, pr_nc)`` max-plus row once; placement uploads
+   its row table once per grid and collapses the rows on the device,
+   where they stay resident, and tiles ship only two ``int32``
+   row-index vectors. The tile program gathers its
    rows in-jit (``_bank_gather``) and runs the blocked scan
    (``_scan_wv``).
 
@@ -53,8 +54,8 @@ Two notes on axes and threads:
 * **Coupled axes ride the plane keys.** Lane and bank-row dedup both
   key on ``simulator._plane_keys``, which already appends the resolved
   ``ContentionParams`` / ``DirectoryParams`` tails for coupled cells
-  (the two-level directory recurrence is folded into the wv row on the
-  host, before the bank ever sees it). The engine therefore needs no
+  (the two-level directory recurrence is a host-built delay row of the
+  bank's row table, folded into the wv row by the device collapse). The engine therefore needs no
   knowledge of either axis: coupled cells that share a (shard,
   epoch-profile) still collapse to one scan lane, and axis-off grids
   produce byte-identical keys -- and rows -- to grids without the axes.
@@ -91,6 +92,7 @@ from repro.core.chaos import (
 )
 from repro.core.retry import PLACEMENT_RETRY, retry_call
 from repro.core.simulator import (
+    COLLAPSE_PROGRAMS,
     ScenarioSpec,
     SimResult,
     TraceBank,
@@ -102,12 +104,16 @@ from repro.core.simulator import (
     _scan_wv,
     auto_chunk,
     bank_row_maps,
+    collapse_products,
+    collapse_sums,
     get_trace_bank,
     register_cache_clearer,
     sub_bank_rows,
 )
 from repro.distributed.context import cells_mesh, shard_map
 from repro.distributed.sharding import (
+    BANK_COLUMN_SPEC,
+    SUB_BANK_SPEC,
     bank_shardings,
     bank_tile_specs,
     index_shardings,
@@ -407,7 +413,7 @@ def _build_bank_tile_fn(sig: TileSignature) -> Callable:
             w_bank, v_bank, p_bank = w_bank[0], v_bank[0], p_bank[0]
         # the gather (one row memcpy per lane + a cheap device
         # transpose) -- and NO per-tile precompute: w/v were collapsed
-        # on the host, once per unique row
+        # at placement, once per unique row
         a, w, v, p = _bank_gather(a_bank, w_bank, v_bank, p_bank,
                                   trace_idx, wv_idx)
         return _scan_wv(a, w, v, p, sig.chunk, sig.sb_uniform)
@@ -440,9 +446,38 @@ def _tile_fn(sig: TileSignature) -> Callable:
 tile_fn = _tile_fn
 
 
+#: The device row collapse shard_mapped over each mesh size (one shard
+#: runs ``simulator.COLLAPSE_PROGRAMS`` as they are).
+_COLLAPSE_FNS: Dict[int, Tuple[Callable, Callable]] = {}
+
+
+def _collapse_programs(n_shards: int) -> Tuple[Callable, Callable]:
+    """The two collapse programs for an ``n_shards`` mesh: the row
+    table's laid-out ``ints`` / ``scal`` and the planes they build are
+    partitioned like the sub-bank stacks (``SUB_BANK_SPEC``), the
+    per-trace columns and the extra plane replicated, so each shard
+    builds exactly its own local rows with no communication."""
+    if n_shards == 1:
+        return COLLAPSE_PROGRAMS
+    fns = _COLLAPSE_FNS.get(n_shards)
+    if fns is None:
+        mesh = cells_mesh(n_shards)
+        col, sub = BANK_COLUMN_SPEC, SUB_BANK_SPEC
+        fns = _COLLAPSE_FNS.setdefault(n_shards, (
+            jax.jit(shard_map(collapse_products, mesh,
+                              in_specs=(col,) * 3 + (sub,) * 2,
+                              out_specs=(sub,) * 2)),
+            jax.jit(shard_map(collapse_sums, mesh,
+                              in_specs=(sub,) * 2 + (col,) * 3 + (sub,) * 2,
+                              out_specs=(sub,) * 3),
+                    donate_argnums=(0, 1))))
+    return fns
+
+
 @register_cache_clearer
 def _clear_engine_caches() -> None:
     _TILE_FNS.clear()
+    _COLLAPSE_FNS.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -497,42 +532,43 @@ def _place_bank(bank: TraceBank, n_shards: int) -> Tuple[int, tuple]:
 def _place_sub_bank(bank: TraceBank, n_shards: int,
                     k_replicas: int = 1) -> Tuple[int, tuple]:
     """Device-resident PER-SHARD sub-bank (``bank_partition="sub"``,
-    the default): arrivals replicated as in :func:`_place_bank` (tiny
-    -- ~1% of the bank's bytes -- and a lane's trace row may be owned
-    by a different shard than its wv row), the three max-plus planes
-    shard-partitioned via ``TraceBank.sub_bank_host`` -- ONE copy of
-    each wv row fleet-wide, so resident device bytes drop to
-    ~``1/n_shards`` of the replicated layout. At one shard that layout
-    is the identity, so the bank's own host columns are uploaded as
-    they are, with no host re-layout copy. The sub stacks
-    ``device_put`` straight to their sharded layout (each device
-    receives only its slice: host->device bytes stay at bank scale,
-    no fabric replication); only the arrivals staging replicates.
+    the default), collapsed on the device: the host ships the
+    :meth:`TraceBank.collapse_inputs` -- arrivals, per-trace columns
+    and the extra delay / flush plane replicated as in
+    :func:`_place_bank` (staged once to device 0, then copied over the
+    fabric), the row table laid out and shard-partitioned like the
+    stacks -- and the two collapse programs build each shard's local
+    ``(w, v, pr_nc)`` rows where they are used (``bank/collapse``). The
+    planes come out in the layout and shardings the tile programs
+    take, ONE copy of each wv row fleet-wide, so resident device bytes
+    are ~``1/n_shards`` of the replicated layout. Host->device bytes
+    are the inputs', about 2% of the planes' on the mega-grid.
     Memoized on the bank like :func:`_place_bank`.
 
-    ``k_replicas > 1`` (chaos/recovery runs only) places the
+    ``k_replicas > 1`` (chaos/recovery runs only) builds the
     :meth:`TraceBank.sub_bank_host` Replica-set layout: each shard's
     stack carries ``k`` local-row blocks, block ``j`` holding the rows
     owned by shard ``(s - j) % n_shards`` -- single-shard loss then
     never loses a row (``chaos.replica_rebuild``). Gathers still target
     block 0, so the compiled programs only see the wider local axis."""
+    programs = _collapse_programs(n_shards)
     if n_shards == 1:
         def place1(host: tuple) -> tuple:
             # same commitment as the memo's default path -- the hook is
-            # the only addition, so shardings (and jit keys) match PR-8
-            _h2d_hook(sum(int(x.nbytes) for x in host))
-            return tuple(jax.numpy.asarray(x) for x in host)
-        return bank.sub_device_args(1, place1, k_replicas)
+            # the only addition
+            _h2d_hook(sum(int(x.nbytes) for x in jax.tree.leaves(host)))
+            return jax.tree.map(jax.numpy.asarray, host)
+        return bank.sub_device_args(1, place1, k_replicas, programs)
     mesh = cells_mesh(n_shards)
 
     def place(host: tuple) -> tuple:
-        _h2d_hook(sum(int(x.nbytes) for x in host))
-        a = jax.device_put(host[0], jax.devices()[0])     # host -> dev0
-        a = jax.device_put(a, bank_shardings(mesh)[0])    # dev -> dev
-        subs = jax.device_put(tuple(host[1:]), sub_bank_shardings(mesh))
-        return (a,) + tuple(subs)
+        _h2d_hook(sum(int(x.nbytes) for x in jax.tree.leaves(host)))
+        shared = jax.device_put(host[:6], jax.devices()[0])  # host -> dev0
+        shared = jax.device_put(shared, bank_shardings(mesh)[0])  # dev -> dev
+        tables = jax.device_put(host[6:], sub_bank_shardings(mesh)[0])
+        return tuple(shared) + tuple(tables)
 
-    return bank.sub_device_args(n_shards, place, k_replicas)
+    return bank.sub_device_args(n_shards, place, k_replicas, programs)
 
 
 def _measured_device_bytes(arrays: Sequence[jax.Array]) -> Tuple[int, int]:
@@ -906,10 +942,11 @@ def _run_grid(specs: Sequence[ScenarioSpec], cluster: ClusterConfig,
             bank_fresh, bank_dev = _retried(
                 lambda: _place_sub_bank(bank, n_shards, k_eff),
                 "bank placement")
-            # only the replicated arrivals staging crosses the
-            # device fabric; the partitioned max-plus stacks ship
-            # each shard's slice straight from the host
-            fabric_bytes += (bank.arrivals.nbytes * (n_shards - 1)
+            # only the replicated collapse inputs (arrivals, trace
+            # columns, extra plane) cross the device fabric; the
+            # partitioned row table ships each shard's slice straight
+            # from the host, and the planes are built in place
+            fabric_bytes += (bank.shared_nbytes * (n_shards - 1)
                              if bank_fresh else 0)
         else:
             bank_fresh, bank_dev = _retried(

@@ -106,6 +106,8 @@ from repro.core.simulator import (
     _finish_result,
     _plane_keys,
     get_trace_bank,
+    sub_bank_layout,
+    take_rows,
 )
 from repro.distributed.context import cells_mesh
 from repro.distributed.sharding import bank_shardings, sub_bank_shardings
@@ -382,14 +384,8 @@ class ScenarioServer:
         ``r`` is resident on shards ``r % n`` AND ``(r % n + 1) % n``
         and one lost shard never loses a row. Gathers (and the compiled
         programs' shapes at ``k=1``) only ever touch block 0."""
-        n = self.n_shards
-        out = np.zeros((n, self.k_replicas * cap) + col.shape[1:],
-                       col.dtype)
-        for s in range(n):
-            for j in range(self.k_replicas):
-                rows = col[(s - j) % n::n]
-                out[s, j * cap:j * cap + rows.shape[0]] = rows
-        return out
+        return take_rows(col, sub_bank_layout(
+            col.shape[0], self.n_shards, self.k_replicas, cap))
 
     def _splice(self, dev, rows: np.ndarray, r0: int):
         """Splice ``rows`` into the replicated capacity array at row

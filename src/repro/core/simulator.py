@@ -90,10 +90,14 @@ every cell of one trace and most cells share their derivations. The
 * one ``(w, v, pr_nc)`` column per unique *max-plus row key* --
   ``(config-rule, workload, seed, N_r, bw, coalescing)``, with the
   constant WB/WT rules collapsing to a single constant column each.
-  The max-plus collapse is applied **on the host, once per unique
-  row** (IEEE add/max/select are exactly defined, so host numpy and
-  XLA produce identical bits), so the device never re-derives
-  ``w``/``v`` per cell;
+  The max-plus collapse runs **once per unique row, on the device at
+  placement** (:func:`collapse_planes`, from the bank's
+  :class:`RowTable`: per-trace columns, per-row scalars, host-built
+  delay / flush rows), bit for bit the host collapse
+  :func:`_make_wv_row` (IEEE add/max/select are exactly defined, and
+  the two collapse programs keep every multiply out of the program
+  with the adds), so the device never re-derives ``w``/``v`` per
+  cell and the planes never cross host->device;
 * cells carry only two ``int32`` row indices; the tile program gathers
   its columns on device (:func:`_bank_gather`), and the engine keeps
   one device-resident bank per grid;
@@ -183,6 +187,7 @@ from repro.core.contention import (
     contention_arrays,
     contention_memo_misses,
     resolve_contention,
+    schedule_flush_ns,
 )
 from repro.core.directory import (
     DirectoryParams,
@@ -561,16 +566,29 @@ def _directory_delay_row(arrivals: np.ndarray, tx_mask: np.ndarray,
     return np.where(tx_mask, delay, 0.0).astype(np.float32)
 
 
-def _make_cell_arrays(workload: str, n_stores: int, seed: int,
-                      cluster: ClusterConfig, nr: int, bw: float,
-                      replicating: bool, coalesce_on: bool,
-                      contention: Optional[ContentionParams] = None,
-                      directory: Optional[DirectoryParams] = None
-                      ) -> _CellArrays:
+@dataclasses.dataclass(frozen=True)
+class _RowScalars:
+    """The per-row scalars of one replicating max-plus row: every
+    per-store term of :func:`_make_cell_arrays` is an elementwise
+    function of the trace's arrays and these. Python floats, the exact
+    values the host expressions use; the device collapse rounds each
+    to f32 once, as numpy does when it meets them."""
+    congestion: float
+    qslope: float                    # queue ramp per burst position (>= 0)
+    qcap: float                      # SRAM backpressure bound (ns)
+    t_repl_base: float               # congestion- and port-scaled REPL
+    svc_floor: float                 # drain floor inside bursts
+    t_drain: float                   # drain service outside bursts
+
+
+def _row_scalars(ts: _TraceScalars, workload: str, cluster: ClusterConfig,
+                 nr: int, bw: float, replicating: bool) -> _RowScalars:
+    """The one derivation of a row's congestion and REPL / drain
+    scalars from its trace's scalars ``ts``, for the host arrays
+    (:func:`_make_cell_arrays`) and the row table of the device
+    collapse (:func:`_row_recipes`)."""
     wl = WORKLOADS[workload]
-    trace = _trace_cached(workload, n_stores, seed, cluster)
     costs = _commit_cost_ns("proactive", cluster)   # config-independent
-    ts = _make_trace_scalars(workload, n_stores, seed, cluster, coalesce_on)
 
     # --- replication fan-out cost scaling -------------------------------
     # N_r REPLs leave in parallel but share the CN's CXL port: serialization
@@ -584,10 +602,6 @@ def _make_cell_arrays(workload: str, n_stores: int, seed: int,
     congestion = max(1.0, total_demand / bw)
     port_serial = 1.0 + 0.08 * (nr - 1)
 
-    coalesce = trace["coalesce"] if coalesce_on else \
-        np.zeros_like(trace["coalesce"])
-    exposed = trace["exposed_coh"] * congestion
-
     # Per-store REPL latency: inflated inside cluster-wide bursts (the
     # SPMD apps' flush phases align across CNs, so every Logging Unit is
     # absorbing its peers' REPL streams at once). The ACK backlog ramps
@@ -600,16 +614,36 @@ def _make_cell_arrays(workload: str, n_stores: int, seed: int,
     dram_svc_ns = 4.0 * cluster.dram_lat_ns
     qslope = (svc_entry_ns * cores * nr * (1.0 - wl.coalesce_rate)
               - cluster.cycle_ns)
-    qcap = 195.0                 # SRAM buffer backpressure bound (ns)
-    queue_i = np.minimum(trace["burst_pos"] * max(qslope, 0.0), qcap) \
+    return _RowScalars(
+        congestion=congestion,
+        qslope=max(qslope, 0.0),
+        qcap=195.0,
+        t_repl_base=costs["t_repl"] * congestion * port_serial,
+        # commit-drain service floor inside bursts (proactive path)
+        svc_floor=dram_svc_ns * (1.0 - wl.coalesce_rate) * congestion
+        * (1.0 + 0.1 * (nr - cluster.n_replicas)),
+        t_drain=costs["t_drain"])
+
+
+def _make_cell_arrays(workload: str, n_stores: int, seed: int,
+                      cluster: ClusterConfig, nr: int, bw: float,
+                      replicating: bool, coalesce_on: bool,
+                      contention: Optional[ContentionParams] = None,
+                      directory: Optional[DirectoryParams] = None
+                      ) -> _CellArrays:
+    trace = _trace_cached(workload, n_stores, seed, cluster)
+    ts = _make_trace_scalars(workload, n_stores, seed, cluster, coalesce_on)
+    sc = _row_scalars(ts, workload, cluster, nr, bw, replicating)
+    congestion = sc.congestion
+
+    coalesce = trace["coalesce"] if coalesce_on else \
+        np.zeros_like(trace["coalesce"])
+    exposed = trace["exposed_coh"] * congestion
+    queue_i = np.minimum(trace["burst_pos"] * sc.qslope, sc.qcap) \
         * trace["in_burst"] * congestion
-    t_repl_base = costs["t_repl"] * congestion * port_serial
-    t_repl_i = t_repl_base + queue_i
-    # commit-drain service floor inside bursts (proactive path)
-    svc_floor = dram_svc_ns * (1.0 - wl.coalesce_rate) * congestion \
-        * (1.0 + 0.1 * (nr - cluster.n_replicas))
-    svc_i = np.where(trace["in_burst"], svc_floor,
-                     costs["t_drain"]).astype(np.float32)
+    t_repl_i = sc.t_repl_base + queue_i
+    svc_i = np.where(trace["in_burst"], sc.svc_floor,
+                     sc.t_drain).astype(np.float32)
 
     if contention is not None:
         # conflict backoff + sharer invalidations delay the coherence
@@ -784,16 +818,64 @@ def sub_bank_rows(rows: int, n_shards: int) -> int:
     return max(1, -(-rows // n_shards))
 
 
+def sub_bank_layout(rows: int, n_shards: int, k_replicas: int = 1,
+                    cap: Optional[int] = None) -> np.ndarray:
+    """The sub-bank layout as a row-index table: ``(n_shards,
+    k_replicas * cap)`` global wv rows, ``-1`` where the slot is a zero
+    padding row. ``cap`` (local rows per replica block) defaults to
+    :func:`sub_bank_rows`. Block ``j`` of shard ``s`` holds, at local
+    row ``q``, global row ``q * n_shards + (s - j) % n_shards`` -- the
+    rows owned by shard ``(s - j) % n_shards`` -- so block 0 is the
+    shard's own sub-bank and, with ``k_replicas >= 2``, row ``r`` is
+    also resident on shard ``(r % n + 1) % n``. The one statement of
+    the layout: the host stacks (:meth:`TraceBank.sub_bank_host`, the
+    serving daemon's capacity stacks) and the device collapse
+    (:meth:`TraceBank.collapse_inputs`) all read it."""
+    cap = sub_bank_rows(rows, n_shards) if cap is None else cap
+    s = np.arange(n_shards)[:, None, None]
+    j = np.arange(k_replicas)[None, :, None]
+    q = np.arange(cap)[None, None, :]
+    r = q * n_shards + (s - j) % n_shards
+    return np.where(r < rows, r, -1).reshape(n_shards, k_replicas * cap)
+
+
+def take_rows(col: np.ndarray, idx: np.ndarray) -> np.ndarray:
+    """``col[idx]`` with zero rows where ``idx`` is ``-1`` (a
+    :func:`sub_bank_layout` padding slot); always a fresh array."""
+    out = np.zeros(idx.shape + col.shape[1:], col.dtype)
+    have = idx >= 0
+    out[have] = col[idx[have]]
+    return out
+
+
+def _coupling_of(wv_key: tuple) -> Tuple[Optional[ContentionParams],
+                                         Optional[DirectoryParams]]:
+    """The coupling params a replicating row key carries. Trailing
+    components are typed, not positional: a key may carry contention,
+    directory params, both (contention first), or neither -- see
+    :func:`_plane_keys`."""
+    con = dirp = None
+    for extra in wv_key[6:]:
+        if isinstance(extra, ContentionParams):
+            con = extra
+        elif isinstance(extra, DirectoryParams):
+            dirp = extra
+    return con, dirp
+
+
 def _make_wv_row(wv_key: tuple, n_stores: int, cluster: ClusterConfig
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """One precollapsed max-plus column: host-side
-    :func:`_blocked_precompute` for a single unique row.
+    """One precollapsed max-plus column, on the host: the host truth of
+    a bank row (the lazily materialized :attr:`TraceBank.w` / ``v`` /
+    ``pr_nc`` planes, the chaos digests) and the reference the device
+    collapse (:func:`collapse_products` / :func:`collapse_sums`) is
+    tested ``==`` against.
 
-    Applies the exact arithmetic of the device precompute -- f32 add /
-    maximum / select are exactly-defined IEEE ops, so numpy and XLA
-    produce identical bits -- once per unique row instead of once per
-    cell. Returns ``(w, v, pr_nc)``, each ``(n_stores,)`` (f32, f32,
-    bool)."""
+    Applies the exact arithmetic of :func:`_blocked_precompute` -- f32
+    add / maximum / select are exactly-defined IEEE ops, so numpy and
+    XLA produce identical bits -- once per unique row instead of once
+    per cell. Returns ``(w, v, pr_nc)``, each ``(n_stores,)`` (f32,
+    f32, bool)."""
     costs = _commit_cost_ns("proactive", cluster)
     t_l1 = np.float32(costs["t_l1"])
     t_wt = np.float32(costs["t_wt"])
@@ -802,15 +884,7 @@ def _make_wv_row(wv_key: tuple, n_stores: int, cluster: ClusterConfig
         w = np.full(n_stores, t_l1 if config == "wb" else t_wt, np.float32)
         return w, w, np.zeros(n_stores, bool)
     _, workload, seed, nr, bw, coalescing = wv_key[:6]
-    # trailing coupling components are typed, not positional: a key may
-    # carry contention, directory params, both (contention first), or
-    # neither -- see _plane_keys
-    con = dirp = None
-    for extra in wv_key[6:]:
-        if isinstance(extra, ContentionParams):
-            con = extra
-        elif isinstance(extra, DirectoryParams):
-            dirp = extra
+    con, dirp = _coupling_of(wv_key)
     arr = _cell_arrays(workload, n_stores, seed, cluster, nr, bw, True,
                        coalescing, contention=con, directory=dirp)
     if config == "baseline":
@@ -830,10 +904,279 @@ def _make_wv_row(wv_key: tuple, n_stores: int, cluster: ClusterConfig
 
 def _wv_row(wv_key: tuple, n_stores: int, cluster: ClusterConfig):
     """Memoized :func:`_make_wv_row` (rows recur across banks and across
-    sweeps of the same grid)."""
-    return _WV_ROW_CACHE.get_or_put(
-        (wv_key, n_stores, cluster),
-        lambda: _make_wv_row(wv_key, n_stores, cluster))
+    sweeps of the same grid). Counter ``bank/wv_rows_built``: rows
+    collapsed on the host, one per memo miss."""
+    def build():
+        _tm.count("bank/wv_rows_built")
+        return _make_wv_row(wv_key, n_stores, cluster)
+
+    return _WV_ROW_CACHE.get_or_put((wv_key, n_stores, cluster), build)
+
+
+# ---------------------------------------------------------------------------
+# Row table and the device collapse
+# ---------------------------------------------------------------------------
+
+#: Rule codes of the row table. A ``_RULE_CONST`` row is its constant
+#: everywhere: WB (``t_l1``), WT (``t_wt``) and, at 0, the zero rows
+#: that pad a sub-bank -- so a zero table row collapses to a zero row.
+_RULE_CONST, _RULE_BASELINE, _RULE_PARALLEL, _RULE_PROACTIVE = range(4)
+_RULE_OF = {"baseline": _RULE_BASELINE, "parallel": _RULE_PARALLEL,
+            "proactive": _RULE_PROACTIVE}
+#: int32 columns of the row table: rule, trace row, coalescing on, and
+#: three rows of the extra plane (contention delay, directory delay,
+#: persist flush; row 0 of the plane is zeros).
+_I_RULE, _I_TRACE, _I_COAL, _I_DELAY, _I_DIR, _I_FLUSH = range(6)
+#: f32 columns: the :class:`_RowScalars` fields in order, then the
+#: row's constant (``t_l1`` of a replicating row's coalesced stores).
+_S_CONG, _S_QSLOPE, _S_QCAP, _S_TREPL, _S_SVC, _S_DRAIN, _S_CONST = range(7)
+#: Rows per upload chunk of the extra plane (see :func:`collapse_planes`).
+EXTRA_CHUNK_ROWS = 8
+
+
+@dataclasses.dataclass(frozen=True)
+class RowTable:
+    """What the device collapse builds a bank's max-plus rows from:
+    each wv row is an elementwise function of its trace's per-store
+    arrays, a few per-row scalars and at most three host-built rows
+    with f64 intermediates (contention delay, directory delay, persist
+    flush), so only those cross host->device -- about 2% of the planes'
+    bytes on the mega-grid.
+
+    The extra plane's rows are deduplicated by value (rows with equal
+    congestion share one), so their number depends on the traces; its
+    device capacity ``extra_cap`` counts them by the row keys alone (a
+    multiple of :data:`EXTRA_CHUNK_ROWS`), so every sweep of one grid
+    structure places the same shapes and compiles nothing new."""
+    coalesce: np.ndarray             # (T, n_stores) bool, per trace row
+    exposed_coh: np.ndarray          # (T, n_stores) f32 ns
+    burst_pos: np.ndarray            # (T, n_stores) f32
+    in_burst: np.ndarray             # (T, n_stores) bool
+    extra: np.ndarray                # (E, n_stores) f32 ns, row 0 zeros
+    extra_cap: int                   # device rows of the extra plane, >= E
+    ints: np.ndarray                 # (P, 6) int32, the _I_* columns
+    scal: np.ndarray                 # (P, 7) f32, the _S_* columns
+
+    def extra_chunks(self) -> Tuple[np.ndarray, ...]:
+        """The extra plane as :data:`EXTRA_CHUNK_ROWS`-row chunks, the
+        last one zero-padded: fixed upload shapes whatever ``E`` is."""
+        c = EXTRA_CHUNK_ROWS
+        e = self.extra.shape[0]
+        chunks = [self.extra[i:i + c] for i in range(0, e - e % c, c)]
+        if e % c:
+            last = np.zeros((c,) + self.extra.shape[1:], self.extra.dtype)
+            last[:e % c] = self.extra[e - e % c:]
+            chunks.append(last)
+        return tuple(chunks)
+
+    @property
+    def shared_nbytes(self) -> int:
+        """Bytes of the per-trace columns and the extra plane's chunks:
+        what every shard of a mesh needs whole."""
+        return sum(int(x.nbytes) for x in (
+            self.coalesce, self.exposed_coh, self.burst_pos,
+            self.in_burst) + self.extra_chunks())
+
+
+class _ExtraRows:
+    """The extra plane of a row table under construction: host-built
+    rows deduplicated by ``key`` (what determines the row's values),
+    row 0 the zero row. ``slot`` names the row by row-key components
+    alone; each slot determines its key, so the slots bound the rows
+    whatever values the traces give."""
+
+    def __init__(self, n_stores: int) -> None:
+        self.index: Dict[tuple, int] = {}
+        self.slots: set = set()
+        self.rows: List[np.ndarray] = [np.zeros(n_stores, np.float32)]
+
+    def add(self, key: tuple, slot: tuple,
+            build: Callable[[], np.ndarray]) -> int:
+        self.slots.add(slot)
+        i = self.index.get(key)
+        if i is None:
+            i = self.index[key] = len(self.rows)
+            self.rows.append(build())
+        return i
+
+    @property
+    def capacity(self) -> int:
+        c = EXTRA_CHUNK_ROWS
+        return -(-(1 + len(self.slots)) // c) * c
+
+
+def _row_recipes(trace_row: Dict[tuple, int], wv_row: Dict[tuple, int],
+                 n_stores: int, cluster: ClusterConfig
+                 ) -> Tuple[List[tuple], List[tuple], "_ExtraRows"]:
+    """One ``(ints, scalars)`` recipe per wv row, in row order, and the
+    extra plane. Each scalar is the Python float the host
+    expression of :func:`_make_cell_arrays` uses (numpy rounds it to
+    f32 as it meets the f32 arrays; the table rounds it once the same
+    way), and the delay / flush rows are the very rows
+    :func:`_make_cell_arrays` adds."""
+    costs = _commit_cost_ns("proactive", cluster)
+    t_l1, t_wt = costs["t_l1"], costs["t_wt"]
+    extra = _ExtraRows(n_stores)
+    trace_scalars: Dict[tuple, _TraceScalars] = {}
+    ints: List[tuple] = []
+    scal: List[tuple] = []
+    for key in wv_row:
+        config = key[0]
+        if config in ("wb", "wt"):
+            ints.append((_RULE_CONST, 0, 0, 0, 0, 0))
+            scal.append((0.0,) * 6 + (t_l1 if config == "wb" else t_wt,))
+            continue
+        _, workload, seed, nr, bw, coalescing = key[:6]
+        con, dirp = _coupling_of(key)
+        ts = trace_scalars.get((workload, seed))
+        if ts is None:
+            ts = trace_scalars[(workload, seed)] = _make_trace_scalars(
+                workload, n_stores, seed, cluster, False)
+        sc = _row_scalars(ts, workload, cluster, nr, bw, True)
+        delay = dir_delay = flush = 0
+        if con is not None:
+            delay = extra.add(
+                ("delay", con, seed, sc.congestion),
+                ("delay", con, workload, seed, nr, bw),
+                lambda: contention_arrays(con, n_stores, seed, cluster,
+                                          sc.congestion)[0])
+            flush = extra.add(
+                ("flush", con.schedule), ("flush", con.schedule),
+                lambda: schedule_flush_ns(con.schedule, n_stores, cluster))
+        if dirp is not None:
+            trace = _trace_cached(workload, n_stores, seed, cluster)
+            coalesce = trace["coalesce"] if coalescing else \
+                np.zeros_like(trace["coalesce"])
+            dir_delay = extra.add(
+                ("directory", workload, seed, coalescing, dirp,
+                 sc.congestion),
+                ("directory", workload, seed, coalescing, dirp, nr, bw),
+                lambda: _directory_delay_row(
+                    np.asarray(trace["arrivals"], np.float32),
+                    ~np.asarray(coalesce, bool), dirp, cluster,
+                    sc.congestion))
+        ints.append((_RULE_OF[config], trace_row[(workload, seed)],
+                     int(bool(coalescing)), delay, dir_delay, flush))
+        scal.append((sc.congestion, sc.qslope, sc.qcap, sc.t_repl_base,
+                     sc.svc_floor, sc.t_drain, t_l1))
+    return ints, scal, extra
+
+
+def _stack_row_table(trace_row: Dict[tuple, int], recipes: tuple,
+                     n_stores: int, cluster: ClusterConfig) -> RowTable:
+    """Stack the per-trace columns, the recipes and the extra plane."""
+    ints, scal, extra = recipes
+    traces = [_trace_cached(w, n_stores, seed, cluster)
+              for (w, seed) in trace_row]
+
+    def col(name: str) -> np.ndarray:
+        return np.stack([t[name] for t in traces], axis=0)
+
+    return RowTable(
+        coalesce=col("coalesce"), exposed_coh=col("exposed_coh"),
+        burst_pos=col("burst_pos"), in_burst=col("in_burst"),
+        extra=np.stack(extra.rows, axis=0), extra_cap=extra.capacity,
+        ints=np.asarray(ints, np.int32).reshape(-1, 6),
+        scal=np.asarray(scal, np.float32).reshape(-1, 7))
+
+
+def collapse_products(exposed_coh: jax.Array, burst_pos: jax.Array,
+                      in_burst: jax.Array, ints: jax.Array,
+                      scal: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """First program of the device collapse: the products of
+    :func:`_make_cell_arrays`, ``exposed_coh * congestion`` and
+    ``min(burst_pos * qslope, qcap) * in_burst * congestion``, in its
+    order, for a laid-out table ``ints`` / ``scal`` of shape ``(S, L,
+    ·)``; returns two ``(S, L, n_stores)`` f32 planes. No add runs
+    here, and no multiply in :func:`collapse_sums`: XLA:CPU contracts a
+    multiply feeding an add inside one program into a fused
+    multiply-add, which rounds once where numpy rounds twice (1 ulp on
+    about a sixth of the mega-grid's rows), and nothing inside one
+    program stops it. Two programs keep every intermediate rounded to
+    f32, as on the host."""
+    tr = ints[..., _I_TRACE]
+    cong = scal[..., _S_CONG, None]
+    exposed = exposed_coh[tr] * cong
+    queue = (jnp.minimum(burst_pos[tr] * scal[..., _S_QSLOPE, None],
+                         scal[..., _S_QCAP, None])
+             * in_burst[tr].astype(jnp.float32) * cong)
+    return exposed, queue
+
+
+def collapse_sums(exposed: jax.Array, queue: jax.Array,
+                  coalesce: jax.Array, in_burst: jax.Array,
+                  extra: jax.Array, ints: jax.Array, scal: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Second program of the device collapse: the adds, maxima and
+    selects of :func:`_make_cell_arrays` and :func:`_make_wv_row`, op
+    for op, over :func:`collapse_products`' planes. An absent delay or
+    flush adds the extra plane's zero row: ``x + 0.0 == x`` for the
+    non-negative latencies here. Returns the ``(S, L, n_stores)``
+    planes ``(w, v, pr_nc)``."""
+    tr = ints[..., _I_TRACE]
+    flush = extra[ints[..., _I_FLUSH]]
+    ex = exposed + extra[ints[..., _I_DELAY]]
+    ex = ex + extra[ints[..., _I_DIR]]
+    t_repl = scal[..., _S_TREPL, None] + queue
+    t_repl = t_repl + flush
+    svc = jnp.where(in_burst[tr], scal[..., _S_SVC, None],
+                    scal[..., _S_DRAIN, None]) + flush
+    coal = coalesce[tr] & (ints[..., _I_COAL, None] != 0)
+    const = scal[..., _S_CONST, None]
+    rule = ints[..., _I_RULE, None]
+    pr_nc = (rule == _RULE_PROACTIVE) & ~coal
+    w = jnp.where(
+        rule == _RULE_BASELINE, jnp.where(coal, const, ex + t_repl),
+        jnp.where(rule == _RULE_PARALLEL,
+                  jnp.where(coal, const, jnp.maximum(ex, t_repl)),
+                  jnp.where(pr_nc, jnp.maximum(t_repl, ex), const)))
+    v = jnp.where(rule == _RULE_PROACTIVE, jnp.where(pr_nc, svc, const), w)
+    return w, v, pr_nc
+
+
+#: The two collapse programs on one device (the engine shard_maps the
+#: same bodies over its mesh). The product planes are donated to
+#: ``w`` / ``v``.
+COLLAPSE_PROGRAMS = (jax.jit(collapse_products),
+                     jax.jit(collapse_sums, donate_argnums=(0, 1)))
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _extra_open(chunk: jax.Array, rows: int) -> jax.Array:
+    """A zero ``(rows, n_stores)`` extra plane holding ``chunk`` at row
+    0 (placed like ``chunk``)."""
+    return jnp.zeros((rows,) + chunk.shape[1:], chunk.dtype) \
+        .at[:chunk.shape[0]].set(chunk)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _extra_write(plane: jax.Array, chunk: jax.Array,
+                 row: jax.Array) -> jax.Array:
+    """``chunk`` written into ``plane`` at ``row`` (a traced offset)."""
+    return jax.lax.dynamic_update_slice(plane, chunk, (row, 0))
+
+
+def collapse_planes(inputs: tuple, extra_cap: int,
+                    products: Callable = COLLAPSE_PROGRAMS[0],
+                    sums: Callable = COLLAPSE_PROGRAMS[1]) -> tuple:
+    """Build the placed max-plus planes from placed
+    :meth:`TraceBank.collapse_inputs`: returns ``(arrivals, w, v,
+    pr_nc)``, the planes shaped as the table. The extra plane's chunks
+    are written into a zero plane of ``extra_cap`` rows
+    (``RowTable.extra_cap``) on the device, so no program's shape
+    depends on how many rows the traces' values gave. Span
+    ``bank/collapse`` covers the writes, both programs and the wait
+    for them."""
+    a, coalesce, exposed_coh, burst_pos, in_burst, chunks, ints, scal = inputs
+    with _tm.span("bank/collapse", rows=int(ints.shape[0] * ints.shape[1])):
+        extra = _extra_open(chunks[0], extra_cap)
+        for i, chunk in enumerate(chunks[1:], 1):
+            extra = _extra_write(extra, chunk, i * EXTRA_CHUNK_ROWS)
+        exposed, queue = products(exposed_coh, burst_pos, in_burst,
+                                  ints, scal)
+        planes = sums(exposed, queue, coalesce, in_burst, extra, ints, scal)
+        jax.block_until_ready(planes)
+    return (a,) + tuple(planes)
 
 
 @dataclasses.dataclass
@@ -847,10 +1190,20 @@ class TraceBank:
     into the scan's time-major layout is a cheap local device op.
     ``arrivals[trace_row[k]]`` is the arrivals row
     of trace key ``k``; ``w / v / pr_nc[wv_row[k]]`` the precollapsed
-    max-plus row of row key ``k``. Host rows are built once per grid
-    (memoized by :func:`get_trace_bank`) and placed on device at most
-    once per placement key (:meth:`device_args`);
-    :func:`clear_sim_caches` drops both.
+    max-plus row of row key ``k``.
+
+    The max-plus rows have two forms. The sweep path places them
+    through :meth:`sub_device_args`, which uploads the
+    :class:`RowTable` (per-trace columns, per-row scalars, the
+    host-built delay / flush rows) and collapses the rows on the device
+    (:func:`collapse_planes`): the planes never exist on the host. The
+    host planes ``w`` / ``v`` / ``pr_nc`` are materialized on first
+    access, row by row through :func:`_make_wv_row`, for
+    the readers that need a host truth (the serving daemon's splices,
+    :meth:`device_args`, :meth:`extend`, the chaos digests; one row:
+    :meth:`host_row`). Both are memoized with the bank by
+    :func:`get_trace_bank` and placed at most once per placement key;
+    :func:`clear_sim_caches` drops them.
 
     Banks are **append-only**: :meth:`extend` adds the rows of new
     specs in first-seen order -- exactly the order a from-scratch build
@@ -865,9 +1218,6 @@ class TraceBank:
     n_stores: int
     cluster: ClusterConfig
     arrivals: np.ndarray             # (T, n_stores) f32 ns
-    w: np.ndarray                    # (P, n_stores) f32 ns
-    v: np.ndarray                    # (P, n_stores) f32 ns
-    pr_nc: np.ndarray                # (P, n_stores) bool
     trace_row: Dict[tuple, int]
     wv_row: Dict[tuple, int]
     _device: Dict[object, tuple] = dataclasses.field(
@@ -876,6 +1226,11 @@ class TraceBank:
     # see enable_journal / ack_journal / replay_journal below)
     _journal: Optional[List[Dict[str, np.ndarray]]] = dataclasses.field(
         default=None, repr=False)
+    # derived from the row keys, built on first use (None = not yet)
+    _planes: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]] = \
+        dataclasses.field(default=None, init=False, repr=False)
+    _table: Optional[RowTable] = dataclasses.field(
+        default=None, init=False, repr=False)
 
     @property
     def trace_rows(self) -> int:
@@ -883,7 +1238,7 @@ class TraceBank:
 
     @property
     def wv_rows(self) -> int:
-        return self.w.shape[0]
+        return len(self.wv_row)
 
     @property
     def n_rows(self) -> int:
@@ -891,9 +1246,64 @@ class TraceBank:
 
     @property
     def nbytes(self) -> int:
-        """Host bytes of all four columns (= H2D bytes of one upload)."""
-        return (self.arrivals.nbytes + self.w.nbytes + self.v.nbytes
-                + self.pr_nc.nbytes)
+        """Bytes of all four columns (= H2D bytes of one
+        :meth:`device_args` upload): arrivals plus 9 bytes a store of
+        each wv row, whether or not the host planes exist yet."""
+        return self.arrivals.nbytes + self.wv_rows * self.n_stores * 9
+
+    def _host_planes(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        if self._planes is None:
+            rows = [_wv_row(k, self.n_stores, self.cluster)
+                    for k in self.wv_row]
+            n = self.n_stores
+            self._planes = tuple(
+                np.stack([r[i] for r in rows], axis=0) if rows
+                else np.zeros((0, n), dt)
+                for i, dt in enumerate((np.float32, np.float32, bool)))
+        return self._planes
+
+    @property
+    def w(self) -> np.ndarray:
+        """(P, n_stores) f32 ns, host truth (materialized on access)."""
+        return self._host_planes()[0]
+
+    @property
+    def v(self) -> np.ndarray:
+        """(P, n_stores) f32 ns, host truth (materialized on access)."""
+        return self._host_planes()[1]
+
+    @property
+    def pr_nc(self) -> np.ndarray:
+        """(P, n_stores) bool, host truth (materialized on access)."""
+        return self._host_planes()[2]
+
+    def host_row(self, r: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Global wv row ``r``'s host truth ``(w, v, pr_nc)``, without
+        materializing the planes."""
+        if self._planes is not None:
+            return tuple(p[r] for p in self._planes)
+        # row indices are assigned in insertion order (bank_row_maps,
+        # extend), so the r-th key is row r's
+        return _wv_row(list(self.wv_row)[r], self.n_stores, self.cluster)
+
+    @property
+    def row_table(self) -> RowTable:
+        """The :class:`RowTable` of the bank's rows (built with the
+        bank, rebuilt on first use after :meth:`extend`)."""
+        if self._table is None:
+            self._table = _stack_row_table(
+                self.trace_row,
+                _row_recipes(self.trace_row, self.wv_row, self.n_stores,
+                             self.cluster),
+                self.n_stores, self.cluster)
+        return self._table
+
+    @property
+    def shared_nbytes(self) -> int:
+        """Bytes a sub-bank placement needs whole on every shard: the
+        arrivals plus the row table's per-trace columns and extra
+        plane."""
+        return int(self.arrivals.nbytes) + self.row_table.shared_nbytes
 
     def rows_for(self, spec: ScenarioSpec) -> Tuple[int, int]:
         """(trace_row, wv_row) indices of one cell of the build grid."""
@@ -903,7 +1313,8 @@ class TraceBank:
     def device_args(self, key: object = 1,
                     place: Optional[Callable[[tuple], tuple]] = None
                     ) -> Tuple[int, tuple]:
-        """Device-resident ``(arrivals, w, v, pr_nc)`` for one placement.
+        """Device-resident ``(arrivals, w, v, pr_nc)`` for one placement,
+        from the host planes.
 
         ``place`` maps the host tuple onto devices (the streaming engine
         passes a replicating ``device_put`` over its ``cells`` mesh);
@@ -941,11 +1352,12 @@ class TraceBank:
     def sub_bank_host(self, n_shards: int, k_replicas: int = 1) -> tuple:
         """Host arrays of the per-shard sub-bank layout: ``(arrivals,
         w_sub, v_sub, pr_nc_sub)`` with the three max-plus planes
-        stacked ``(n_shards, k_replicas * local_rows, n_stores)`` --
-        shard ``s``'s PRIMARY sub-bank (local rows ``[0, local)``) is
-        rows ``s::n_shards`` of the global plane, zero-padded to the
-        widest shard's :func:`sub_bank_rows` count.  Arrivals stay the
-        global 2-D plane (they are replicated on device; see
+        stacked ``(n_shards, k_replicas * local_rows, n_stores)`` by
+        :func:`sub_bank_layout` -- shard ``s``'s PRIMARY sub-bank
+        (local rows ``[0, local)``) is rows ``s::n_shards`` of the
+        global plane, zero-padded to the widest shard's
+        :func:`sub_bank_rows` count.  Arrivals stay the global 2-D
+        plane (they are replicated on device; see
         ``distributed.sharding.SUB_BANK_SPEC``).
 
         ``k_replicas > 1`` appends the paper's **Replica set** along
@@ -960,47 +1372,66 @@ class TraceBank:
         unchanged from the PR-8 layout; the replica blocks cost
         ``(k - 1)/n_shards`` extra resident bytes per max-plus plane.
 
-        At one shard (which forces ``k_replicas=1``) with at least one
-        wv row the layout is the identity: the planes are ``(1, P,
-        n_stores)`` VIEWS of ``w``, ``v`` and ``pr_nc``, not a copy.
-        That is safe because placement copies them to the device,
-        :meth:`extend` replaces the columns rather than writing into
-        them, and chaos tampering touches only the device copy. Every
-        other layout (several shards, replica blocks, the one padding
-        row of an empty plane) is a fresh zero-padded copy."""
+        The host form of what :meth:`sub_device_args` builds on the
+        device. At one shard (which forces ``k_replicas=1``) with at
+        least one wv row the layout is the identity: the planes are
+        ``(1, P, n_stores)`` VIEWS of ``w``, ``v`` and ``pr_nc``, not a
+        copy. Every other layout (several shards, replica blocks, the
+        one padding row of an empty plane) is a fresh zero-padded
+        copy."""
         if not 1 <= k_replicas <= n_shards:
             raise ValueError(f"k_replicas must be in [1, {n_shards}], "
                              f"got {k_replicas}")
         if n_shards == 1 and self.wv_rows:
             return (self.arrivals, self.w[None], self.v[None],
                     self.pr_nc[None])
-        p_loc = sub_bank_rows(self.wv_rows, n_shards)
+        idx = sub_bank_layout(self.wv_rows, n_shards, k_replicas)
+        return (self.arrivals,) + tuple(
+            take_rows(col, idx) for col in (self.w, self.v, self.pr_nc))
 
-        def sub(col: np.ndarray) -> np.ndarray:
-            out = np.zeros((n_shards, k_replicas * p_loc) + col.shape[1:],
-                           col.dtype)
-            for s in range(n_shards):
-                for j in range(k_replicas):
-                    rows = col[(s - j) % n_shards::n_shards]
-                    out[s, j * p_loc:j * p_loc + rows.shape[0]] = rows
-            return out
-
-        return self.arrivals, sub(self.w), sub(self.v), sub(self.pr_nc)
+    def collapse_inputs(self, n_shards: int, k_replicas: int = 1) -> tuple:
+        """Host inputs of the device collapse into the
+        :meth:`sub_bank_host` layout: ``(arrivals, coalesce,
+        exposed_coh, burst_pos, in_burst, extra_chunks, ints, scal)`` --
+        the arrivals and the :class:`RowTable`'s columns whole, its
+        extra plane as a tuple of fixed-shape chunks, its ``ints`` /
+        ``scal`` laid out ``(n_shards, k_replicas * local_rows, ·)`` by
+        :func:`sub_bank_layout`, a padding slot a zero row (a
+        ``_RULE_CONST`` row of constant 0, which collapses to the zero
+        padding row). At one shard the table is a view."""
+        if not 1 <= k_replicas <= n_shards:
+            raise ValueError(f"k_replicas must be in [1, {n_shards}], "
+                             f"got {k_replicas}")
+        t = self.row_table
+        if n_shards == 1 and self.wv_rows:
+            ints, scal = t.ints[None], t.scal[None]
+        else:
+            idx = sub_bank_layout(self.wv_rows, n_shards, k_replicas)
+            ints, scal = take_rows(t.ints, idx), take_rows(t.scal, idx)
+        return (self.arrivals, t.coalesce, t.exposed_coh, t.burst_pos,
+                t.in_burst, t.extra_chunks(), ints, scal)
 
     def sub_device_args(self, n_shards: int,
                         place: Optional[Callable[[tuple], tuple]] = None,
-                        k_replicas: int = 1) -> Tuple[int, tuple]:
+                        k_replicas: int = 1,
+                        programs: Tuple[Callable, Callable] =
+                        COLLAPSE_PROGRAMS) -> Tuple[int, tuple]:
         """Device-resident sub-bank placement (:meth:`sub_bank_host`
-        layout), memoized like :meth:`device_args` under the key
-        ``("sub", n_shards)`` (``("sub", n_shards, k_replicas)`` for a
-        replicated layout, so resilient and plain placements of one
-        bank coexist). Returns ``(bytes_uploaded_now, arrays)``.
-        Growth re-places the whole sub-bank (no diff path: the
-        streaming engine never extends a bank mid-run, and the serving
-        daemon keeps its own capacity-padded device state with
-        per-shard splices). Counter ``bank/layout_bytes``: the host
-        bytes copied to lay the planes out, 0 for the one-shard
-        identity layout, whose views are uploaded as they are."""
+        layout), collapsed on the device: ``place`` puts the
+        :meth:`collapse_inputs` on devices (default: the default
+        device) and :func:`collapse_planes` runs the two collapse
+        ``programs`` over them (the engine passes them shard_mapped
+        over its mesh), returning ``(arrivals, w, v, pr_nc)``. Memoized
+        like :meth:`device_args` under the key ``("sub", n_shards)``
+        (``("sub", n_shards, k_replicas)`` for a replicated layout, so
+        resilient and plain placements of one bank coexist). Returns
+        ``(bytes_uploaded_now, arrays)``: the collapse inputs' bytes,
+        about 2% of the planes'. Growth re-places the whole sub-bank
+        (no diff path: the streaming engine never extends a bank
+        mid-run, and the serving daemon keeps its own capacity-padded
+        device state with per-shard splices). Counter
+        ``bank/rows_device``: the wv rows collapsed on the device,
+        replica copies included."""
         key = ("sub", n_shards) if k_replicas == 1 \
             else ("sub", n_shards, k_replicas)
         entry = self._device.get(key)
@@ -1009,15 +1440,13 @@ class TraceBank:
             rows_placed, dev = entry
             if rows_placed == rows_now:
                 return 0, dev
-        host = self.sub_bank_host(n_shards, k_replicas)
-        _tm.count("bank/layout_bytes", sum(
-            int(x.nbytes) for x, col in zip(host[1:],
-                                            (self.w, self.v, self.pr_nc))
-            if not np.may_share_memory(x, col)))
-        dev = place(host) if place is not None else \
-            tuple(jnp.asarray(x) for x in host)
+        host = self.collapse_inputs(n_shards, k_replicas)
+        _tm.count("bank/rows_device", k_replicas * self.wv_rows)
+        placed = place(host) if place is not None else \
+            jax.tree.map(jnp.asarray, host)
+        dev = collapse_planes(placed, self.row_table.extra_cap, *programs)
         self._device[key] = (rows_now, dev)
-        return sum(int(x.nbytes) for x in host), dev
+        return sum(int(x.nbytes) for x in jax.tree.leaves(host)), dev
 
     def drop_placement(self, key: object) -> None:
         """Forget one memoized device placement (recovery re-admission:
@@ -1085,6 +1514,8 @@ class TraceBank:
         vectors and resident device placements of the old grid all stay
         valid; stale placements are refreshed by the next
         :meth:`device_args` call via a diff upload of just these rows.
+        Host planes already materialized grow by the new rows; the row
+        table is rebuilt on its next use.
 
         Returns ``(new_trace_rows, new_wv_rows)`` -- ``(0, 0)`` when
         every spec's rows were already present. Not thread-safe on its
@@ -1106,19 +1537,19 @@ class TraceBank:
             if wk not in self.wv_row:
                 self.wv_row[wk] = len(self.wv_row)
                 new_wv.append(wk)
+        if new_trace or new_wv:
+            self._table = None
         if new_trace:
             rows = [_trace_cached(w, self.n_stores, seed, self.cluster)
                     ["arrivals"] for (w, seed) in new_trace]
             self.arrivals = np.concatenate(
                 [self.arrivals, np.stack(rows, axis=0)], axis=0)
-        if new_wv:
+        if new_wv and self._planes is not None:
             cols = [_wv_row(k, self.n_stores, self.cluster) for k in new_wv]
-            self.w = np.concatenate(
-                [self.w, np.stack([c[0] for c in cols], axis=0)], axis=0)
-            self.v = np.concatenate(
-                [self.v, np.stack([c[1] for c in cols], axis=0)], axis=0)
-            self.pr_nc = np.concatenate(
-                [self.pr_nc, np.stack([c[2] for c in cols], axis=0)], axis=0)
+            self._planes = tuple(
+                np.concatenate([plane, np.stack([c[i] for c in cols],
+                                                axis=0)], axis=0)
+                for i, plane in enumerate(self._planes))
         if self._journal is not None and (new_trace or new_wv):
             self._journal.append({
                 "arrivals": self.arrivals[t0:].copy(),
@@ -1147,31 +1578,39 @@ def bank_row_maps(specs: Sequence[ScenarioSpec],
 
 def _make_trace_bank(specs: Tuple[ScenarioSpec, ...], n_stores: int,
                      cluster: ClusterConfig) -> TraceBank:
+    """Build a bank: its traces (``bank/synth``), the row table's
+    recipes and host-built delay / flush rows (``bank/rows``), and the
+    stacked arrivals, per-trace columns, table and extra plane
+    (``bank/stack``). No max-plus row is collapsed here: placement
+    collapses them on the device, and the host planes wait for a
+    reader."""
     with _tm.span("bank/build", cells=len(specs)):
         trace_row, wv_row = bank_row_maps(specs, cluster)
         with _tm.span("bank/synth", rows=len(trace_row)):
             a_rows = [_trace_cached(w, n_stores, seed, cluster)["arrivals"]
                       for (w, seed) in trace_row]
-        misses = _WV_ROW_CACHE.misses
         draws0, delays0 = contention_memo_misses()
         with _tm.span("bank/rows", rows=len(wv_row)):
-            wv_rows = [_wv_row(k, n_stores, cluster) for k in wv_row]
+            recipes = _row_recipes(trace_row, wv_row, n_stores, cluster)
         _tm.count("bank/trace_rows", len(trace_row))
         _tm.count("bank/wv_rows", len(wv_row))
-        _tm.count("bank/wv_rows_built", _WV_ROW_CACHE.misses - misses)
+        # host-collapsed rows are counted where _wv_row builds them; a
+        # bank build collapses none, so the counter reads 0 unless a
+        # host reader materializes rows
+        _tm.count("bank/wv_rows_built", 0)
         if any(isinstance(x, ContentionParams) for k in wv_row
                for x in k[6:]):
             draws1, delays1 = contention_memo_misses()
             _tm.count("contention/draws_built", draws1 - draws0)
             _tm.count("contention/delay_rows_built", delays1 - delays0)
         with _tm.span("bank/stack"):
-            return TraceBank(
+            bank = TraceBank(
                 n_stores=n_stores, cluster=cluster,
                 arrivals=np.stack(a_rows, axis=0),
-                w=np.stack([c[0] for c in wv_rows], axis=0),
-                v=np.stack([c[1] for c in wv_rows], axis=0),
-                pr_nc=np.stack([c[2] for c in wv_rows], axis=0),
                 trace_row=trace_row, wv_row=wv_row)
+            bank._table = _stack_row_table(trace_row, recipes, n_stores,
+                                           cluster)
+            return bank
 
 
 def get_trace_bank(specs: Sequence[ScenarioSpec], n_stores: int,

@@ -86,10 +86,11 @@ def test_stream_single_shard_matches_sharded(serial_results):
 
 @pytest.mark.parametrize("n_shards", [1, 8])
 def test_bank_placement_layout_bytes_and_oracle(n_shards):
-    """Sub-bank placement copies host bytes only where the layout moves
-    rows: ``bank/layout_bytes`` reads 0 at one shard (the bank's own
-    columns are uploaded) and the padded stacks' bytes on the 8-device
-    mesh, while every answer stays ``==`` the serial oracle."""
+    """Sub-bank placement lays out no host plane at any shard count:
+    the host ships the row table and the device collapses the rows
+    (``bank/rows_device`` reads the wv rows, ``bank/wv_rows_built`` 0,
+    no ``bank/layout_bytes``), the h2d bytes are the collapse inputs',
+    and every answer stays ``==`` the serial oracle."""
     from repro.core import telemetry as tm
     if jax.device_count() < n_shards:
         pytest.skip(f"needs {n_shards} devices")
@@ -100,9 +101,13 @@ def test_bank_placement_layout_bytes_and_oracle(n_shards):
                          n_shards=n_shards)
         counters = rec.summary()["counters"]
     bank = E.get_trace_bank(grid, N)
-    stacks = 0 if n_shards == 1 else sum(
-        x.nbytes for x in bank.sub_bank_host(n_shards)[1:])
-    assert counters["bank/layout_bytes"] == stacks
+    assert "bank/layout_bytes" not in counters
+    assert counters["bank/rows_device"] == bank.wv_rows
+    assert counters["bank/wv_rows_built"] == 0
+    sent = sum(x.nbytes for x in jax.tree.leaves(
+        bank.collapse_inputs(n_shards)))
+    tiles = 8 * out[0].meta["tile_cells"] * counters["engine/tiles"]
+    assert E.bank_stats()["h2d_bytes"] == sent + tiles
     _assert_bit_identical(grid, out, [simulate_spec(s, n_stores=N)
                                       for s in grid], "layout")
 
@@ -293,6 +298,7 @@ def test_bank_stats_and_meta_on_banked_run():
     """bank_stats() reports the last run's bank accounting -- MEASURED
     resident device bytes from the live buffers, sub vs replicated --
     and the scanned lanes never outnumber the cells."""
+    clear_sim_caches()                       # force a fresh placement
     out = E.run_grid(RAGGED_GRID, n_stores=N, tile_cells=16)
     meta = out[0].meta
     assert meta["data_plane"] == "bank"
@@ -318,8 +324,9 @@ def test_bank_stats_and_meta_on_banked_run():
         n_shards * a.nbytes + w.nbytes + v.nbytes + p.nbytes
     assert stats["bank_dev_bytes"] < stats["bank_bytes"] * n_shards \
         or n_shards == 1
-    # only the arrivals staging replicates over the fabric
-    assert stats["bank_fabric_bytes"] == a.nbytes * (n_shards - 1)
+    # only the shared collapse inputs (arrivals, trace columns, extra
+    # plane) replicate over the fabric
+    assert stats["bank_fabric_bytes"] == bank.shared_nbytes * (n_shards - 1)
     assert stats["dev_mem_hwm_bytes"] >= stats["bank_dev_bytes"]
 
     # replicated baseline: measured bytes really are ~bank x n_shards

@@ -164,9 +164,7 @@ def test_sub_bank_host_copies_only_when_the_layout_moves_rows(n_shards):
         assert np.array_equal(got, want)
         assert np.shares_memory(got, col) == (n_shards == 1)
 
-    empty = dataclasses.replace(
-        bank, w=bank.w[:0], v=bank.v[:0], pr_nc=bank.pr_nc[:0],
-        wv_row={}, _device={})
+    empty = dataclasses.replace(bank, wv_row={}, _device={})
     for got, col in zip(empty.sub_bank_host(n_shards)[1:], cols):
         assert got.shape == (n_shards, 1, N) and got.dtype == col.dtype
         assert not got.any()
@@ -201,8 +199,9 @@ def test_measured_sub_bytes_cut_vs_replicated():
     # per-shard: its stack slice + the replicated arrivals, nothing more
     assert 0 < sub["bank_dev_bytes_per_shard"] \
         <= a.nbytes + stacks // n_shards
-    # only arrivals replicate over the fabric under sub
-    assert sub["bank_fabric_bytes"] == a.nbytes * (n_shards - 1)
+    # only the shared collapse inputs (arrivals, trace columns, extra
+    # plane) replicate over the fabric under sub
+    assert sub["bank_fabric_bytes"] == bank.shared_nbytes * (n_shards - 1)
     assert rep["bank_fabric_bytes"] == \
         rep["bank_bytes"] * (n_shards - 1)
 

@@ -5,8 +5,10 @@ gathering a cell's columns out of the bank must reconstruct its
 per-cell inputs **bit-exactly** -- arrivals verbatim, and the host-
 precollapsed ``(w, v, pr_nc)`` columns equal to the device
 ``_blocked_precompute`` of the per-cell arrays -- for arbitrary ragged
-mixed-SB grids; and ``clear_sim_caches()`` must drop the bank cache
-including its device placements (no leaked device buffers).
+mixed-SB grids; the device collapse of the row table must equal those
+host rows bit for bit, at one shard and on a mesh with replica blocks;
+and ``clear_sim_caches()`` must drop the bank cache including its
+device placements (no leaked device buffers).
 """
 
 import dataclasses
@@ -18,9 +20,11 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import engine as E
+from repro.core import scenarios
 from repro.core import simulator as S
 from repro.core.simulator import (
     CONFIGS,
@@ -245,3 +249,74 @@ def test_wb_wt_rows_collapse_to_constants():
     assert len(rows) == 2
     with pytest.raises(KeyError):      # cells outside the build grid
         bank.rows_for(ScenarioSpec("ycsb", "proactive"))
+
+
+#: Grids whose device-collapsed rows are pinned bitwise to the host
+#: rows: the mega-grid axes, the contended pod, a directory-load grid.
+COLLAPSE_GRIDS = {
+    "megagrid": scenarios.mega_grid,
+    "contention": scenarios.contention_mega_grid,
+    "directory": scenarios.directory_mega_grid,
+}
+COLLAPSE_STORES = 2000
+
+
+def _bits(x):
+    """An array's raw bits: f32 compared as uint32, so ``-0.0`` and
+    NaN payloads count."""
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("n_shards,k_replicas", [(1, 1), (8, 2)])
+@pytest.mark.parametrize("grid", sorted(COLLAPSE_GRIDS))
+def test_device_collapse_is_bitwise_the_host_rows(grid, n_shards,
+                                                  k_replicas):
+    """The sub-bank placement collapses the rows on the device from the
+    row table; its planes are bitwise the host layout of the rows
+    ``_make_wv_row`` collapses (the host truth), replica blocks and
+    zero padding rows included, and the placement ships only the
+    collapse inputs."""
+    if jax.device_count() < n_shards:
+        pytest.skip(f"needs {n_shards} devices")
+    clear_sim_caches()
+    bank = get_trace_bank(COLLAPSE_GRIDS[grid](), COLLAPSE_STORES,
+                          PAPER_CLUSTER)
+    sent, dev = E._place_sub_bank(bank, n_shards, k_replicas)
+    assert sent == sum(x.nbytes for x in jax.tree.leaves(
+        bank.collapse_inputs(n_shards, k_replicas)))
+    assert sent < bank.nbytes
+    host = bank.sub_bank_host(n_shards, k_replicas)
+    for got, want in zip(dev, host):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert np.array_equal(_bits(got), _bits(want))
+
+
+def test_collapse_keeps_products_and_sums_in_separate_programs():
+    """Regression for the fused multiply-add trap: in ONE program
+    XLA:CPU contracts ``t_repl_base + queue`` (and ``exposed + ...``)
+    with the products feeding them into FMAs, which round once where
+    numpy rounds twice -- 1 ulp off on about a sixth of the mega-grid's
+    rows (every replicating rule). The production collapse keeps the
+    products and the sums in two programs and matches the host bits."""
+    clear_sim_caches()
+    bank = get_trace_bank(scenarios.mega_grid(), COLLAPSE_STORES,
+                          PAPER_CLUSTER)
+    inputs = jax.tree.map(jnp.asarray, bank.collapse_inputs(1))
+    extra = jnp.asarray(bank.row_table.extra)
+    @jax.jit
+    def one_program(coalesce, exposed_coh, burst_pos, in_burst, extra,
+                    ints, scal):
+        exposed, queue = S.collapse_products(exposed_coh, burst_pos,
+                                             in_burst, ints, scal)
+        return S.collapse_sums(exposed, queue, coalesce, in_burst, extra,
+                               ints, scal)
+
+    fused_w = _bits(one_program(*inputs[1:5], extra, *inputs[6:])[0])[0]
+    _, w, v, pr_nc = S.collapse_planes(inputs, bank.row_table.extra_cap)
+    host_w = _bits(bank.w)
+    # the trap is live: a fused program is off on whole rows
+    assert (fused_w != host_w).any(axis=1).sum() > 0
+    assert np.array_equal(_bits(w)[0], host_w)
+    assert np.array_equal(_bits(v)[0], _bits(bank.v))
+    assert np.array_equal(np.asarray(pr_nc)[0], bank.pr_nc)
