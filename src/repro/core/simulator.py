@@ -540,30 +540,32 @@ def _directory_delay_row(arrivals: np.ndarray, tx_mask: np.ndarray,
     latency. Host numpy (f64 recurrence, f32 result): the delays are
     folded into the ``w`` side before the collapse, so no scan kernel
     changes. Exactly all-zero when ``rho_bg == 0`` (the load-0
-    normalization cell); monotone in ``rho_bg``.
+    normalization cell); monotone in ``rho_bg``. Span
+    ``directory/rows``: each nonzero row built, on either path.
     """
     n = int(arrivals.shape[0])
     if dirp.rho_bg <= 0.0 or n == 0:
         return np.zeros(n, np.float32)
-    e_len = int(dirp.epoch)
-    a = np.asarray(arrivals, np.float64)
-    starts = a[::e_len]
-    ends = np.concatenate([starts[1:], a[-1:] + cluster.cycle_ns])
-    span = np.maximum(ends - starts, cluster.cycle_ns)
-    tx = np.add.reduceat(np.asarray(tx_mask, np.float64),
-                         np.arange(0, n, e_len))
-    s_dir = float(cluster.dram_lat_ns)
-    own = tx * s_dir / dirp.buckets
-    bg = float(dirp.rho_bg) * span
-    x = own + bg - span
-    cs = np.cumsum(x)
-    backlog = cs - np.minimum(np.minimum.accumulate(cs), 0.0)
-    b_prev = np.concatenate([[0.0], backlog[:-1]])
-    rho = np.minimum((own + bg) / span, 0.95)
-    wq = rho * s_dir / (2.0 * (1.0 - rho))
-    d_e = (b_prev + wq) * congestion
-    delay = np.repeat(d_e, e_len)[:n]
-    return np.where(tx_mask, delay, 0.0).astype(np.float32)
+    with _tm.span("directory/rows"):
+        e_len = int(dirp.epoch)
+        a = np.asarray(arrivals, np.float64)
+        starts = a[::e_len]
+        ends = np.concatenate([starts[1:], a[-1:] + cluster.cycle_ns])
+        span = np.maximum(ends - starts, cluster.cycle_ns)
+        tx = np.add.reduceat(np.asarray(tx_mask, np.float64),
+                             np.arange(0, n, e_len))
+        s_dir = float(cluster.dram_lat_ns)
+        own = tx * s_dir / dirp.buckets
+        bg = float(dirp.rho_bg) * span
+        x = own + bg - span
+        cs = np.cumsum(x)
+        backlog = cs - np.minimum(np.minimum.accumulate(cs), 0.0)
+        b_prev = np.concatenate([[0.0], backlog[:-1]])
+        rho = np.minimum((own + bg) / span, 0.95)
+        wq = rho * s_dir / (2.0 * (1.0 - rho))
+        d_e = (b_prev + wq) * congestion
+        delay = np.repeat(d_e, e_len)[:n]
+        return np.where(tx_mask, delay, 0.0).astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1043,7 +1045,8 @@ def _row_recipes(trace_row: Dict[tuple, int], wv_row: Dict[tuple, int],
             flush = extra.add(
                 ("flush", con.schedule), ("flush", con.schedule),
                 lambda: schedule_flush_ns(con.schedule, n_stores, cluster))
-        if dirp is not None:
+        if dirp is not None and dirp.rho_bg > 0.0:
+            # a zero-load shard adds the plane's zero row (row 0)
             trace = _trace_cached(workload, n_stores, seed, cluster)
             coalesce = trace["coalesce"] if coalescing else \
                 np.zeros_like(trace["coalesce"])
@@ -1603,6 +1606,12 @@ def _make_trace_bank(specs: Tuple[ScenarioSpec, ...], n_stores: int,
             draws1, delays1 = contention_memo_misses()
             _tm.count("contention/draws_built", draws1 - draws0)
             _tm.count("contention/delay_rows_built", delays1 - delays0)
+        if any(isinstance(x, DirectoryParams) for k in wv_row
+               for x in k[6:]):
+            _tm.count("directory/delay_rows_built", sum(
+                k[0] == "directory" for k in recipes[2].index))
+            _tm.count("directory/coupled_rows", sum(
+                r[_I_DIR] != 0 for r in recipes[0]))
         with _tm.span("bank/stack"):
             bank = TraceBank(
                 n_stores=n_stores, cluster=cluster,

@@ -23,6 +23,7 @@ Plus the recorder's place on the profiler's clock: its spans reach a
 live are gone when it is not.
 """
 
+import dataclasses
 import gc
 import glob
 import json
@@ -340,6 +341,44 @@ def test_contended_bank_build_times_and_counts_its_delay_rows():
     assert not {"contention/draws_built", "contention/delay_rows_built"} \
         & set(summ["counters"])
     assert (bank.trace_rows, bank.wv_rows) == (27, 1298)
+    clear_sim_caches()
+
+
+def test_directory_bank_build_times_and_counts_its_delay_rows():
+    """A directory-coupled grid's bank build spans each delay row it
+    builds (``directory/rows``, inside ``bank/rows``), one per distinct
+    (workload, seed, loaded directory params, N_r) -- at 20 GB/s each
+    replica count has a congestion of its own -- and counts the rows
+    that add one; an axis-off grid records none of them."""
+    from repro.core.directory import resolve_directory_load
+    from repro.core.scenarios import directory_mega_grid
+
+    clear_sim_caches()
+    specs = directory_mega_grid(workloads=("ycsb", "canneal"), seeds=(0,),
+                                cn_counts=(16, 4), sb_sizes=(72,))
+    specs = [dataclasses.replace(s, link_bw_gbps=20.0) for s in specs]
+    with tm.recording() as rec:
+        bank = get_trace_bank(specs, 300, PAPER_CLUSTER)
+        summ = rec.summary()
+    spans, counters = summ["spans"], summ["counters"]
+    loaded = [s for s in specs if s.directory_load > 0.0]
+    rows = {(s.workload, s.seed, s.n_replicas,
+             resolve_directory_load(s.directory_load, s.n_cns,
+                                    s.n_replicas)) for s in loaded}
+    assert counters["directory/delay_rows_built"] == len(rows) == 24
+    assert spans["directory/rows"]["count"] == len(rows)
+    assert spans["directory/rows"]["total"] <= \
+        spans["bank/rows"]["total"] + 1e-6
+    assert counters["directory/coupled_rows"] == \
+        len({_plane_keys(s, PAPER_CLUSTER)[1] for s in loaded}) == 72
+    assert bank.wv_rows == 84           # 12 more at load 0
+    clear_sim_caches()
+    with tm.recording() as rec:
+        get_trace_bank(GRID, 64, PAPER_CLUSTER)
+        summ = rec.summary()
+    assert "directory/rows" not in summ["spans"]
+    assert not {"directory/delay_rows_built", "directory/coupled_rows"} \
+        & set(summ["counters"])
     clear_sim_caches()
 
 

@@ -2,12 +2,12 @@
 
 Each test lowers a tile program built by ``engine._build_bank_tile_fn``
 for a v5e topology that is described, not attached, and compiles it
-with the TPU compiler: the mega-grid's two store-buffer signatures at
-50 000 stores, the serving daemon's capacity-padded tile, and the
-4-shard sub-bank program. Nothing runs; the compiler's refusals (block
-tiling, memory) are what these tests catch without a chip. Each
-program must fit one chip's HBM by ``memory_analysis()``, and the
-sharded one must contain no collective.
+with the TPU compiler: the two store-buffer signatures of the mega-grid
+and of the directory grid at 50 000 stores, the serving daemon's
+capacity-padded tile, and the 4-shard sub-bank program. Nothing runs;
+the compiler's refusals (block tiling, memory) are what these tests
+catch without a chip. Each program must fit one chip's HBM by
+``memory_analysis()``, and the sharded one must contain no collective.
 """
 
 import dataclasses
@@ -22,7 +22,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.configs.recxl_paper import PAPER_CLUSTER
 from repro.core import engine
-from repro.core.scenarios import mega_grid
+from repro.core.scenarios import directory_mega_grid, mega_grid
 from repro.core.serving import SERVE_BATCH_CELLS, SERVE_ROW_PAD, _row_capacity
 from repro.core.simulator import bank_row_maps, sub_bank_rows
 from repro.distributed.sharding import sub_bank_tile_specs
@@ -82,8 +82,8 @@ def _fits_hbm(compiled):
     return used
 
 
-def _mega_sigs(n_shards):
-    specs = mega_grid()
+def _mega_sigs(n_shards, specs=None):
+    specs = mega_grid() if specs is None else specs
     trace_map, wv_map = bank_row_maps(specs, PAPER_CLUSTER)
     shape = (len(trace_map), sub_bank_rows(len(wv_map), n_shards))
     tiles = engine.plan_tiles(
@@ -96,6 +96,18 @@ def _mega_sigs(n_shards):
 def test_mega_grid_tile_compiles_for_v5e(topo, sb):
     sig = _mega_sigs(1)[sb]
     assert sig.n_stores == N_STORES and sig.b_pad % 8 == 0
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = engine._build_bank_tile_fn(sig).lower(
+        *_args(sig, [one_chip] * 6)).compile()
+    _fits_hbm(compiled)
+
+
+@pytest.mark.parametrize("sb", [72, 48])
+def test_directory_grid_tile_compiles_for_v5e(topo, sb):
+    """The directory grid's tiles (18 traces, 1 080 max-plus rows, 2 160
+    lanes) at 50 000 stores, as the ``directory.fresh`` cell runs them."""
+    sig = _mega_sigs(1, directory_mega_grid())[sb]
+    assert sig.bank_shape == (18, 1080)
     one_chip = SingleDeviceSharding(topo.devices[0])
     compiled = engine._build_bank_tile_fn(sig).lower(
         *_args(sig, [one_chip] * 6)).compile()
